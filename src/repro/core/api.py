@@ -45,16 +45,36 @@ from .types import (
 )
 
 
-def _wire_block(b: int) -> int:
-    """Kernel batch-block size for a burst of ``b`` messages."""
-    return plan_mod.wire_block(b)
+def default_use_kernels() -> bool:
+    """The engine a context runs when not told: the Pallas kernels on a TPU
+    backend, the jnp oracle elsewhere (where the kernels only interpret)."""
+    return jax.default_backend() == "tpu"
 
 
-def _wire_window_aligned(cfg: PaxosConfig, base: int, b: int) -> bool:
-    """True iff a contiguous window [base, base+b) satisfies the Pallas
-    ring-blocking invariants — the ONE definition both dataplanes consult
-    (``core.plan.window_aligned``, DESIGN.md §2)."""
-    return plan_mod.window_aligned(cfg.n_instances, base, b)
+def _kernel_blocks(dp, bases, b: int) -> int | None:
+    """Ring blocks per group a kernel dispatch of ``b``-slot windows at
+    ``bases`` visits, or ``None`` when the dispatch runs the jnp engine
+    (a jnp dataplane, or a window the kernels cannot run)."""
+    if not dp.use_kernels:
+        return None
+    return plan_mod.window_blocks(dp.cfg.n_instances, list(bases), b)
+
+
+class _DispatchCounter:
+    """``dispatch_count`` counts every device program launch (the KV tier
+    pins its consensus-free read claim on it staying flat);
+    ``jnp_dispatch_count`` counts the launches that ran the jnp engine — on
+    a kernel dataplane each one is a fallback (recovery traffic, or a window
+    the kernels cannot run), never a silent one."""
+
+    dispatch_count = 0
+    jnp_dispatch_count = 0
+    persistent_dispatch_count = 0   # K-round waves run as one launch
+
+    def _count(self, kernel: bool) -> None:
+        self.dispatch_count += 1
+        if not kernel:
+            self.jnp_dispatch_count += 1
 
 
 @dataclasses.dataclass
@@ -95,7 +115,7 @@ class _DeferredRound:
         return fresh, self._inst, value
 
 
-class HardwareDataplane(RingReclamationMixin):
+class HardwareDataplane(RingReclamationMixin, _DispatchCounter):
     """The coordinator + acceptor array + learner dedup memory, executing as
     single-dispatch device programs.
 
@@ -129,18 +149,19 @@ class HardwareDataplane(RingReclamationMixin):
         self.alive = [True] * cfg.n_acceptors       # host mirror (introspection)
         self.alive_mask = jnp.ones((cfg.n_acceptors,), jnp.bool_)
         self.use_kernels = use_kernels
-        # host mirror of the sequencer watermark — lets the kernel path check
-        # its block-alignment invariant without a device sync
+        # host mirror of the sequencer watermark — sizes the kernel's ring
+        # window without a device sync
         self._next_inst_host = 0
-        # monotone count of device program launches (wire-path dispatches);
-        # the KV tier pins its consensus-free read claim on this staying flat
-        self.dispatch_count = 0
         self._seq_base: int | None = None        # provenance hint for vote()
         if use_kernels:
             from repro.kernels import ops as kops
 
             self._seq = kops.coordinator_sequence
-            self._fused_k = jax.jit(kops.fused_round, donate_argnums=(1, 2))
+            self._fused_k = jax.jit(
+                kops.fused_round,
+                donate_argnums=(1, 2),
+                static_argnames=("window_blocks",),
+            )
             self._vote_all_k = jax.jit(
                 kops.acceptor_phase2_all, donate_argnums=(0,)
             )
@@ -149,13 +170,6 @@ class HardwareDataplane(RingReclamationMixin):
         self._fused = jax.jit(batched.fused_round, donate_argnums=(1, 2))
         self._vote_all = jax.jit(batched.acceptor_phase2_all, donate_argnums=(0,))
         self._prep_all = jax.jit(batched.acceptor_phase1_all, donate_argnums=(0,))
-
-    # -- wire-path invariants -------------------------------------------------
-    def _block(self, b: int) -> int:
-        return _wire_block(b)
-
-    def _window_aligned(self, base: int, b: int) -> bool:
-        return _wire_window_aligned(self.cfg, base, b)
 
     # -- ring reclamation: RingReclamationMixin at G == 1 (DESIGN.md §9) -----
     def _seq_marks(self) -> list[int]:
@@ -187,8 +201,12 @@ class HardwareDataplane(RingReclamationMixin):
         """
         b = values.shape[0]
         self._guard_capacity(self._next_inst_host, b)
-        use_k = self.use_kernels and self._window_aligned(self._next_inst_host, b)
-        fn = self._fused_k if use_k else self._fused
+        nblk = _kernel_blocks(self, [self._next_inst_host], b)
+        fn = (
+            self._fused
+            if nblk is None
+            else functools.partial(self._fused_k, window_blocks=nblk)
+        )
         args = [
             self.cstate,
             self.stack,
@@ -202,7 +220,7 @@ class HardwareDataplane(RingReclamationMixin):
             args.append(
                 jnp.int32(self.reclaimed_host + self.cfg.n_instances)
             )
-        self.dispatch_count += 1
+        self._count(nblk is not None)
         self.cstate, self.stack, self.lstate, fresh, inst, _win, value = fn(
             *args
         )
@@ -232,7 +250,7 @@ class HardwareDataplane(RingReclamationMixin):
     def sequence(self, values: np.ndarray, active: np.ndarray) -> MsgBatch:
         self._guard_capacity(self._next_inst_host, values.shape[0])
         self._seq_base = self._next_inst_host
-        self.dispatch_count += 1
+        self._count(self.use_kernels)
         self.cstate, p2a = self._seq(
             self.cstate, jnp.asarray(values), jnp.asarray(active)
         )
@@ -242,26 +260,27 @@ class HardwareDataplane(RingReclamationMixin):
     def vote(self, p2a: MsgBatch) -> list[MsgBatch | None]:
         """Phase-2 vote of the whole acceptor array, one dispatch.
 
-        Batches produced by ``sequence()`` (contiguous, block-aligned window)
-        go through the Pallas wire-path kernel when ``use_kernels``; anything
-        else (recovery singletons, software-coordinator batches at arbitrary
-        watermarks) takes the general jnp scatter path.  Dead acceptors come
-        back as ``None`` — their votes are never sent.
+        Batches produced by ``sequence()`` on a ring-block-aligned window go
+        through the Pallas vote kernel when ``use_kernels``; anything else
+        (recovery singletons, software-coordinator batches at arbitrary
+        watermarks) takes the general jnp scatter path, counted in
+        ``jnp_dispatch_count``.  Dead acceptors come back as ``None`` —
+        their votes are never sent.
         """
         base, self._seq_base = self._seq_base, None
         b = p2a.batch
         use_k = (
             self.use_kernels
             and base is not None
-            and self._window_aligned(base, b)
+            and plan_mod.blocks_aligned(self.cfg.n_instances, [base], b)
         )
         fn = self._vote_all_k if use_k else self._vote_all
-        self.dispatch_count += 1
+        self._count(use_k)
         self.stack, votes = fn(self.stack, p2a, self.alive_mask)
         return self._split(votes)
 
     def prepare(self, p1a: MsgBatch) -> list[MsgBatch | None]:
-        self.dispatch_count += 1
+        self._count(False)
         self.stack, outs = self._prep_all(self.stack, p1a, self.alive_mask)
         return self._split(outs)
 
@@ -297,7 +316,7 @@ class _GroupView:
     def vote(self, p2a: MsgBatch) -> list[MsgBatch | None]:
         mg, gid = self.mg, self.gid
         row = mg._slab_row(gid)
-        mg.dispatch_count += 1
+        mg._count(False)
         st = jax.tree_util.tree_map(lambda x: x[row], mg.stack)
         st, votes = mg._vote_all(st, p2a, mg.alive_mask[gid])
         mg.stack = jax.tree_util.tree_map(
@@ -308,7 +327,7 @@ class _GroupView:
     def prepare(self, p1a: MsgBatch) -> list[MsgBatch | None]:
         mg, gid = self.mg, self.gid
         row = mg._slab_row(gid)
-        mg.dispatch_count += 1
+        mg._count(False)
         st = jax.tree_util.tree_map(lambda x: x[row], mg.stack)
         st, outs = mg._prep_all(st, p1a, mg.alive_mask[gid])
         mg.stack = jax.tree_util.tree_map(
@@ -328,7 +347,7 @@ class _GroupView:
         ]
 
 
-class MultiGroupDataplane(RingReclamationMixin):
+class MultiGroupDataplane(RingReclamationMixin, _DispatchCounter):
     """G device-resident Paxos groups sharing one fused dispatch per round —
     consensus as a service, the NetChain-style generalization of
     ``HardwareDataplane`` (DESIGN.md §5).
@@ -337,9 +356,9 @@ class MultiGroupDataplane(RingReclamationMixin):
     coordinator watermarks/rounds, ``(G, A, N)`` acceptor rings, ``(G, N)``
     learner rings, a ``(G, A)`` runtime liveness mask.  ``pipeline`` advances
     *every* group one Phase-2 round in one device program — the Pallas
-    multi-group megakernel when ``use_kernels`` and every group's watermark
-    is block-aligned (folding all groups into each grid step when the host
-    watermark mirrors are in lockstep), else the vmapped jnp oracle.
+    multi-group megakernel when ``use_kernels`` (folding groups into each
+    grid step when the host watermark mirrors are in lockstep), else the
+    vmapped jnp oracle.
 
     Per-group failover support: ``freeze_group`` parks a group's coordinator
     round at ``NO_ROUND`` so the shared dispatch can keep running — a frozen
@@ -372,11 +391,9 @@ class MultiGroupDataplane(RingReclamationMixin):
         self._free: list[int] = []
         self.use_kernels = use_kernels
         # per-group host mirrors of the sequencer watermark and round — the
-        # kernel path's alignment/lockstep decisions cost no device sync
+        # kernel path's window/lockstep decisions cost no device sync
         self.next_inst_host: list[int] = [0] * g
         self.crnd_host: list[int] = [0] * g
-        # monotone device-program-launch counter (see HardwareDataplane)
-        self.dispatch_count = 0
         self.last_gb: int | None = None   # fold width of the last dispatch
         if use_kernels:
             from repro.kernels import ops as kops
@@ -384,12 +401,12 @@ class MultiGroupDataplane(RingReclamationMixin):
             self._fused_k = jax.jit(
                 kops.multigroup_fused_round,
                 donate_argnums=(1, 2),
-                static_argnames=("group_block",),
+                static_argnames=("group_block", "window_blocks"),
             )
             self._cohort_k = jax.jit(
                 kops.cohort_fused_round,
                 donate_argnums=(0, 1),
-                static_argnames=("group_block",),
+                static_argnames=("group_block", "window_blocks"),
             )
             self._persist_k = jax.jit(
                 kops.persistent_cohort_rounds,
@@ -404,13 +421,6 @@ class MultiGroupDataplane(RingReclamationMixin):
         )
         self._vote_all = jax.jit(batched.acceptor_phase2_all)
         self._prep_all = jax.jit(batched.acceptor_phase1_all)
-
-    # -- wire-path invariants (shared definition: _wire_window_aligned) ------
-    def _block(self, b: int) -> int:
-        return _wire_block(b)
-
-    def _window_aligned(self, base: int, b: int) -> bool:
-        return _wire_window_aligned(self.cfg, base, b)
 
     # -- ring reclamation: RingReclamationMixin per group (DESIGN.md §9) -----
     def _seq_marks(self) -> list[int]:
@@ -441,23 +451,28 @@ class MultiGroupDataplane(RingReclamationMixin):
     # -- shared pre-dispatch plan (the parity contract between this class
     # and its sharded subclass: both MUST resolve a round identically) ------
     def _fold_width(self) -> int:
-        """Groups folded per grid step under lockstep (the whole service
-        here; one shard's slab in the sharded subclass)."""
-        return self.cfg.n_groups
+        """Widest fold of groups into one grid step (the whole service
+        here, one shard's slab in the sharded subclass), capped by what one
+        step may hold in VMEM (``core.plan.fold_cap``)."""
+        cfg = self.cfg
+        return plan_mod.fold_cap(
+            cfg.n_groups, cfg.n_instances, cfg.n_acceptors, cfg.value_words
+        )
 
     def _plan_round(self, b: int, enabled: list[bool] | None):
         """Resolve the enabled mask against membership and frozen rounds,
-        decide kernel eligibility from the host watermark mirrors, and pick
-        the fold width (``core.plan.fold_width_full`` — the widest divisor
-        of the fold cap whose aligned blocks are internally lockstep, not
-        the historical all-or-nothing fold).  Returns
-        ``(enabled, use_k, group_block)``.
+        size the kernel's ring window from the host watermark mirrors, and
+        pick the fold width (``core.plan.fold_width_full`` — the widest
+        divisor of the fold cap whose aligned blocks are internally
+        lockstep, not the historical all-or-nothing fold).  Returns
+        ``(enabled, window_blocks, group_block)``; ``window_blocks`` is
+        ``None`` when the round runs the jnp engine.
 
         Only *enabled* groups constrain the plan: a disabled group — frozen,
         vacant (retired), or idle this round — rides the dispatch inert at
         whatever watermark it has (the kernel's enabled-mask path substitutes
         a folded block's ring offset for it), so divergent disabled
-        watermarks neither break alignment nor forfeit the lockstep fold."""
+        watermarks neither widen the window nor forfeit the lockstep fold."""
         if enabled is None:
             enabled = [
                 lv and c != NO_ROUND
@@ -469,13 +484,11 @@ class MultiGroupDataplane(RingReclamationMixin):
                 for e, lv, c in zip(enabled, self.live_host, self.crnd_host, strict=True)
             ]
         en_gids = [i for i, e in enumerate(enabled) if e]
-        use_k = self.use_kernels and all(
-            self._window_aligned(self.next_inst_host[g], b) for g in en_gids
-        )
+        nblk = _kernel_blocks(self, (self.next_inst_host[g] for g in en_gids), b)
         gb = plan_mod.fold_width_full(
             en_gids, self.next_inst_host, self._fold_width()
         )
-        return enabled, use_k, gb
+        return enabled, nblk, gb
 
     def _empty_round(self, g: int, b: int):
         """The all-disabled result: nothing would decide, skip dispatch."""
@@ -505,7 +518,7 @@ class MultiGroupDataplane(RingReclamationMixin):
         ``(fresh, inst, value)`` with a leading group axis.
         """
         g, b = values.shape[0], values.shape[1]
-        enabled, use_k, gb = self._plan_round(b, enabled)
+        enabled, nblk, gb = self._plan_round(b, enabled)
         if not any(enabled):
             return self._empty_round(g, b)
         self._guard_capacity(
@@ -513,13 +526,14 @@ class MultiGroupDataplane(RingReclamationMixin):
         )
         lim = self._reclaim_limits()
         en = jnp.asarray(enabled)
-        if use_k:
+        if nblk is not None:
             # the kernel takes the membership mask itself (enabled-mask
             # path): it forces disabled rounds to NO_ROUND and substitutes
             # folded-block watermarks for vacant/frozen members
             fn = functools.partial(
                 self._fused_k,
                 group_block=gb,
+                window_blocks=nblk,
                 enabled=en.astype(jnp.int32),
                 reclaim_limit=lim,
             )
@@ -531,7 +545,7 @@ class MultiGroupDataplane(RingReclamationMixin):
         eff = CoordinatorState(
             next_inst=cs.next_inst, crnd=jnp.where(en, cs.crnd, NO_ROUND)
         )
-        self.dispatch_count += 1
+        self._count(nblk is not None)
         new_c, self.stack, self.lstate, fresh, inst, _win, value = fn(
             eff,
             self.stack,
@@ -555,26 +569,23 @@ class MultiGroupDataplane(RingReclamationMixin):
     # -- cohort dispatch: one tier of a RoundPlan (DESIGN.md §8) -------------
     def _cohort_prologue(self, gids, values: np.ndarray):
         """Shared pre-dispatch resolution for a cohort tier: membership
-        mask, kernel eligibility (every member's window aligned for this
-        burst), and the per-member instance windows — identical for the
-        unsharded and sharded executions, which is half the parity
-        contract."""
+        mask, the kernel's ring window (``None`` = jnp engine), and the
+        per-member instance windows — identical for the unsharded and
+        sharded executions, which is half the parity contract."""
         gids = list(gids)
         be = values.shape[1]
         assert values.shape[0] == len(gids), (values.shape, len(gids))
         marks = self.next_inst_host
         member = np.zeros((self.cfg.n_groups,), np.int32)
         member[gids] = 1
-        use_k = self.use_kernels and all(
-            self._window_aligned(marks[gid], be) for gid in gids
-        )
+        nblk = _kernel_blocks(self, (marks[gid] for gid in gids), be)
         inst = np.stack(
             [
                 np.arange(marks[gid], marks[gid] + be, dtype=np.int32)
                 for gid in gids
             ]
         )
-        return gids, member, use_k, inst
+        return gids, member, nblk, inst
 
     @mirror_guard
     def pipeline_cohort(
@@ -596,7 +607,7 @@ class MultiGroupDataplane(RingReclamationMixin):
         (DESIGN.md §11); host watermark mirrors advance at dispatch time
         either way.
         """
-        gids, member, use_k, inst = self._cohort_prologue(gids, values)
+        gids, member, nblk, inst = self._cohort_prologue(gids, values)
         g = self.cfg.n_groups
         be = values.shape[1]
         self._guard_capacity(gids, be)
@@ -607,22 +618,11 @@ class MultiGroupDataplane(RingReclamationMixin):
         # engines, so introspection never depends on engine choice
         gb, blocks = plan_mod.cohort_blocks(gids, marks, self._fold_width())
         self.last_gb = gb
-        self.dispatch_count += 1
+        self._count(nblk is not None)
         en = jnp.asarray(member)
-        if use_k:
-            # compact kernel layout: row j*gb + k <-> group blocks[j]*gb + k
-            rowof = {
-                blk * gb + k: j * gb + k
-                for j, blk in enumerate(blocks)
-                for k in range(gb)
-            }
-            kvals = np.zeros(
-                (len(blocks) * gb, be, self.cfg.value_words), np.int32
-            )
-            kvals[:, :, 0] = NOP_SENTINEL
-            for row, gid in enumerate(gids):
-                kvals[rowof[gid]] = values[row]
-            self.stack, self.lstate, kfresh, _win, kvalue = self._cohort_k(
+        if nblk is not None:
+            rows, kvals = self._compact_rows(gids, blocks, gb, values)
+            self.stack, self.lstate, dfresh, _win, dvalue = self._cohort_k(
                 self.stack,
                 self.lstate,
                 jnp.asarray(np.asarray(blocks, np.int32)),
@@ -634,9 +634,8 @@ class MultiGroupDataplane(RingReclamationMixin):
                 en,
                 reclaim_limit=lim,
                 group_block=gb,
+                window_blocks=nblk,
             )
-            rows = [rowof[gid] for gid in gids]
-            dfresh, dvalue = kfresh, kvalue
         else:
             # jnp oracle: full-width dispatch with non-members held inert
             # (round presented as NO_ROUND) — bit-identical results
@@ -672,18 +671,22 @@ class MultiGroupDataplane(RingReclamationMixin):
         handle = _DeferredRound(dfresh, dvalue, inst, rows=rows, axis=0)
         return handle if defer else handle.resolve()
 
-    def _wave_block(self, be: int, bases) -> int:
-        """Batch-block size for a persistent wave: upgrade to one grid step
-        per round (``bb = be``) when every member's base — and therefore
-        every subsequent window base, each round advancing by ``be`` —
-        is ``be``-aligned; else the ordinary wire block.  A perf-only
-        choice: block size never changes results."""
-        if (
-            self.cfg.n_instances % be == 0
-            and all(base % be == 0 for base in bases)
-        ):
-            return be
-        return self._block(be)
+    def _compact_rows(self, gids, blocks, gb: int, values: np.ndarray):
+        """Compact kernel layout of a cohort's ``(..., M, BE, V)`` rows: row
+        ``j*gb + k`` belongs to group ``blocks[j]*gb + k``; rows of
+        non-members carry NOP fillers.  Returns ``(rows, kvals)`` with
+        ``rows`` each member's compact row, in cohort order."""
+        rowof = {
+            blk * gb + k: j * gb + k
+            for j, blk in enumerate(blocks)
+            for k in range(gb)
+        }
+        shape = values.shape[:-3] + (len(blocks) * gb,) + values.shape[-2:]
+        kvals = np.zeros(shape, np.int32)
+        kvals[..., 0] = NOP_SENTINEL
+        rows = [rowof[gid] for gid in gids]
+        kvals[..., rows, :, :] = values
+        return rows, kvals
 
     @mirror_guard
     def pipeline_persistent(
@@ -701,9 +704,12 @@ class MultiGroupDataplane(RingReclamationMixin):
         participates in every round (the planner only mints K > 1 when each
         member has K full chunks queued), windows are consecutive
         ``BE``-slices from each member's watermark, and delivery is
-        bit-identical to K sequential ``pipeline_cohort`` calls.  Returns
-        host ``(fresh[K, M, BE], inst[K, M, BE], value[K, M, BE, V])``, or
-        a ``_DeferredRound`` with ``defer=True``.
+        bit-identical to K sequential ``pipeline_cohort`` calls.  On the
+        kernel engine every round must start and end on a ring block (the
+        planner mints K > 1 only then, ``core.plan.blocks_aligned``), so
+        that rounds share no block.  Returns host ``(fresh[K, M, BE],
+        inst[K, M, BE], value[K, M, BE, V])``, or a ``_DeferredRound``
+        with ``defer=True``.
         """
         k, be = values.shape[0], values.shape[2]
         if k * be > self.cfg.n_instances:
@@ -711,9 +717,17 @@ class MultiGroupDataplane(RingReclamationMixin):
                 f"persistent wave of {k} x {be} instances would lap the "
                 f"{self.cfg.n_instances}-instance ring"
             )
-        gids, member, use_k, _inst0 = self._cohort_prologue(gids, values[0])
+        gids, member, _nblk, _inst0 = self._cohort_prologue(gids, values[0])
         g = self.cfg.n_groups
         marks = self.next_inst_host
+        if self.use_kernels and not plan_mod.blocks_aligned(
+            self.cfg.n_instances, [marks[gid] for gid in gids], be
+        ):
+            raise ValueError(
+                f"persistent wave of {be}-slot rounds at watermarks "
+                f"{[marks[gid] for gid in gids]} is off the ring-block "
+                f"boundary ({plan_mod.ring_block(self.cfg.n_instances)} slots)"
+            )
         # guard the wave's LAST window up front: an over-watermark wave
         # must fail before any state moves, never mid-wave
         for gid in gids:
@@ -721,7 +735,8 @@ class MultiGroupDataplane(RingReclamationMixin):
         lim = self._reclaim_limits()
         gb, blocks = plan_mod.cohort_blocks(gids, marks, self._fold_width())
         self.last_gb = gb
-        self.dispatch_count += 1
+        self._count(self.use_kernels)
+        self.persistent_dispatch_count += 1
         # wave descriptor: cumulative window-base table + participation
         # (rows for non-members are ignored — the kernel substitutes the
         # folded block's lockstep base for them)
@@ -742,19 +757,9 @@ class MultiGroupDataplane(RingReclamationMixin):
                 for r in range(k)
             ]
         )
-        if use_k:
-            rowof = {
-                blk * gb + kk: j * gb + kk
-                for j, blk in enumerate(blocks)
-                for kk in range(gb)
-            }
-            kvals = np.zeros(
-                (k, len(blocks) * gb, be, self.cfg.value_words), np.int32
-            )
-            kvals[:, :, :, 0] = NOP_SENTINEL
-            for row, gid in enumerate(gids):
-                kvals[:, rowof[gid]] = values[:, row]
-            self.stack, self.lstate, kfresh, _win, kvalue = self._persist_k(
+        if self.use_kernels:
+            rows, kvals = self._compact_rows(gids, blocks, gb, values)
+            self.stack, self.lstate, dfresh, _win, dvalue = self._persist_k(
                 self.stack,
                 self.lstate,
                 jnp.asarray(np.asarray(blocks, np.int32)),
@@ -766,10 +771,7 @@ class MultiGroupDataplane(RingReclamationMixin):
                 jnp.asarray(kvals),
                 reclaim_limit=lim,
                 group_block=gb,
-                block_b=self._wave_block(be, [marks[gid] for gid in gids]),
             )
-            rows = [rowof[gid] for gid in gids]
-            dfresh, dvalue = kfresh, kvalue
         else:
             # jnp oracle: full-width scatter per round, K-unrolled under
             # one jit — still one dispatch, bit-identical results
@@ -871,13 +873,8 @@ class MultiGroupDataplane(RingReclamationMixin):
     @mirror_guard
     def restore_group(self, gid: int, next_inst: int, crnd: int) -> None:
         """Hand a group back to the hardware sequencer at the watermark and
-        round the software coordinator reached (block-realigned on the kernel
-        path — the skipped instances are never proposed and are recoverable
-        as no-ops, exactly as in the single-group restore)."""
+        round the software coordinator reached."""
         self._check_gid(gid)
-        if self.use_kernels:
-            bb = self._block(self.cfg.batch)
-            next_inst = -(-next_inst // bb) * bb
         self.cstate = CoordinatorState(
             next_inst=self.cstate.next_inst.at[gid].set(next_inst),
             crnd=self.cstate.crnd.at[gid].set(crnd),
@@ -962,9 +959,7 @@ class MultiGroupDataplane(RingReclamationMixin):
         it lives in the ``SnapshotStore``; instances below the watermark
         are never proposed again.  Requires reclamation to be enabled
         (without it a wrapped snapshot watermark has no meaning).  Returns
-        the claimed gid.  On the kernel path the sequencer realigns up to
-        the next block boundary — the gap instances are permanent NOP
-        holes, exactly as in ``restore_group``."""
+        the claimed gid."""
         if self.reclaimed_host is None:
             raise ValueError("adopt_group requires reclamation enabled")
         if watermark < 0:
@@ -1053,8 +1048,8 @@ class ShardedMultiGroupDataplane(MultiGroupDataplane):
         self._slab_sharding = NamedSharding(mesh, P(axis))
         self.stack = jax.device_put(self.stack, self._slab_sharding)
         self.lstate = jax.device_put(self.lstate, self._slab_sharding)
-        self._dispatches: dict[tuple[bool, int], Any] = {}
-        self._packed_dispatches: dict[bool, Any] = {}
+        self._dispatches: dict[tuple[int | None, int], Any] = {}
+        self._packed_dispatches: dict[int | None, Any] = {}
         # group -> physical slot permutation (DESIGN.md §13); identity at
         # boot, mutated only by ``migrate_group`` slot swaps.  Device slabs
         # are SLOT-indexed; every host mirror stays gid-indexed and the
@@ -1064,10 +1059,14 @@ class ShardedMultiGroupDataplane(MultiGroupDataplane):
         )
 
     def _fold_width(self) -> int:
-        # lockstep folds one shard's slab per grid step (a block has a
-        # single ring offset, and a shard sees only its own slab); on a
-        # 1-device mesh this is the parent's full-service fold
-        return self.groups_per_shard
+        # lockstep folds within one shard's slab (a block has a single ring
+        # offset, and a shard sees only its own slab); on a 1-device mesh
+        # this is the parent's full-service fold
+        cfg = self.cfg
+        return plan_mod.fold_cap(
+            self.groups_per_shard, cfg.n_instances, cfg.n_acceptors,
+            cfg.value_words,
+        )
 
     # -- placement (consumed by serve.ConsensusService) ----------------------
     @property
@@ -1096,8 +1095,10 @@ class ShardedMultiGroupDataplane(MultiGroupDataplane):
         )
 
     # -- dispatch construction ----------------------------------------------
-    def _dispatch(self, use_k: bool, gb: int):
-        key = (use_k, gb)
+    def _dispatch(self, nblk: int | None, gb: int):
+        """The full-width sharded step for ``nblk`` ring blocks per group
+        (``None`` = the jnp engine) at fold width ``gb``."""
+        key = (nblk, gb)
         fn = self._dispatches.get(key)
         if fn is None:
             from .fabric import make_sharded_multigroup_round
@@ -1107,14 +1108,15 @@ class ShardedMultiGroupDataplane(MultiGroupDataplane):
                 n_groups=self.cfg.n_groups,
                 quorum=self.cfg.quorum,
                 axis=self.axis,
-                use_kernels=use_k,
+                use_kernels=nblk is not None,
                 group_block=gb,
+                window_blocks=nblk,
             )
             self._dispatches[key] = fn
         return fn
 
-    def _packed_dispatch(self, use_k: bool):
-        fn = self._packed_dispatches.get(use_k)
+    def _packed_dispatch(self, nblk: int | None):
+        fn = self._packed_dispatches.get(nblk)
         if fn is None:
             from .fabric import make_packed_sharded_round
 
@@ -1122,9 +1124,10 @@ class ShardedMultiGroupDataplane(MultiGroupDataplane):
                 self.mesh,
                 quorum=self.cfg.quorum,
                 axis=self.axis,
-                use_kernels=use_k,
+                use_kernels=nblk is not None,
+                window_blocks=nblk,
             )
-            self._packed_dispatches[use_k] = fn
+            self._packed_dispatches[nblk] = fn
         return fn
 
     def _ensure_placement(self) -> None:
@@ -1147,7 +1150,7 @@ class ShardedMultiGroupDataplane(MultiGroupDataplane):
         ``MultiGroupDataplane.pipeline``, executed as one ``shard_map``
         program over the group slabs."""
         g, b = values.shape[0], values.shape[1]
-        enabled, use_k, _ = self._plan_round(b, enabled)
+        enabled, nblk, _ = self._plan_round(b, enabled)
         if not any(enabled):
             return self._empty_round(g, b)
         self._guard_capacity(
@@ -1161,7 +1164,7 @@ class ShardedMultiGroupDataplane(MultiGroupDataplane):
         slots = [pm.slot_of[gid] for gid in range(g) if enabled[gid]]
         gb = plan_mod.fold_width_full(slots, marks_slot, self._fold_width())
         plan_gb = gb               # reported engine-agnostically (last_gb)
-        if not use_k:
+        if nblk is None:
             gb = 1
         self._ensure_placement()
         ni = np.asarray(self.next_inst_host, np.int32)[perm]
@@ -1170,8 +1173,8 @@ class ShardedMultiGroupDataplane(MultiGroupDataplane):
             en != 0, np.asarray(self.crnd_host, np.int32)[perm], NO_ROUND
         ).astype(np.int32)
         lim = self._reclaim_limits_np()
-        fn = self._dispatch(use_k, gb)
-        self.dispatch_count += 1
+        fn = self._dispatch(nblk, gb)
+        self._count(nblk is not None)
         self.stack, self.lstate, fresh, inst, _win, value = fn(
             ni,
             eff_crnd,
@@ -1214,7 +1217,7 @@ class ShardedMultiGroupDataplane(MultiGroupDataplane):
         ``segids`` table riding scalar prefetch; shards with fewer resident
         members ride inert pad lanes.  The burst still right-sizes per
         tier, so cohort cost is ``O(C x BE)`` instead of ``O(Gl x BE)``."""
-        gids, member, use_k, inst = self._cohort_prologue(gids, values)
+        gids, member, nblk, inst = self._cohort_prologue(gids, values)
         be = values.shape[1]
         self._guard_capacity(gids, be)
         marks = self.next_inst_host
@@ -1232,7 +1235,7 @@ class ShardedMultiGroupDataplane(MultiGroupDataplane):
             # slab rows as the full-width fold but pays one grid step per
             # lane, so the fat folded dispatch is strictly cheaper
             return self._cohort_full_width(
-                gids, member, use_k, inst, values, active, defer
+                gids, member, nblk, inst, values, active, defer
             )
         # the full-width fold over slot-ordered marks remains the reported
         # plan (engine-agnostic, comparable across rounds); packed
@@ -1265,8 +1268,8 @@ class ShardedMultiGroupDataplane(MultiGroupDataplane):
                 valsp[s, j] = values[row]
                 lane_of[gid] = (s, j)
         self._ensure_placement()
-        fn = self._packed_dispatch(use_k)
-        self.dispatch_count += 1
+        fn = self._packed_dispatch(nblk)
+        self._count(nblk is not None)
         self.stack, self.lstate, fresh, _inst_d, _win, value = fn(
             seg,
             nip,
@@ -1292,7 +1295,7 @@ class ShardedMultiGroupDataplane(MultiGroupDataplane):
 
     @mirror_guard
     def _cohort_full_width(
-        self, gids, member, use_k, inst, values, active, defer: bool,
+        self, gids, member, nblk, inst, values, active, defer: bool,
     ):
         """Full-width folded execution for saturated cohorts: non-members
         ride the dispatch inert (NOP sentinel rows, membership-masked
@@ -1307,7 +1310,7 @@ class ShardedMultiGroupDataplane(MultiGroupDataplane):
         plan_gb = plan_mod.fold_width_full(
             [pm.slot_of[gid] for gid in gids], marks_slot, self._fold_width()
         )
-        gb = plan_gb if use_k else 1
+        gb = plan_gb if nblk is not None else 1
         vals_f, act_f = plan_mod.scatter_rows(
             gids, values, active, g, self.cfg.value_words
         )
@@ -1317,8 +1320,8 @@ class ShardedMultiGroupDataplane(MultiGroupDataplane):
         ).astype(np.int32)
         lim = self._reclaim_limits_np()
         self._ensure_placement()
-        fn = self._dispatch(use_k, gb)
-        self.dispatch_count += 1
+        fn = self._dispatch(nblk, gb)
+        self._count(nblk is not None)
         self.stack, self.lstate, fresh, _inst_d, _win, value = fn(
             np.asarray(marks, np.int32)[perm],
             eff_crnd,
@@ -1414,9 +1417,6 @@ class ShardedMultiGroupDataplane(MultiGroupDataplane):
     @mirror_guard
     def restore_group(self, gid: int, next_inst: int, crnd: int) -> None:
         self._check_gid(gid)
-        if self.use_kernels:
-            bb = self._block(self.cfg.batch)
-            next_inst = -(-next_inst // bb) * bb
         self.next_inst_host[gid] = next_inst
         self.crnd_host[gid] = crnd
         self._sync_cstate()
@@ -1437,8 +1437,7 @@ class ShardedMultiGroupDataplane(MultiGroupDataplane):
           1. swap slots with the lowest vacant group on ``dst_shard``;
           2. zero the adopted slot (it holds the vacant group's stale
              retired rows — exactly ``create_group``'s lazy reset);
-          3. re-seat the sequencer at the drain watermark (block-realigned
-             on the kernel path, as in ``restore_group``/``adopt_group``).
+          3. re-seat the sequencer at the drain watermark.
 
         No other group's slab state, watermark or placement is touched, so
         the rest of the service keeps dispatching normally around the swap
@@ -1476,14 +1475,17 @@ class ShardedMultiGroupDataplane(MultiGroupDataplane):
 
 
 class PaxosContext:
-    """Drop-in replacement context (the paper's ``paxos_ctx``)."""
+    """Drop-in replacement context (the paper's ``paxos_ctx``).
+
+    ``use_kernels=None`` picks the engine from the platform
+    (``default_use_kernels``): the Pallas wire path on a TPU."""
 
     def __init__(
         self,
         cfg: PaxosConfig | None = None,
         deliver: Callable[[bytes, int, int], None] | None = None,
         net: SimNet | None = None,
-        use_kernels: bool = False,
+        use_kernels: bool | None = None,
         retransmit_after: int = 3,
         n_learners: int = 1,
         fused: bool = False,
@@ -1491,6 +1493,8 @@ class PaxosContext:
         snapshots: bool = False,
     ):
         self.cfg = cfg or PaxosConfig()
+        if use_kernels is None:
+            use_kernels = default_use_kernels()
         self.deliver_cb = deliver
         self.net = net or SimNet()
         self.n_groups = self.cfg.n_groups
@@ -1672,12 +1676,7 @@ class PaxosContext:
             chunk = submits[i : i + b]
             # the fused path right-sizes the burst on BOTH engines
             # (engine-agnostic quantization, core.plan); the staged path
-            # keeps the full batch.  A sub-batch burst can leave the
-            # watermark off the full-batch block boundary, in which case
-            # later full bursts take the jnp fallback (bit-identical,
-            # slower) — the grouped pump's realignment sweep recovers the
-            # kernel window; a single-group deployment accepts the
-            # fallback (or burns forward via fail/restore).
+            # keeps the full batch.
             be = self._burst_size(len(chunk)) if self.fused else b
             vals, active = self._pack_chunk(chunk, be)
             if self.fused and self._softco is None:
@@ -2307,23 +2306,12 @@ class PaxosContext:
         if self.grouped:
             co = self._softco_g.pop(group, None)
             if co is not None:
-                # per-group realignment: only this group's watermark/round
-                # move; the kernel path's block realignment happens inside
-                # restore_group (same §3.1 gap-fill rationale as below)
+                # only this group's watermark/round move
                 self.hw.restore_group(group, int(co.next_inst), int(co.crnd))
             return
         if self._softco is None:
             return
         nxt = int(self._softco.next_inst)
-        if self.hw.use_kernels:
-            # An arbitrary takeover watermark can break the kernel path's
-            # block-alignment invariant — and since bursts advance in block
-            # multiples it would never realign on its own, silently pinning
-            # the dataplane to the jnp fallback forever.  Burn forward to the
-            # next block boundary instead: the skipped instances are never
-            # proposed and are recoverable as no-ops (paper §3.1 gap fill).
-            bb = self.hw._block(self.cfg.batch)
-            nxt = -(-nxt // bb) * bb
         self.hw.cstate = CoordinatorState(
             next_inst=jnp.int32(nxt),
             crnd=jnp.int32(self._softco.crnd),

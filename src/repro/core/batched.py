@@ -145,7 +145,7 @@ def acceptor_phase2_all(
     back MSG_REJECT) nor mutate their register file — exactly the semantics
     of a crashed switch: its BRAM is frozen and it emits nothing.
 
-    Inherits ``acceptor_phase2``'s vectorized-scatter precondition: batch
+    Shares ``acceptor_phase2``'s vectorized-scatter precondition: batch
     positions must hit *distinct* ring slots (``inst % N`` pairwise
     distinct), or slot updates race.  Use ``acceptor_sequential`` for
     adversarial duplicate-slot traffic.
@@ -154,26 +154,40 @@ def acceptor_phase2_all(
     rewrote the full stacked state with ``.at[aid].set`` per acceptor).
     Returns (stack', votes) with every vote field shaped [A, ...].
     """
-    a = stack.rnd.shape[0]
-
-    def vote_one(st, aid, alv):
-        new_st, votes = acceptor_phase2(st, msgs, aid=aid)
-        # crashed acceptor: register file frozen, and its vote row is exactly
-        # what a pure rejecter would emit (so the kernel path can reproduce
-        # it without special cases)
-        slots = msgs.inst % st.n_instances
-        votes = votes.replace(
-            msgtype=jnp.where(alv, votes.msgtype, MSG_REJECT).astype(jnp.int32),
-            rnd=jnp.where(alv, votes.rnd, st.rnd[slots]),
-            vrnd=jnp.where(alv, votes.vrnd, st.vrnd[slots]),
-            value=jnp.where(alv, votes.value, 0),
-        )
-        st = jax.tree_util.tree_map(
-            lambda n, o: jnp.where(alv, n, o), new_st, st
-        )
-        return st, votes
-
-    return jax.vmap(vote_one)(stack, jnp.arange(a), alive)
+    a, n, v = stack.value.shape
+    slots = msgs.inst % n
+    cur_rnd = stack.rnd[:, slots]                               # [A, B]
+    is_p2a = (msgs.msgtype == MSG_P2A) | (msgs.msgtype == MSG_NOP)
+    # a crashed acceptor accepts nothing: its scatter writes its slots back
+    # unchanged, and its vote row is exactly what a pure rejecter would emit
+    # (so the kernel path can reproduce it without special cases)
+    accept = is_p2a & (msgs.rnd >= cur_rnd) & alive[:, None]   # [A, B]
+    new_rnd = jnp.where(accept, msgs.rnd, cur_rnd)
+    new_vrnd = jnp.where(accept, msgs.rnd, stack.vrnd[:, slots])
+    # all A value rings as (A*V, N) rows, updated by one scatter along N: a
+    # per-acceptor scatter made XLA copy the whole ring into a layout that
+    # pads (A, V) to a (4, 128) tile, 8 GiB per round at G=64 on a TPU
+    rows = jnp.swapaxes(stack.value, 1, 2).reshape(a * v, n)
+    new_val = jnp.where(
+        accept[:, None, :], msgs.value.T, rows[:, slots].reshape(a, v, -1)
+    )
+    rows = rows.at[:, slots].set(new_val.reshape(a * v, -1), mode="drop")
+    stack = AcceptorState(
+        rnd=stack.rnd.at[:, slots].set(new_rnd, mode="drop"),
+        vrnd=stack.vrnd.at[:, slots].set(new_vrnd, mode="drop"),
+        value=jnp.swapaxes(rows.reshape(a, v, n), 1, 2),
+    )
+    votes = MsgBatch(
+        msgtype=jnp.where(accept, MSG_P2B, MSG_REJECT).astype(jnp.int32),
+        inst=jnp.broadcast_to(msgs.inst, accept.shape),
+        rnd=new_rnd,
+        vrnd=new_vrnd,
+        swid=jnp.broadcast_to(
+            jnp.arange(a, dtype=msgs.swid.dtype)[:, None], accept.shape
+        ),
+        value=jnp.where(accept[:, :, None], msgs.value, 0),
+    )
+    return stack, votes
 
 
 def acceptor_phase1_all(
@@ -183,16 +197,10 @@ def acceptor_phase1_all(
     a = stack.rnd.shape[0]
 
     def prep_one(st, aid, alv):
-        new_st, out = acceptor_phase1(st, msgs, aid=aid)
-        slots = msgs.inst % st.n_instances
-        out = out.replace(
-            msgtype=jnp.where(alv, out.msgtype, MSG_REJECT).astype(jnp.int32),
-            rnd=jnp.where(alv, out.rnd, st.rnd[slots]),
-        )
-        st = jax.tree_util.tree_map(
-            lambda n, o: jnp.where(alv, n, o), new_st, st
-        )
-        return st, out
+        # a crashed acceptor receives no requests: it promises nothing and
+        # its register file stays as it was
+        unseen = msgs.replace(msgtype=jnp.where(alv, msgs.msgtype, MSG_REJECT))
+        return acceptor_phase1(st, unseen, aid=aid)
 
     return jax.vmap(prep_one)(stack, jnp.arange(a), alive)
 

@@ -39,22 +39,12 @@ def _shard_map(
     in_specs: Any,
     out_specs: Any,
 ) -> Callable[..., Any]:
-    """``shard_map`` across jax versions: the top-level export with
-    ``check_vma`` (jax >= 0.6) or the experimental one with ``check_rep``
-    (older releases, including this container's).  Replication checking is
-    disabled either way — the replicated outputs here are replicated by
-    construction (psum / identical sequencing), which the checker cannot
-    always prove."""
-    try:
-        from jax import shard_map as sm
-
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    except (ImportError, TypeError):
-        from jax.experimental.shard_map import shard_map as sm
-
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
+    """``jax.shard_map`` with replication checking off: the replicated
+    outputs here are replicated by construction (psum / identical
+    sequencing), which the checker cannot always prove."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )
 
 
 def consensus_round(
@@ -183,6 +173,7 @@ def make_sharded_multigroup_round(
     axis: str = "groups",
     use_kernels: bool = False,
     group_block: int = 1,
+    window_blocks: int | None = None,
 ) -> Callable[..., Any]:
     """Build the groups-sharded fused dispatch (DESIGN.md §6): ONE compiled
     program advances all G groups one Phase-2 round, with the ``(G, A, N)``
@@ -211,7 +202,9 @@ def make_sharded_multigroup_round(
     step retraces per distinct pow2 burst — a bounded vocabulary), the
     ``enabled`` mask is the tier's membership, and ``group_block`` is the
     per-cohort fold width (``core.plan.fold_width_full`` against the
-    per-shard slab).  The group axis is *not* compacted here — shard_map
+    per-shard slab), and ``window_blocks`` the kernel's ring blocks per
+    group (``core.plan.window_blocks``; ``None`` covers any offset).  The
+    group axis is *not* compacted here — shard_map
     needs uniform per-shard shapes, and a cohort may concentrate on one
     shard — so non-member slabs ride each tier inert; the unsharded
     dataplane additionally compacts via
@@ -261,7 +254,8 @@ def make_sharded_multigroup_round(
                 off[0], ni, cr, jnp.int32(q), alive,
                 stack.rnd, stack.vrnd, stack.value,
                 lstate.delivered, lstate.inst, lstate.value, values, en, lim,
-                group_block=group_block, interpret=kops.INTERPRET,
+                group_block=group_block, window_blocks=window_blocks,
+                interpret=kops.INTERPRET,
             )
             stack = AcceptorState(*outs[:3])
             lstate = batched.LearnerState(*outs[3:6])
@@ -358,6 +352,7 @@ def make_packed_sharded_round(
     axis: str = "groups",
     use_kernels: bool = False,
     block_b: int | None = None,
+    window_blocks: int | None = None,
 ) -> Callable[..., Any]:
     """Build the *packed* groups-sharded cohort dispatch (DESIGN.md §13):
     each shard advances only its resident, enabled cohort lanes — packed
@@ -382,7 +377,8 @@ def make_packed_sharded_round(
     state donated in place, and the slab state updated bit-identically to
     the full-width dispatch (pads and absent rows untouched).  ``C`` is a
     trace-time shape: the step retraces per distinct (C, B) — both pow2-
-    quantized vocabularies bounded by the planner.
+    quantized vocabularies bounded by the planner.  ``window_blocks`` is
+    the kernel's ring blocks per lane (``None`` covers any offset).
     """
     if axis not in mesh.shape:
         raise ValueError(f"mesh has no {axis!r} axis: {mesh.axis_names}")
@@ -415,7 +411,8 @@ def make_packed_sharded_round(
                 seg[0], ni[0], cr[0], jnp.int32(q), alive[0],
                 stack.rnd, stack.vrnd, stack.value,
                 lstate.delivered, lstate.inst, lstate.value, values[0],
-                en[0], lim[0], interpret=kops.INTERPRET, **kw,
+                en[0], lim[0], window_blocks=window_blocks,
+                interpret=kops.INTERPRET, **kw,
             )
             stack = AcceptorState(*outs[:3])
             lstate = batched.LearnerState(*outs[3:6])

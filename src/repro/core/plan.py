@@ -50,26 +50,69 @@ import numpy as np
 NO_ROUND = -1
 NOP_SENTINEL = -0x7FFFFFFF  # first value word marking an internal filler slot
 MIN_BURST = 8               # smallest wire burst (pow2 quantization floor)
+# Groups x ring slots one wire-path grid step may hold: at A <= 5 and V = 16
+# a fold of GB * BB = 8192 slots is the widest that compiles for a v5e under
+# the kernels' scoped-VMEM limit (GB = 64 groups at BB = 128).  Other shapes
+# scale it by the words a group-slot holds in VMEM (``fold_cap``).
+MAX_FOLD_LANES = 8192
+_FOLD_SHAPE = (5, 16)       # the (A, V) at which MAX_FOLD_LANES compiled
 
 
 def wire_block(b: int) -> int:
-    """Kernel batch-block size for a burst of ``b`` messages."""
+    """Block boundary a burst of ``b`` messages advances by — the boundary
+    the realignment sweep burns fragmented watermarks forward to."""
     from repro.kernels.wirepath import DEFAULT_BLOCK_B
 
     return min(DEFAULT_BLOCK_B, b)
 
 
-def window_aligned(n_instances: int, base: int, b: int) -> bool:
-    """True iff a contiguous window [base, base+b) satisfies the Pallas
-    ring-blocking invariants (BB | base, BB | B, BB | N, B <= N) — the ONE
-    definition every dataplane consults (DESIGN.md §2)."""
-    bb = wire_block(b)
-    return (
-        b % bb == 0
-        and n_instances % bb == 0
-        and b <= n_instances
-        and base % bb == 0
-    )
+def ring_block(n_instances: int) -> int:
+    """Ring slots per kernel grid step (``kernels.wirepath.ring_block``)."""
+    from repro.kernels.wirepath import ring_block as _ring_block
+
+    return _ring_block(n_instances)
+
+
+def window_blocks(n_instances: int, bases: Sequence[int], b: int) -> int | None:
+    """Ring blocks a kernel dispatch visits per group so that every window
+    ``[base, base + b)`` is covered from the block holding its first slot,
+    or ``None`` when some window would wrap onto its own first block (only
+    possible on rings shorter than ``b`` plus one block) — the kernel cannot
+    run that window and the dispatch takes the jnp engine."""
+    bb = ring_block(n_instances)
+    need = max((base % bb + b for base in bases), default=b)
+    nblk = -(-need // bb)
+    return nblk if nblk * bb <= n_instances else None
+
+
+def blocks_aligned(n_instances: int, bases: Sequence[int], b: int) -> bool:
+    """True iff every window ``[base, base + b)`` starts and ends on a ring
+    block boundary — the persistent wave kernel's precondition."""
+    bb = ring_block(n_instances)
+    return b % bb == 0 and all(base % bb == 0 for base in bases)
+
+
+def _slot_words(a: int, v: int) -> int:
+    """int32 words one group-slot takes in a wire-path grid step's blocks,
+    inputs and outputs: the four acceptor round blocks ``(A, ·)`` pad to 8
+    sublanes, the value blocks are ``(A*V, ·)`` twice and ``(V, ·)`` four
+    times, and the six one-row blocks (learner delivered and inst, in and
+    out; fresh; win) pad to 8 rows each."""
+    pad_a = -(-a // 8) * 8
+    return 2 * a * v + 4 * pad_a + 4 * v + 48
+
+
+def fold_cap(
+    n_groups: int, n_instances: int, n_acceptors: int, value_words: int
+) -> int:
+    """Widest group fold a dispatch may use: the largest divisor of
+    ``n_groups`` whose grid step holds at most the VMEM of
+    ``MAX_FOLD_LANES`` slots at ``_FOLD_SHAPE`` — never more slots than
+    that, and proportionally fewer for more acceptors or value words."""
+    budget = MAX_FOLD_LANES * _slot_words(*_FOLD_SHAPE)
+    slots = min(MAX_FOLD_LANES, budget // _slot_words(n_acceptors, value_words))
+    lanes = slots // ring_block(n_instances)
+    return max(d for d in _divisors(n_groups) if d <= max(1, lanes))
 
 
 def quantize_burst(n: int, cap: int) -> int:
@@ -392,13 +435,17 @@ class DispatchPlanner:
         burst: int,
         gids: Sequence[int],
         pending: Sequence[int] | None,
+        marks: Sequence[int],
     ) -> int:
         """Persistent-wave depth K for one cohort (DESIGN.md §11).
 
         K > 1 only when the burst is the full batch — the wave's rounds are
         consecutive batch-sized queue slices, so numbering is identical to
-        K single-round waves by construction — and every member has K full
-        chunks queued.  Clamped by the ``persistent_rounds`` policy knob and
+        K single-round waves by construction — every member has K full
+        chunks queued, and every member's rounds start and end on a ring
+        block (``blocks_aligned``: the persistent kernel's precondition,
+        applied on every engine so that all of them plan the same waves).
+        Clamped by the ``persistent_rounds`` policy knob and
         by the ring (a wave may not lap itself: K * burst <= N).  On a
         sharded planner K is clamped to 1 up front: the wave would unroll
         into K cohort dispatches anyway (host-authoritative control scalars
@@ -409,6 +456,9 @@ class DispatchPlanner:
             or self.persistent_rounds <= 1
             or pending is None
             or burst != self.batch
+            or not blocks_aligned(
+                self.n_instances, [marks[i] for i in gids], burst
+            )
         ):
             return 1
         k = min(pending[i] // burst for i in gids)
@@ -433,7 +483,8 @@ class DispatchPlanner:
         ``pending`` gives per-group *total* queued lengths (first chunk
         included); when provided and ``persistent_rounds`` > 1, a cohort
         whose burst is the full batch and whose every member has K full
-        batch-sized chunks queued is planned as a K-round persistent wave
+        batch-sized chunks queued at a ring-block-aligned watermark is
+        planned as a K-round persistent wave
         — burst quantization itself never changes, so engine-agnostic
         numbering is preserved round for round.
         """
@@ -488,7 +539,7 @@ class DispatchPlanner:
             Cohort(
                 gids=tuple(gids),
                 burst=be,
-                rounds=self._wave_depth(be, gids, pending),
+                rounds=self._wave_depth(be, gids, pending, marks),
             )
             for be, gids in sorted(tiers.items(), reverse=True)
         )

@@ -26,20 +26,20 @@ DEFAULT_BLOCK_B = 128
 def _coordinator_kernel(
     next_inst_ref,    # int32[1] scalar prefetch
     crnd_ref,         # int32[1] scalar prefetch
-    active_ref,       # int32[BB]
-    msgtype_ref,      # int32[BB] out
-    inst_ref,         # int32[BB] out
-    rnd_ref,          # int32[BB] out
-    vrnd_ref,         # int32[BB] out
+    active_ref,       # int32[1, BB]
+    msgtype_ref,      # int32[1, BB] out
+    inst_ref,         # int32[1, BB] out
+    rnd_ref,          # int32[1, BB] out
+    vrnd_ref,         # int32[1, BB] out
 ):
     i = pl.program_id(0)
-    bb = active_ref.shape[0]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (bb, 1), 0)[:, 0]
+    bb = active_ref.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, bb), 1)
     active = active_ref[...] != 0
     msgtype_ref[...] = jnp.where(active, MSG_P2A, MSG_NOP).astype(jnp.int32)
     inst_ref[...] = next_inst_ref[0] + i * bb + lane
-    rnd_ref[...] = jnp.full((bb,), crnd_ref[0], jnp.int32)
-    vrnd_ref[...] = jnp.full((bb,), NO_ROUND, jnp.int32)
+    rnd_ref[...] = jnp.full((1, bb), crnd_ref[0], jnp.int32)
+    vrnd_ref[...] = jnp.full((1, bb), NO_ROUND, jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
@@ -57,18 +57,20 @@ def coordinator_sequence_window(
     assert b % bb == 0
     grid = (b // bb,)
 
+    # the burst rides as (1, B): a one-row block of a 2-D array is legal at
+    # any B, a 1-D block is not (XLA tiles s32[B] by 1024)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid,
-        in_specs=[pl.BlockSpec((bb,), lambda i, *_: (i,))],
+        in_specs=[pl.BlockSpec((1, bb), lambda i, *_: (0, i))],
         out_specs=[
-            pl.BlockSpec((bb,), lambda i, *_: (i,)),
-            pl.BlockSpec((bb,), lambda i, *_: (i,)),
-            pl.BlockSpec((bb,), lambda i, *_: (i,)),
-            pl.BlockSpec((bb,), lambda i, *_: (i,)),
+            pl.BlockSpec((1, bb), lambda i, *_: (0, i)),
+            pl.BlockSpec((1, bb), lambda i, *_: (0, i)),
+            pl.BlockSpec((1, bb), lambda i, *_: (0, i)),
+            pl.BlockSpec((1, bb), lambda i, *_: (0, i)),
         ],
     )
-    out_shapes = [jax.ShapeDtypeStruct((b,), jnp.int32) for _ in range(4)]
+    out_shapes = [jax.ShapeDtypeStruct((1, b), jnp.int32) for _ in range(4)]
     fn = pl.pallas_call(
         _coordinator_kernel,
         grid_spec=grid_spec,
@@ -77,5 +79,7 @@ def coordinator_sequence_window(
     )
     ni = jnp.asarray(next_inst, jnp.int32).reshape((1,))
     cr = jnp.asarray(crnd, jnp.int32).reshape((1,))
-    msgtype, inst, rnd, vrnd = fn(ni, cr, active.astype(jnp.int32))
+    msgtype, inst, rnd, vrnd = (
+        x.reshape((b,)) for x in fn(ni, cr, active.astype(jnp.int32).reshape(1, b))
+    )
     return msgtype, inst, rnd, vrnd, (ni[0] + b).astype(jnp.int32)

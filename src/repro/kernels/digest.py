@@ -22,23 +22,29 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-DEFAULT_BLOCK = 32 * 1024  # elements per grid step (128 KiB of f32)
+LANES = 128
+DEFAULT_BLOCK = 32 * 1024  # elements per grid step (128 KiB of int32)
 
 
 def _digest_kernel(x_ref, out_ref):
     i = pl.program_id(0)
-    nb = x_ref.shape[0]
-    bits = x_ref[...].view(jnp.int32) if x_ref.dtype != jnp.int32 else x_ref[...]
-    # use 2D iota for TPU compatibility
-    idx = jax.lax.broadcasted_iota(jnp.int32, (nb, 1), 0)[:, 0] + i * nb
-    w = idx * 2 + 1
-    partial = jnp.sum(bits * w)  # int32 wraparound == mod 2^32
+    rows = x_ref.shape[0]
+    bits = x_ref[...]                                      # (rows, 128)
+    idx = (
+        (i * rows + jax.lax.broadcasted_iota(jnp.int32, (rows, LANES), 0))
+        * LANES
+        + jax.lax.broadcasted_iota(jnp.int32, (rows, LANES), 1)
+    )
+    # fold whole (8, 128) tiles into the lane-parallel accumulator; the
+    # 1024 partial sums are added on the host side of the kernel (int32
+    # wraparound makes the split exact mod 2^32)
+    part = (bits * (idx * 2 + 1)).reshape(rows // 8, 8, LANES).sum(axis=0)
 
     @pl.when(i == 0)
     def _init():
-        out_ref[0, 0] = 0
+        out_ref[...] = jnp.zeros_like(out_ref)
 
-    out_ref[0, 0] += partial
+    out_ref[...] += part
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -47,22 +53,27 @@ def digest(
 ) -> jax.Array:
     """Fold a flat array into an int32 digest."""
     flat = x.reshape(-1)
+    if flat.dtype != jnp.int32:
+        flat = flat.view(jnp.int32)
     n = flat.shape[0]
-    nb = min(block, n)
+    # lane-dense (rows, 128) view in whole (8, 128) tiles; zero padding adds
+    # nothing to the fold
+    tile = 8 * LANES
+    nb = min(block, -(-n // tile) * tile)
     pad = (-n) % nb
     if pad:
         flat = jnp.pad(flat, (0, pad))
-        n += pad
-    grid = (n // nb,)
+    rows = nb // LANES
+    grid = ((n + pad) // nb,)
     out = pl.pallas_call(
         _digest_kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((nb,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
+        in_specs=[pl.BlockSpec((rows, LANES), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((8, LANES), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((8, LANES), jnp.int32),
         interpret=interpret,
-    )(flat)
-    return out[0, 0]
+    )(flat.reshape(-1, LANES))
+    return jnp.sum(out)
 
 
 def tree_digest(tree, *, interpret: bool = False) -> jax.Array:

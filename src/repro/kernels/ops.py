@@ -2,9 +2,9 @@
 signatures, so the hardware dataplane (``core.api.HardwareDataplane``) can be
 switched between the jnp engine and the kernels with one flag.
 
-On CPU (this container) the kernels execute in ``interpret=True`` mode —
-the kernel body runs in Python for correctness validation; on a real TPU
-backend they compile to Mosaic.  ``INTERPRET`` auto-detects.
+On a CPU backend the kernels execute in ``interpret=True`` mode — the
+kernel body runs in Python for correctness validation; on a TPU backend
+they compile to Mosaic.  ``INTERPRET`` follows the default backend.
 """
 from __future__ import annotations
 
@@ -99,7 +99,9 @@ def learner_quorum(
 
 
 @dataplane_contract(
-    oracle=_batched.fused_round, state_args=("stack", "lstate")
+    oracle=_batched.fused_round,
+    state_args=("stack", "lstate"),
+    extra=("window_blocks",),
 )
 def fused_round(
     cstate: CoordinatorState,
@@ -110,6 +112,8 @@ def fused_round(
     alive: jax.Array,
     quorum: int | jax.Array,
     reclaim_limit: jax.Array | None = None,
+    *,
+    window_blocks: int | None = None,
 ) -> tuple[CoordinatorState, AcceptorState, LearnerState,
            jax.Array, jax.Array, jax.Array, jax.Array]:
     """Kernel-backed drop-in for ``batched.fused_round`` — the whole Phase-2
@@ -118,8 +122,8 @@ def fused_round(
     ``active`` is accepted for signature parity but never reaches the device:
     sequenced NOP fillers vote identically to P2As, so on the wire path the
     active mask only matters to the application layer (which discards fillers
-    by value).  Precondition: ``cstate.next_inst`` is block-aligned — the
-    invariant ``HardwareDataplane`` maintains (and checks host-side).
+    by value).  ``window_blocks`` is the ring-block count the window needs
+    (``core.plan.window_blocks``, from the host watermark mirror).
     ``reclaim_limit`` is the first instance the ring may NOT sequence into
     (snapshot watermark + N, DESIGN.md §9); ``None`` = no reclamation.
     """
@@ -139,6 +143,7 @@ def fused_round(
             lstate.value,
             values,
             reclaim_limit,
+            window_blocks=window_blocks,
             interpret=INTERPRET,
         )
     )
@@ -160,7 +165,7 @@ def fused_round(
 @dataplane_contract(
     oracle=_batched.multigroup_fused_round,
     state_args=("stack", "lstate"),
-    extra=("group_block",),
+    extra=("group_block", "window_blocks"),
 )
 def multigroup_fused_round(
     cstate: CoordinatorState,   # leaves shaped (G,)
@@ -174,6 +179,7 @@ def multigroup_fused_round(
     reclaim_limit: jax.Array | None = None,  # int32[G]; None = no reclamation
     *,
     group_block: int = 1,
+    window_blocks: int | None = None,
 ) -> tuple[CoordinatorState, AcceptorState, LearnerState,
            jax.Array, jax.Array, jax.Array, jax.Array]:
     """Kernel-backed drop-in for ``batched.multigroup_fused_round`` — G
@@ -185,8 +191,7 @@ def multigroup_fused_round(
     which the ``MultiGroupDataplane`` checks against its host watermark
     mirrors; ``enabled`` (0/1 per group) marks frozen/vacant/idle groups so
     the kernel can hold them inert and fold over their divergent watermarks
-    (DESIGN.md §7).  Precondition: every enabled group's ``next_inst`` is
-    block-aligned.
+    (DESIGN.md §7).  ``window_blocks`` as in ``fused_round``.
     """
     del active  # sequenced fillers vote like P2As; see fused_round
     b = values.shape[1]
@@ -206,6 +211,7 @@ def multigroup_fused_round(
             None if enabled is None else jnp.asarray(enabled, jnp.int32),
             reclaim_limit,
             group_block=group_block,
+            window_blocks=window_blocks,
             interpret=INTERPRET,
         )
     )
@@ -246,6 +252,7 @@ def cohort_fused_round(
     reclaim_limit: jax.Array | None = None,  # int32[G]; None = no reclamation
     *,
     group_block: int = 1,
+    window_blocks: int | None = None,
 ) -> tuple[AcceptorState, LearnerState, jax.Array, jax.Array, jax.Array]:
     """Cohort-compacted fused round (DESIGN.md §8): the grid visits only the
     group blocks named by ``gsel``, so a dispatch costs what its cohort
@@ -273,6 +280,7 @@ def cohort_fused_round(
             jnp.asarray(enabled, jnp.int32),
             reclaim_limit,
             group_block=group_block,
+            window_blocks=window_blocks,
             interpret=INTERPRET,
         )
     )
@@ -288,7 +296,7 @@ def cohort_fused_round(
 @dataplane_contract(
     oracle=_batched.packed_multigroup_round,
     state_args=("stack", "lstate"),
-    extra=("block_b",),
+    extra=("block_b", "window_blocks"),
 )
 def packed_shard_round(
     stack: AcceptorState,       # leaves shaped (Gl, A, N[, V])
@@ -303,6 +311,7 @@ def packed_shard_round(
     reclaim_limit: jax.Array | None = None,  # int32[C]; None = no reclamation
     *,
     block_b: int | None = None,
+    window_blocks: int | None = None,
 ) -> tuple[AcceptorState, LearnerState, jax.Array, jax.Array, jax.Array]:
     """Packed ragged-shard round (DESIGN.md §13): ``C`` uniform lanes, each
     routed to its resident slab row by the ``segids`` prefetch table, so a
@@ -332,6 +341,7 @@ def packed_shard_round(
             jnp.asarray(enabled, jnp.int32),
             reclaim_limit,
             block_b=block_b,
+            window_blocks=window_blocks,
             interpret=INTERPRET,
         )
     )
@@ -413,8 +423,8 @@ def acceptor_phase2_all(
     """Kernel-backed drop-in for ``batched.acceptor_phase2_all``.
 
     Requires the contiguous-window invariant (``msgs.inst == base + iota(B)``
-    with block-aligned ``base``); the API layer falls back to the jnp scatter
-    path when it cannot guarantee it.
+    with ``base`` and B multiples of the ring block); the API layer takes the
+    jnp scatter path, and counts it, when it cannot guarantee that.
     """
     base = msgs.inst[0]
     (st_rnd, st_vrnd, st_val, vt, vr, vv, vs, vval) = (

@@ -11,11 +11,22 @@ a device pipeline serves *many* replicated groups as one shared service
 
 Layout (DESIGN.md §5):
 
-    grid = (G // GB, B // BB)       # group axis x batch axis
-    stacked rings  (G, A, N)[, V]   --BlockSpec (GB, A, BB)-->  VMEM, in-place
-    learner rings  (G, N)[, V]      --BlockSpec (GB, BB)  -->   VMEM, in-place
-    burst values   (G, B, V)        --BlockSpec (GB, BB, V)-->  VMEM
-    fresh/win/value outputs         <--                         VMEM
+    grid = (G // GB, NBLK)          # group axis x ring blocks of the window
+    acceptor rings (G, A, N)        --BlockSpec (GB, A, BB)-->     VMEM, in-place
+    acceptor vals  (G, A, V, N)     --BlockSpec (GB, A, V, BB)-->  VMEM, in-place
+    learner rings  (G, 1, N)        --BlockSpec (GB, 1, BB)-->     VMEM, in-place
+    learner vals   (G, V, N)        --BlockSpec (GB, V, BB)-->     VMEM, in-place
+    burst values   (G, V, NBLK*BB)  --BlockSpec (GB, V, BB)-->     VMEM
+    fresh/win/value outputs         <--                            VMEM
+
+The wrappers take the host layouts — ``(..., N, V)`` value words, ``(G, N)``
+learner rings — and hand the kernels V-major views with a unit row axis
+(``_v_major``).  ``BB = ring_block(N)`` is the 128-lane tile (the whole ring
+when N is not a multiple of it), so every block is one the TPU can tile.  A window
+``[base, base + B)`` may start anywhere and be any length up to N: the grid
+visits the NBLK ring blocks that cover it, the wrapper moves the burst into
+ring-lane order, and lanes outside the window ride through refused —
+exactly like lanes past the reclamation limit.
 
 Groups never interact: each has its own coordinator watermark/round (the
 ``next_inst``/``crnd`` scalar-prefetch vectors are per-group), its own
@@ -30,14 +41,17 @@ acceptor rings, its own learner ring, and its own liveness row in the
     whose watermarks diverged after a per-group coordinator failover.
   * ``group_block=GB>1``: GB groups ride the leading block dimension of a
     single grid step (the batch analogue of the acceptor-in-block decision).
-    Requires the GB groups of a block to share one BB-aligned watermark
-    ("lockstep"), since a block has a single ring offset.  This is the
+    Requires the GB groups of a block to share one watermark ("lockstep"),
+    since a block has a single ring offset.  This is the
     highest-amortization mapping for the common case of a service pumping
     all groups together.
 
 Invariants (maintained by ``core.api.MultiGroupDataplane``, asserted where
-shapes are static): ``BB | B``, ``BB | N``, ``B <= N``, ``GB | G``, and every
-*enabled* group's window base is BB-aligned.  Liveness is a *runtime* input —
+shapes are static): ``GB | G``, and every enabled window covers at most the
+NBLK blocks the grid visits without wrapping onto its own first block
+(``base % BB + B <= NBLK * BB <= N``; ``core.plan.window_blocks``).  The
+persistent K-round entry additionally needs block-aligned windows, so its
+rounds never share a ring block.  Liveness is a *runtime* input —
 the ``(G, A)`` alive mask rides in scalar-prefetch SMEM, so killing/reviving
 an acceptor in any group never recompiles the kernel.
 
@@ -77,157 +91,209 @@ from repro.core.types import MSG_NOP, MSG_P2A, MSG_P2B, MSG_REJECT
 
 NO_ROUND = -1
 
-# Messages per grid step; 128 is the int32 lane width.
+# Ring slots per grid step; 128 is the int32 lane width.
 DEFAULT_BLOCK_B = 128
 
+# Scoped-VMEM limit of the wire-path kernels; ``core.plan.MAX_FOLD_LANES``
+# keeps a grid step's blocks (GB groups x BB ring slots) inside it.
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
-def _lane_iota(bb: int) -> jax.Array:
-    # 1-D iota via 2-D broadcasted_iota (TPU requires >= 2D iota)
-    return jax.lax.broadcasted_iota(jnp.int32, (bb, 1), 0)[:, 0]
+
+def ring_block(n: int, block_b: int = DEFAULT_BLOCK_B) -> int:
+    """Ring slots per grid step for an ``n``-slot ring: ``block_b`` when it
+    tiles the ring, else the whole ring (a block equal to the array's extent
+    is always a legal TPU block)."""
+    return block_b if n % block_b == 0 else n
 
 
-def _alive_col(alive_ref, a: int) -> jax.Array:
-    # scalar-prefetch liveness -> (A, 1) vector mask (A is static)
-    return jnp.stack([alive_ref[i] for i in range(a)])[:, None] != 0
+def _default_window_blocks(n: int, bb: int, b: int) -> int:
+    # enough blocks for a B-slot window at any offset inside its first block
+    return min(n // bb, -(-b // bb) + 1)
+
+
+# The kernels see every value-word array V-major, (..., V, N), and every
+# learner ring with a unit row axis, (R, 1, N).  V-major is how XLA lays
+# out the (..., N, V) slabs on a TPU (V=16 on sublanes, the ring on lanes),
+# so the swap is free there, and the value words need no lane padding.  The
+# unit row axis makes a one-group block legal (TPU blocks need their last
+# two dims divisible by (8, 128) or equal to the array's).
+def _v_major(x: jax.Array) -> jax.Array:
+    return jnp.swapaxes(x, -1, -2)
+
+
+def _host_state(outs) -> tuple[jax.Array, ...]:
+    """The six kernel state outputs in the host layouts again."""
+    st_rnd, st_vrnd, st_val, ldel, linst, lval = outs[:6]
+    return (
+        st_rnd, st_vrnd, _v_major(st_val), ldel[:, 0], linst[:, 0],
+        _v_major(lval),
+    )
+
+
+def _to_ring_lanes(values: jax.Array, off: jax.Array, lanes: int) -> jax.Array:
+    """(C, B, V) burst rows -> (C, V, lanes) in ring-lane order: row r's
+    burst slot j lands on lane ``off[r] + j``; other lanes carry zeros."""
+    c, b, v = values.shape
+    j = jnp.arange(lanes, dtype=jnp.int32)[None, None, :] - off[:, None, None]
+    idx = jnp.broadcast_to(jnp.clip(j, 0, b - 1), (c, v, lanes))
+    got = jnp.take_along_axis(_v_major(values), idx, axis=2)
+    return jnp.where((j >= 0) & (j < b), got, 0)
+
+
+def _from_ring_lanes(x: jax.Array, off: jax.Array, b: int) -> jax.Array:
+    """Inverse of ``_to_ring_lanes`` for a (C, 1|V, lanes) output."""
+    idx = jnp.arange(b, dtype=jnp.int32)[None, None, :] + off[:, None, None]
+    idx = jnp.broadcast_to(idx, x.shape[:2] + (b,))
+    return jnp.take_along_axis(x, idx, axis=2)
+
+
+def _window_outputs(outs, off: jax.Array, b: int) -> tuple[jax.Array, ...]:
+    """Kernel outputs -> ``(state..., fresh[C, B], win[C, B], value[C, B, V])``:
+    host layouts again, per-lane outputs back in burst order."""
+    fresh, win, value = (_from_ring_lanes(x, off, b) for x in outs[6:])
+    return _host_state(outs) + (
+        fresh[:, 0], win[:, 0], _v_major(value),
+    )
+
+
+def _alive_rows(alive_of, a: int, bb: int) -> jax.Array:
+    """(A, BB) int32 liveness tile from A scalar-prefetch reads."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (a, bb), 0)
+    mask = jnp.zeros((a, bb), jnp.int32)
+    for j in range(a):
+        mask = jnp.where(rows == j, alive_of(j), mask)
+    return mask
 
 
 # ---------------------------------------------------------------------------
 # The fused multi-group round megakernel
 # ---------------------------------------------------------------------------
-def _phase2_block(
-    inst,       # int32[GB, BB]  absolute instance numbers of this window
-    crnd_g,     # int32[GB]      per-group coordinator round (NO_ROUND = inert)
-    alive,      # bool[GB, A]
-    lim_g,      # int32[GB]      per-group reclaim limit (first refused inst)
-    quorum,     # int32[]
-    mval,       # int32[GB, BB, V]  burst values
-    cur_rnd,    # int32[GB, A, BB]  acceptor ring blocks
-    cur_vrnd,   # int32[GB, A, BB]
-    cur_val,    # int32[GB, A, BB, V]
-    ldel,       # int32[GB, BB]     learner ring blocks
-    linst,      # int32[GB, BB]
-    lval,       # int32[GB, BB, V]
+def _phase2_rows(
+    i,              # ring-block index of this grid step within the window
+    quorum,         # int32 scalar (f+1)
+    scalars,        # k -> (window base, round, alive_of(j), permit limit)
+    values_ref,     # int32[GB, V, BB]     burst values, ring-lane order
+    st_rnd_ref,     # int32[GB, A, BB]     acceptor ring blocks
+    st_vrnd_ref,    # int32[GB, A, BB]
+    st_val_ref,     # int32[GB, A, V, BB]
+    ldel_ref,       # int32[GB, 1, BB]     learner ring blocks
+    linst_ref,      # int32[GB, 1, BB]
+    lval_ref,       # int32[GB, V, BB]
+    o_rnd_ref, o_vrnd_ref, o_val_ref,      # acceptor ring blocks, updated
+    o_ldel_ref, o_linst_ref, o_lval_ref,   # learner ring blocks, updated
+    fresh_ref,      # int32[GB, 1, BB]     fresh (non-duplicate) delivery mask
+    win_ref,        # int32[GB, 1, BB]     winning vrnd (NO_ROUND if none)
+    value_ref,      # int32[GB, V, BB]     decided value
 ):
-    """One Phase-2 round over one ``(GB, BB)`` window: sequence -> all-
-    acceptor vote -> learner quorum -> ring dedup, as a pure function of the
-    loaded blocks.  Shared by the single-round and persistent kernel bodies
-    (identical arithmetic is what makes the K-round entry bit-exact against
-    K single rounds by construction).  Returns
-    ``(o_rnd, o_vrnd, o_val, o_ldel, o_linst, o_lval, fresh, win, value)``.
+    """One Phase-2 round over one ring block of ``GB`` groups: sequence ->
+    all-acceptor vote -> learner quorum -> ring dedup, one group row at a
+    time (GB and A are static, so the loops unroll).  Shared by every
+    wire-path kernel body, which differ only in where a row's scalars come
+    from — identical arithmetic is what makes the K-round entry bit-exact
+    against K single rounds by construction.
+
+    Every per-slot quantity is a ``(1, BB)`` lane row, which broadcasts down
+    the V sublanes of the value blocks; masks are int32 until consumed.
     """
-    crnd = crnd_g[:, None, None]                                   # (GB, 1, 1)
+    gb, a, bb = st_rnd_ref.shape
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, bb), 1)
+    for k in range(gb):
+        base, crnd, alive_of, limit = scalars(k)
+        # instance numbers of this ring block; the window's first block is
+        # the one holding slot ``base``
+        inst = base - base % bb + i * bb + lane                    # (1, BB)
 
-    # Reclamation permit (DESIGN.md §9): a lane at or past the group's
-    # reclaim limit (snapshot watermark + N) would land in a ring slot whose
-    # decision has not been drained yet — acceptors refuse it wholesale, so
-    # the slot survives bit-unchanged and the host sees backpressure instead
-    # of a silent dedup-state overwrite.
-    permit = inst < lim_g[:, None]                                 # (GB, BB)
+        # Permit: lanes before the window start, and lanes at or past the
+        # limit — the window end, or the group's reclaim limit (snapshot
+        # watermark + N, DESIGN.md §9) if that comes first — are refused
+        # by every acceptor, so their slots survive bit-unchanged.  Past
+        # the reclaim limit that is backpressure: the slot's decision has
+        # not been drained yet.
+        permit = ((inst >= base) & (inst < limit)).astype(jnp.int32)
 
-    # -- every group's acceptor array votes (Phase 2A -> 2B), all at once ----
-    accept = (
-        alive[:, :, None] & (crnd >= cur_rnd) & permit[:, None, :]
-    )                                                              # (GB, A, BB)
+        # -- the group's acceptor array votes (Phase 2A -> 2B), all at once
+        cur_rnd = st_rnd_ref[k]                                    # (A, BB)
+        accept = (
+            _alive_rows(alive_of, a, bb)
+            * permit
+            * (crnd >= cur_rnd).astype(jnp.int32)
+        )                                                          # (A, BB)
+        acc = accept != 0
+        mval = values_ref[k]                                       # (V, BB)
+        o_rnd_ref[k] = jnp.where(acc, crnd, cur_rnd)
+        o_vrnd_ref[k] = jnp.where(acc, crnd, st_vrnd_ref[k])
+        for j in range(a):
+            o_val_ref[k, j] = jnp.where(
+                accept[j:j + 1] != 0, mval, st_val_ref[k, j]
+            )
 
-    o_rnd = jnp.where(accept, crnd, cur_rnd)
-    o_vrnd = jnp.where(accept, crnd, cur_vrnd)
-    o_val = jnp.where(accept[..., None], mval[:, None], cur_val)
+        # -- learner quorum down the acceptor axis.  Every accepting
+        # acceptor voted (crnd, mval), so the winning vrnd is crnd where
+        # anyone accepted, the agreeing votes are exactly the accepts, and
+        # the first agreeing vote's value is the burst value.
+        count = jnp.sum(accept, axis=0, keepdims=True)             # (1, BB)
+        deliver = count >= quorum
+        win = jnp.where(count > 0, crnd, NO_ROUND)
+        value = jnp.where(count > 0, mval, 0)                      # (V, BB)
 
-    # -- learner quorum: reduce down the acceptor axis, per group ------------
-    vote_vrnd = jnp.where(accept, crnd, NO_ROUND)                  # (GB, A, BB)
-    win = jnp.max(vote_vrnd, axis=1)                               # (GB, BB)
-    agree = accept & (vote_vrnd == win[:, None, :])                # (GB, A, BB)
-    count = jnp.sum(agree.astype(jnp.int32), axis=1)               # (GB, BB)
-    deliver = count >= quorum
-    # decided value: first agreeing acceptor's vote, as a one-hot contraction
-    first = agree & (jnp.cumsum(agree.astype(jnp.int32), axis=1) == 1)
-    vote_val = jnp.where(accept[..., None], mval[:, None], 0)      # (GB,A,BB,V)
-    value = jnp.sum(first.astype(jnp.int32)[..., None] * vote_val, axis=1)
-
-    # -- ring dedup (LearnerState), in place, per group ----------------------
-    dup = (ldel != 0) & (linst == inst)
-    fresh = deliver & ~dup
-    o_ldel = ldel | deliver.astype(jnp.int32)
-    o_linst = jnp.where(fresh, inst, linst)
-    o_lval = jnp.where(fresh[..., None], value, lval)
-    return (
-        o_rnd, o_vrnd, o_val, o_ldel, o_linst, o_lval,
-        fresh.astype(jnp.int32), win, value,
-    )
+        # -- ring dedup (LearnerState), in place ---------------------------
+        ldel = ldel_ref[k]                                         # (1, BB)
+        linst = linst_ref[k]
+        fresh = (deliver & ~((ldel != 0) & (linst == inst))).astype(jnp.int32)
+        o_ldel_ref[k] = ldel | deliver.astype(jnp.int32)
+        o_linst_ref[k] = jnp.where(fresh != 0, inst, linst)
+        o_lval_ref[k] = jnp.where(fresh != 0, value, lval_ref[k])
+        fresh_ref[k] = fresh
+        win_ref[k] = win
+        value_ref[k] = value
 
 
-def _mg_wirepath_kernel(
-    # scalar prefetch (SMEM) — consumed by the index maps; the kernel body
-    # reads the same per-group values from the VMEM mirrors below, as vector
-    # loads instead of G*A scalar gathers (the per-group marginal cost)
-    ni_ref,         # int32[G]     per-group window base, BB-aligned
+def _cohort_wirepath_kernel(
+    # scalar prefetch (SMEM): the index maps and the round body read them
+    gsel_ref,       # int32[NB]    selected group-block indices (÷ GB)
+    ni_ref,         # int32[G]     per-group window base
     crnd_ref,       # int32[G]     per-group coordinator round
     q_ref,          # int32[1]     quorum (f+1)
     alive_ref,      # int32[G, A]  per-group runtime liveness mask
     lim_ref,        # int32[G]     per-group reclaim limit (first refused inst)
     # inputs (VMEM tiles)
-    values_ref,     # int32[GB, BB, V]     burst values
+    values_ref,     # int32[GB, V, BB]     burst values (compact rows)
     st_rnd_ref,     # int32[GB, A, BB]     acceptor ring blocks (aliased out)
     st_vrnd_ref,    # int32[GB, A, BB]
-    st_val_ref,     # int32[GB, A, BB, V]
-    ldel_ref,       # int32[GB, BB]        learner ring blocks (aliased out)
-    linst_ref,      # int32[GB, BB]
-    lval_ref,       # int32[GB, BB, V]
-    niv_ref,        # int32[GB]     VMEM mirror of ni_ref's block
-    crndv_ref,      # int32[GB]     VMEM mirror of crnd_ref's block
-    alivev_ref,     # int32[GB, A]  VMEM mirror of alive_ref's block
-    limv_ref,       # int32[GB]     VMEM mirror of lim_ref's block
+    st_val_ref,     # int32[GB, A, V, BB]
+    ldel_ref,       # int32[GB, 1, BB]     learner ring blocks (aliased out)
+    linst_ref,      # int32[GB, 1, BB]
+    lval_ref,       # int32[GB, V, BB]
     # outputs
-    o_rnd_ref,      # int32[GB, A, BB]
-    o_vrnd_ref,     # int32[GB, A, BB]
-    o_val_ref,      # int32[GB, A, BB, V]
-    o_ldel_ref,     # int32[GB, BB]
-    o_linst_ref,    # int32[GB, BB]
-    o_lval_ref,     # int32[GB, BB, V]
-    fresh_ref,      # int32[GB, BB]  out: fresh (non-duplicate) delivery mask
-    win_ref,        # int32[GB, BB]  out: winning vrnd (NO_ROUND if none)
-    value_ref,      # int32[GB, BB, V]  out: decided value
+    o_rnd_ref, o_vrnd_ref, o_val_ref, o_ldel_ref, o_linst_ref, o_lval_ref,
+    fresh_ref,      # int32[GB, 1, BB]  (compact rows)
+    win_ref,        # int32[GB, 1, BB]
+    value_ref,      # int32[GB, V, BB]
 ):
-    # index-map inputs; body uses the mirrors
-    del ni_ref, crnd_ref, alive_ref, lim_ref
-    i = pl.program_id(1)
-    _gb, _a, bb = st_rnd_ref.shape
+    gb = st_rnd_ref.shape[0]
+    g0 = gsel_ref[pl.program_id(0)] * gb
 
-    ni_g = niv_ref[...]                                            # (GB,)
-    inst = ni_g[:, None] + i * bb + _lane_iota(bb)[None, :]        # (GB, BB)
-    (
-        o_rnd_ref[...], o_vrnd_ref[...], o_val_ref[...],
-        o_ldel_ref[...], o_linst_ref[...], o_lval_ref[...],
-        fresh_ref[...], win_ref[...], value_ref[...],
-    ) = _phase2_block(
-        inst,
-        crndv_ref[...],
-        alivev_ref[...] != 0,
-        limv_ref[...],
-        q_ref[0],
-        values_ref[...],
-        st_rnd_ref[...],
-        st_vrnd_ref[...],
-        st_val_ref[...],
-        ldel_ref[...],
-        linst_ref[...],
-        lval_ref[...],
+    def scalars(k):
+        g = g0 + k
+        return ni_ref[g], crnd_ref[g], lambda j: alive_ref[g, j], lim_ref[g]
+
+    _phase2_rows(
+        pl.program_id(1), q_ref[0], scalars,
+        values_ref, st_rnd_ref, st_vrnd_ref, st_val_ref,
+        ldel_ref, linst_ref, lval_ref,
+        o_rnd_ref, o_vrnd_ref, o_val_ref, o_ldel_ref, o_linst_ref, o_lval_ref,
+        fresh_ref, win_ref, value_ref,
     )
 
 
-def _cohort_wirepath_kernel(gsel_ref, *rest):
-    # same body as the full-grid kernel; ``gsel_ref`` is consumed by the
-    # index maps only (it selects which group blocks the grid visits)
-    del gsel_ref
-    _mg_wirepath_kernel(*rest)
-
-
 @functools.partial(
-    jax.jit, static_argnames=("block_b", "group_block", "interpret")
+    jax.jit,
+    static_argnames=("block_b", "group_block", "window_blocks", "interpret"),
 )
 def cohort_wirepath_round(
     gsel: jax.Array,        # int32[NB]  selected group-block indices (÷ GB)
-    next_inst: jax.Array,   # int32[G]  per-group window base (BB-aligned)
+    next_inst: jax.Array,   # int32[G]  per-group window base
     crnd: jax.Array,        # int32[G]  per-group coordinator round
     quorum: jax.Array,      # int32[]
     alive: jax.Array,       # int32[G, A] (0/1)
@@ -243,6 +309,7 @@ def cohort_wirepath_round(
     *,
     block_b: int = DEFAULT_BLOCK_B,
     group_block: int = 1,
+    window_blocks: int | None = None,
     interpret: bool = False,
 ) -> tuple[jax.Array, ...]:
     """One fused Phase-2 round for a *cohort* of groups: the grid visits
@@ -257,8 +324,11 @@ def cohort_wirepath_round(
     ``gsel[j]*GB + k``.
 
     ``group_block > 1`` folds each selected block; the folded *enabled*
-    members of a block must share one BB-aligned watermark (the per-cohort
-    lockstep condition computed by ``core.plan.cohort_blocks``).
+    members of a block must share one watermark (the per-cohort lockstep
+    condition computed by ``core.plan.cohort_blocks``).  ``window_blocks``
+    is the number of ring blocks the grid visits per group block
+    (``core.plan.window_blocks``); ``None`` visits enough for a window at
+    any offset.
     ``enabled`` marks the cohort: non-members inside a selected block ride
     inert — round forced to NO_ROUND, watermark substituted with the
     block's enabled-lockstep base — and are written back bit-unchanged.
@@ -276,87 +346,69 @@ def cohort_wirepath_round(
     """
     g, a, n = st_rnd.shape
     c, b, v = values.shape
-    bb = min(block_b, b)
+    bb = ring_block(n, block_b)
     gb = group_block
     nb = gsel.shape[0]
-    assert b % bb == 0, (b, bb)
-    assert n % bb == 0, (n, bb)
     assert b <= n, "burst may not lap the instance ring"
     assert g % gb == 0, (g, gb)
     assert c == nb * gb, (c, nb, gb)
     nb_ring = n // bb
-    grid = (nb, b // bb)
+    nblk = window_blocks or _default_window_blocks(n, bb, b)
+    assert nblk <= nb_ring, (nblk, nb_ring)
+    grid = (nb, nblk)
 
     # Ring offset of a selected block comes from its first group's watermark;
     # with group_block == 1 that IS the group's own watermark, with
     # group_block > 1 the caller guarantees the folded enabled members are in
     # lockstep (and disabled members' watermarks are substituted below).
-    def ring2(gi, i, gsel_ref, ni_ref, *_):
-        gs = gsel_ref[gi]
-        return (gs, (ni_ref[gs * gb] // bb + i) % nb_ring)
-
-    def ring3(gi, i, gsel_ref, ni_ref, *_):
-        gs = gsel_ref[gi]
-        return (gs, (ni_ref[gs * gb] // bb + i) % nb_ring, 0)
+    def ring(gs, i, ni_ref):
+        return (ni_ref[gs * gb] // bb + i) % nb_ring
 
     def stack3(gi, i, gsel_ref, ni_ref, *_):
         gs = gsel_ref[gi]
-        return (gs, 0, (ni_ref[gs * gb] // bb + i) % nb_ring)
+        return (gs, 0, ring(gs, i, ni_ref))
 
     def stack4(gi, i, gsel_ref, ni_ref, *_):
         gs = gsel_ref[gi]
-        return (gs, 0, (ni_ref[gs * gb] // bb + i) % nb_ring, 0)
+        return (gs, 0, 0, ring(gs, i, ni_ref))
 
-    def batch2(gi, i, *_):
-        return (gi, i)
-
-    def batch3(gi, i, *_):
-        return (gi, i, 0)
-
-    def group1(gi, i, gsel_ref, *_):
-        return (gsel_ref[gi],)
-
-    def group2(gi, i, gsel_ref, *_):
-        return (gsel_ref[gi], 0)
+    def lanes3(gi, i, *_):
+        return (gi, 0, i)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((gb, bb, v), batch3),       # values (compact)
+            pl.BlockSpec((gb, v, bb), lanes3),       # values (compact)
             pl.BlockSpec((gb, a, bb), stack3),       # st_rnd
             pl.BlockSpec((gb, a, bb), stack3),       # st_vrnd
-            pl.BlockSpec((gb, a, bb, v), stack4),    # st_val
-            pl.BlockSpec((gb, bb), ring2),           # ldel
-            pl.BlockSpec((gb, bb), ring2),           # linst
-            pl.BlockSpec((gb, bb, v), ring3),        # lval
-            pl.BlockSpec((gb,), group1),             # ni (VMEM mirror)
-            pl.BlockSpec((gb,), group1),             # crnd (VMEM mirror)
-            pl.BlockSpec((gb, a), group2),           # alive (VMEM mirror)
-            pl.BlockSpec((gb,), group1),             # limit (VMEM mirror)
+            pl.BlockSpec((gb, a, v, bb), stack4),    # st_val
+            pl.BlockSpec((gb, 1, bb), stack3),       # ldel
+            pl.BlockSpec((gb, 1, bb), stack3),       # linst
+            pl.BlockSpec((gb, v, bb), stack3),       # lval
         ],
         out_specs=[
             pl.BlockSpec((gb, a, bb), stack3),       # st_rnd'
             pl.BlockSpec((gb, a, bb), stack3),       # st_vrnd'
-            pl.BlockSpec((gb, a, bb, v), stack4),    # st_val'
-            pl.BlockSpec((gb, bb), ring2),           # ldel'
-            pl.BlockSpec((gb, bb), ring2),           # linst'
-            pl.BlockSpec((gb, bb, v), ring3),        # lval'
-            pl.BlockSpec((gb, bb), batch2),          # fresh (compact)
-            pl.BlockSpec((gb, bb), batch2),          # win_vrnd (compact)
-            pl.BlockSpec((gb, bb, v), batch3),       # value (compact)
+            pl.BlockSpec((gb, a, v, bb), stack4),    # st_val'
+            pl.BlockSpec((gb, 1, bb), stack3),       # ldel'
+            pl.BlockSpec((gb, 1, bb), stack3),       # linst'
+            pl.BlockSpec((gb, v, bb), stack3),       # lval'
+            pl.BlockSpec((gb, 1, bb), lanes3),       # fresh (compact)
+            pl.BlockSpec((gb, 1, bb), lanes3),       # win_vrnd (compact)
+            pl.BlockSpec((gb, v, bb), lanes3),       # value (compact)
         ],
     )
     out_shapes = [
         jax.ShapeDtypeStruct((g, a, n), jnp.int32),
         jax.ShapeDtypeStruct((g, a, n), jnp.int32),
-        jax.ShapeDtypeStruct((g, a, n, v), jnp.int32),
-        jax.ShapeDtypeStruct((g, n), jnp.int32),
-        jax.ShapeDtypeStruct((g, n), jnp.int32),
-        jax.ShapeDtypeStruct((g, n, v), jnp.int32),
-        jax.ShapeDtypeStruct((c, b), jnp.int32),
-        jax.ShapeDtypeStruct((c, b), jnp.int32),
-        jax.ShapeDtypeStruct((c, b, v), jnp.int32),
+        jax.ShapeDtypeStruct((g, a, v, n), jnp.int32),
+        jax.ShapeDtypeStruct((g, 1, n), jnp.int32),
+        jax.ShapeDtypeStruct((g, 1, n), jnp.int32),
+        jax.ShapeDtypeStruct((g, v, n), jnp.int32),
+        jax.ShapeDtypeStruct((c, 1, nblk * bb), jnp.int32),
+        jax.ShapeDtypeStruct((c, 1, nblk * bb), jnp.int32),
+        jax.ShapeDtypeStruct((c, v, nblk * bb), jnp.int32),
     ]
     fn = pl.pallas_call(
         _cohort_wirepath_kernel,
@@ -365,6 +417,7 @@ def cohort_wirepath_round(
         # all five state arrays update in place: inputs 7..12 (after the 6
         # scalar-prefetch args) alias outputs 0..5 — device-resident state
         input_output_aliases={7: 0, 8: 1, 9: 2, 10: 3, 11: 4, 12: 5},
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )
     ni = jnp.asarray(next_inst, jnp.int32).reshape((g,))
@@ -389,23 +442,26 @@ def cohort_wirepath_round(
     q = jnp.asarray(quorum, jnp.int32).reshape((1,))
     al = jnp.asarray(alive, jnp.int32).reshape((g, a))
     gs = jnp.asarray(gsel, jnp.int32).reshape((nb,))
-    if limit is None:
-        # full permit: int32.max is an unreachable instance, so every lane
-        # passes the gate (never add N to a watermark here — it overflows)
-        lim = jnp.full((g,), jnp.iinfo(jnp.int32).max, jnp.int32)
-    else:
-        lim = jnp.asarray(limit, jnp.int32).reshape((g,))
-    return tuple(
-        fn(gs, ni, cr, q, al, lim, values, st_rnd, st_vrnd, st_val, ldel,
-           linst, lval, ni, cr, al, lim)
+    # the window ends the permit: no lane at or past base + B votes
+    lim = ni + b
+    if limit is not None:
+        lim = jnp.minimum(lim, jnp.asarray(limit, jnp.int32).reshape((g,)))
+    rows = (gs[:, None] * gb + jnp.arange(gb, dtype=jnp.int32)).reshape(c)
+    off = ni[rows] % bb
+    return _window_outputs(
+        fn(gs, ni, cr, q, al, lim, _to_ring_lanes(values, off, nblk * bb),
+           st_rnd, st_vrnd, _v_major(st_val), ldel[:, None], linst[:, None],
+           _v_major(lval)),
+        off, b,
     )
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_b", "group_block", "interpret")
+    jax.jit,
+    static_argnames=("block_b", "group_block", "window_blocks", "interpret"),
 )
 def multigroup_wirepath_round(
-    next_inst: jax.Array,   # int32[G]  per-group window base (BB-aligned)
+    next_inst: jax.Array,   # int32[G]  per-group window base
     crnd: jax.Array,        # int32[G]  per-group coordinator round
     quorum: jax.Array,      # int32[]
     alive: jax.Array,       # int32[G, A] (0/1)
@@ -421,6 +477,7 @@ def multigroup_wirepath_round(
     *,
     block_b: int = DEFAULT_BLOCK_B,
     group_block: int = 1,
+    window_blocks: int | None = None,
     interpret: bool = False,
 ) -> tuple[jax.Array, ...]:
     """One fused Phase-2 round for G device-resident groups; single dispatch.
@@ -429,7 +486,7 @@ def multigroup_wirepath_round(
     selected, so the compact value/output layout coincides with the
     ``(G, ...)`` layout.  ``group_block > 1`` folds that many groups into
     each grid step (see the module docstring); the folded *enabled* groups
-    of a block must share one BB-aligned watermark — the caller's
+    of a block must share one watermark — the caller's
     responsibility (``core.plan.fold_width_full`` picks the widest legal
     fold from the host watermark mirrors).  ``enabled`` is the vacant/
     frozen mask: disabled groups get their round forced to NO_ROUND and,
@@ -445,7 +502,8 @@ def multigroup_wirepath_round(
     return cohort_wirepath_round(
         gsel, next_inst, crnd, quorum, alive,
         st_rnd, st_vrnd, st_val, ldel, linst, lval, values, enabled, limit,
-        block_b=block_b, group_block=group_block, interpret=interpret,
+        block_b=block_b, group_block=group_block,
+        window_blocks=window_blocks, interpret=interpret,
     )
 
 
@@ -453,8 +511,7 @@ def multigroup_wirepath_round(
 # Persistent K-round entry: a whole wave of Phase-2 rounds per pallas_call
 # ---------------------------------------------------------------------------
 def _persistent_wirepath_kernel(
-    # scalar prefetch (SMEM) — consumed by the index maps; the body reads
-    # the same per-(round, group) values from the VMEM mirrors below
+    # scalar prefetch (SMEM): the index maps and the round body read them
     gsel_ref,       # int32[NB]    selected group-block indices (÷ GB)
     wni_ref,        # int32[K, G]  wave descriptor: per-round window bases
     crnd_ref,       # int32[G]     per-group coordinator round
@@ -463,58 +520,38 @@ def _persistent_wirepath_kernel(
     lim_ref,        # int32[G]     per-group reclaim limit
     wen_ref,        # int32[K, G]  wave descriptor: per-round enables
     # inputs (VMEM tiles)
-    values_ref,     # int32[1, GB, BB, V]  round k's burst values
+    values_ref,     # int32[GB, V, BB]     round k's burst values
     st_rnd_ref,     # int32[GB, A, BB]     acceptor ring blocks (aliased out)
     st_vrnd_ref,    # int32[GB, A, BB]
-    st_val_ref,     # int32[GB, A, BB, V]
-    ldel_ref,       # int32[GB, BB]        learner ring blocks (aliased out)
-    linst_ref,      # int32[GB, BB]
-    lval_ref,       # int32[GB, BB, V]
-    wniv_ref,       # int32[1, GB]  VMEM mirror of wni_ref's (round, block)
-    wenv_ref,       # int32[1, GB]  VMEM mirror of wen_ref's (round, block)
-    crndv_ref,      # int32[GB]     VMEM mirror of crnd_ref's block
-    alivev_ref,     # int32[GB, A]  VMEM mirror of alive_ref's block
-    limv_ref,       # int32[GB]     VMEM mirror of lim_ref's block
+    st_val_ref,     # int32[GB, A, V, BB]
+    ldel_ref,       # int32[GB, 1, BB]     learner ring blocks (aliased out)
+    linst_ref,      # int32[GB, 1, BB]
+    lval_ref,       # int32[GB, V, BB]
     # outputs
-    o_rnd_ref,      # int32[GB, A, BB]
-    o_vrnd_ref,     # int32[GB, A, BB]
-    o_val_ref,      # int32[GB, A, BB, V]
-    o_ldel_ref,     # int32[GB, BB]
-    o_linst_ref,    # int32[GB, BB]
-    o_lval_ref,     # int32[GB, BB, V]
-    fresh_ref,      # int32[1, GB, BB]
-    win_ref,        # int32[1, GB, BB]
-    value_ref,      # int32[1, GB, BB, V]
+    o_rnd_ref, o_vrnd_ref, o_val_ref, o_ldel_ref, o_linst_ref, o_lval_ref,
+    fresh_ref,      # int32[GB, 1, BB]  round k's rows
+    win_ref,        # int32[GB, 1, BB]
+    value_ref,      # int32[GB, V, BB]
 ):
-    # index-map inputs; body uses the mirrors
-    del gsel_ref, wni_ref, crnd_ref, alive_ref, lim_ref, wen_ref
-    i = pl.program_id(2)
-    _gb, _a, bb = st_rnd_ref.shape
+    kk = pl.program_id(0)
+    gb = st_rnd_ref.shape[0]
+    g0 = gsel_ref[pl.program_id(1)] * gb
 
-    ni_g = wniv_ref[0]                                             # (GB,)
-    # a group sitting out round k (wen == 0) rides the round inert: round
-    # presented as NO_ROUND so its acceptors reject every slot, its window
-    # (unchanged from its last enabled round) written back bit-identical
-    en_g = wenv_ref[0] != 0                                        # (GB,)
-    crnd_g = jnp.where(en_g, crndv_ref[...], jnp.int32(NO_ROUND))
-    inst = ni_g[:, None] + i * bb + _lane_iota(bb)[None, :]        # (GB, BB)
-    (
-        o_rnd_ref[...], o_vrnd_ref[...], o_val_ref[...],
-        o_ldel_ref[...], o_linst_ref[...], o_lval_ref[...],
-        fresh_ref[0], win_ref[0], value_ref[0],
-    ) = _phase2_block(
-        inst,
-        crnd_g,
-        alivev_ref[...] != 0,
-        limv_ref[...],
-        q_ref[0],
-        values_ref[0],
-        st_rnd_ref[...],
-        st_vrnd_ref[...],
-        st_val_ref[...],
-        ldel_ref[...],
-        linst_ref[...],
-        lval_ref[...],
+    def scalars(k):
+        g = g0 + k
+        # a group sitting out round kk (wen == 0) rides the round inert:
+        # round presented as NO_ROUND so its acceptors reject every slot,
+        # its window (unchanged from its last enabled round) written back
+        # bit-identical
+        crnd = jnp.where(wen_ref[kk, g] != 0, crnd_ref[g], NO_ROUND)
+        return wni_ref[kk, g], crnd, lambda j: alive_ref[g, j], lim_ref[g]
+
+    _phase2_rows(
+        pl.program_id(2), q_ref[0], scalars,
+        values_ref, st_rnd_ref, st_vrnd_ref, st_val_ref,
+        ldel_ref, linst_ref, lval_ref,
+        o_rnd_ref, o_vrnd_ref, o_val_ref, o_ldel_ref, o_linst_ref, o_lval_ref,
+        fresh_ref, win_ref, value_ref,
     )
 
 
@@ -555,7 +592,8 @@ def persistent_wirepath_round(
     The **wave descriptor** generalizes the cohort scalar-prefetch vectors
     to a per-round table:
 
-      * ``wni[k, g]`` — group ``g``'s window base at round ``k``.  The host
+      * ``wni[k, g]`` — group ``g``'s window base at round ``k``, a multiple
+        of the ring block ``ring_block(N, block_b)``, which must divide B.  The host
         precomputes the cumulative walk ``wni[k+1] = wni[k] + B·wen[k]``
         (and applies the folded-block base substitution per round), so the
         index maps stay pure lookups: block ``gi`` of round ``k`` maps its
@@ -580,11 +618,12 @@ def persistent_wirepath_round(
     """
     g, a, n = st_rnd.shape
     k, c, b, v = values.shape
-    bb = min(block_b, b)
+    bb = ring_block(n, block_b)
     gb = group_block
     nb = gsel.shape[0]
+    # block-aligned windows: consecutive rounds never share a ring block
+    # (a shared block would be read before the previous round's write lands)
     assert b % bb == 0, (b, bb)
-    assert n % bb == 0, (n, bb)
     assert k * b <= n, "persistent wave may not lap the instance ring"
     assert g % gb == 0, (g, gb)
     assert c == nb * gb, (c, nb, gb)
@@ -593,76 +632,55 @@ def persistent_wirepath_round(
     nb_ring = n // bb
     grid = (k, nb, b // bb)
 
-    def ring2(kk, gi, i, gsel_ref, wni_ref, *_):
-        gs = gsel_ref[gi]
-        return (gs, (wni_ref[kk, gs * gb] // bb + i) % nb_ring)
-
-    def ring3(kk, gi, i, gsel_ref, wni_ref, *_):
-        gs = gsel_ref[gi]
-        return (gs, (wni_ref[kk, gs * gb] // bb + i) % nb_ring, 0)
+    def ring(kk, gs, i, wni_ref):
+        return (wni_ref[kk, gs * gb] // bb + i) % nb_ring
 
     def stack3(kk, gi, i, gsel_ref, wni_ref, *_):
         gs = gsel_ref[gi]
-        return (gs, 0, (wni_ref[kk, gs * gb] // bb + i) % nb_ring)
+        return (gs, 0, ring(kk, gs, i, wni_ref))
 
     def stack4(kk, gi, i, gsel_ref, wni_ref, *_):
         gs = gsel_ref[gi]
-        return (gs, 0, (wni_ref[kk, gs * gb] // bb + i) % nb_ring, 0)
+        return (gs, 0, 0, ring(kk, gs, i, wni_ref))
 
-    def batch3(kk, gi, i, *_):
-        return (kk, gi, i)
-
-    def batch4(kk, gi, i, *_):
-        return (kk, gi, i, 0)
-
-    def wave2(kk, gi, i, gsel_ref, *_):
-        return (kk, gsel_ref[gi])
-
-    def group1(kk, gi, i, gsel_ref, *_):
-        return (gsel_ref[gi],)
-
-    def group2(kk, gi, i, gsel_ref, *_):
-        return (gsel_ref[gi], 0)
+    # per-round rows live at kk*NB*GB + compact row: block kk*NB + gi
+    def lanes3(kk, gi, i, *_):
+        return (kk * nb + gi, 0, i)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, gb, bb, v), batch4),    # values (compact, per-k)
+            pl.BlockSpec((gb, v, bb), lanes3),       # values (compact, per-k)
             pl.BlockSpec((gb, a, bb), stack3),       # st_rnd
             pl.BlockSpec((gb, a, bb), stack3),       # st_vrnd
-            pl.BlockSpec((gb, a, bb, v), stack4),    # st_val
-            pl.BlockSpec((gb, bb), ring2),           # ldel
-            pl.BlockSpec((gb, bb), ring2),           # linst
-            pl.BlockSpec((gb, bb, v), ring3),        # lval
-            pl.BlockSpec((1, gb), wave2),            # wni (VMEM mirror)
-            pl.BlockSpec((1, gb), wave2),            # wen (VMEM mirror)
-            pl.BlockSpec((gb,), group1),             # crnd (VMEM mirror)
-            pl.BlockSpec((gb, a), group2),           # alive (VMEM mirror)
-            pl.BlockSpec((gb,), group1),             # limit (VMEM mirror)
+            pl.BlockSpec((gb, a, v, bb), stack4),    # st_val
+            pl.BlockSpec((gb, 1, bb), stack3),       # ldel
+            pl.BlockSpec((gb, 1, bb), stack3),       # linst
+            pl.BlockSpec((gb, v, bb), stack3),       # lval
         ],
         out_specs=[
             pl.BlockSpec((gb, a, bb), stack3),       # st_rnd'
             pl.BlockSpec((gb, a, bb), stack3),       # st_vrnd'
-            pl.BlockSpec((gb, a, bb, v), stack4),    # st_val'
-            pl.BlockSpec((gb, bb), ring2),           # ldel'
-            pl.BlockSpec((gb, bb), ring2),           # linst'
-            pl.BlockSpec((gb, bb, v), ring3),        # lval'
-            pl.BlockSpec((1, gb, bb), batch3),       # fresh (compact, per-k)
-            pl.BlockSpec((1, gb, bb), batch3),       # win_vrnd
-            pl.BlockSpec((1, gb, bb, v), batch4),    # value
+            pl.BlockSpec((gb, a, v, bb), stack4),    # st_val'
+            pl.BlockSpec((gb, 1, bb), stack3),       # ldel'
+            pl.BlockSpec((gb, 1, bb), stack3),       # linst'
+            pl.BlockSpec((gb, v, bb), stack3),       # lval'
+            pl.BlockSpec((gb, 1, bb), lanes3),       # fresh (compact, per-k)
+            pl.BlockSpec((gb, 1, bb), lanes3),       # win_vrnd
+            pl.BlockSpec((gb, v, bb), lanes3),       # value
         ],
     )
     out_shapes = [
         jax.ShapeDtypeStruct((g, a, n), jnp.int32),
         jax.ShapeDtypeStruct((g, a, n), jnp.int32),
-        jax.ShapeDtypeStruct((g, a, n, v), jnp.int32),
-        jax.ShapeDtypeStruct((g, n), jnp.int32),
-        jax.ShapeDtypeStruct((g, n), jnp.int32),
-        jax.ShapeDtypeStruct((g, n, v), jnp.int32),
-        jax.ShapeDtypeStruct((k, c, b), jnp.int32),
-        jax.ShapeDtypeStruct((k, c, b), jnp.int32),
-        jax.ShapeDtypeStruct((k, c, b, v), jnp.int32),
+        jax.ShapeDtypeStruct((g, a, v, n), jnp.int32),
+        jax.ShapeDtypeStruct((g, 1, n), jnp.int32),
+        jax.ShapeDtypeStruct((g, 1, n), jnp.int32),
+        jax.ShapeDtypeStruct((g, v, n), jnp.int32),
+        jax.ShapeDtypeStruct((k * c, 1, b), jnp.int32),
+        jax.ShapeDtypeStruct((k * c, 1, b), jnp.int32),
+        jax.ShapeDtypeStruct((k * c, v, b), jnp.int32),
     ]
     fn = pl.pallas_call(
         _persistent_wirepath_kernel,
@@ -671,6 +689,7 @@ def persistent_wirepath_round(
         # state arrays update in place: inputs 8..13 (after the 7 scalar-
         # prefetch args) alias outputs 0..5 — device-resident across rounds
         input_output_aliases={8: 0, 9: 1, 10: 2, 11: 3, 12: 4, 13: 5},
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )
     cr = jnp.asarray(crnd, jnp.int32).reshape((g,))
@@ -697,9 +716,16 @@ def persistent_wirepath_round(
         lim = jnp.full((g,), jnp.iinfo(jnp.int32).max, jnp.int32)
     else:
         lim = jnp.asarray(limit, jnp.int32).reshape((g,))
-    return tuple(
-        fn(gs, wnik, cr, q, al, lim, wenk, values, st_rnd, st_vrnd, st_val,
-           ldel, linst, lval, wnik, wenk, cr, al, lim)
+    outs = fn(
+        gs, wnik, cr, q, al, lim, wenk,
+        _v_major(values).reshape(k * c, v, b),
+        st_rnd, st_vrnd, _v_major(st_val), ldel[:, None], linst[:, None],
+        _v_major(lval),
+    )
+    return _host_state(outs) + (
+        outs[6].reshape(k, c, b),
+        outs[7].reshape(k, c, b),
+        _v_major(outs[8].reshape(k, c, v, b)),
     )
 
 
@@ -721,6 +747,7 @@ def shard_slab_round(
     *,
     block_b: int = DEFAULT_BLOCK_B,
     group_block: int = 1,
+    window_blocks: int | None = None,
     interpret: bool = False,
 ) -> tuple[jax.Array, ...]:
     """Local-slab entry point for the groups-sharded dataplane (DESIGN.md §6).
@@ -760,7 +787,8 @@ def shard_slab_round(
     return multigroup_wirepath_round(
         ni, cr, quorum, al,
         st_rnd, st_vrnd, st_val, ldel, linst, lval, values, en, lim,
-        block_b=block_b, group_block=group_block, interpret=interpret,
+        block_b=block_b, group_block=group_block,
+        window_blocks=window_blocks, interpret=interpret,
     )
 
 
@@ -768,18 +796,52 @@ def shard_slab_round(
 # Packed ragged-shard entry: C resident lanes, slab rows routed by segment id
 # ---------------------------------------------------------------------------
 def _packed_shard_kernel(
-    ni_ref, crnd_ref, q_ref, alive_ref, lim_ref, seg_ref, *rest
+    # scalar prefetch (SMEM), all per LANE: the index maps route each lane
+    # to its slab row, the round body reads the lane's scalars
+    ni_ref,         # int32[C]     per-lane window base
+    crnd_ref,       # int32[C]     per-lane coordinator round
+    q_ref,          # int32[1]     quorum (f+1)
+    alive_ref,      # int32[C, A]  per-lane liveness row
+    lim_ref,        # int32[C]     per-lane reclaim limit
+    seg_ref,        # int32[C]     per-lane slab row (index maps only)
+    # inputs (VMEM tiles)
+    values_ref,     # int32[1, V, BB]      the lane's burst values
+    st_rnd_ref,     # int32[1, A, BB]      acceptor ring blocks (aliased out)
+    st_vrnd_ref,    # int32[1, A, BB]
+    st_val_ref,     # int32[1, A, V, BB]
+    ldel_ref,       # int32[1, 1, BB]      learner ring blocks (aliased out)
+    linst_ref,      # int32[1, 1, BB]
+    lval_ref,       # int32[1, V, BB]
+    # outputs
+    o_rnd_ref, o_vrnd_ref, o_val_ref, o_ldel_ref, o_linst_ref, o_lval_ref,
+    fresh_ref,      # int32[1, 1, BB]  (packed lanes)
+    win_ref,        # int32[1, 1, BB]
+    value_ref,      # int32[1, V, BB]
 ):
-    # ``seg_ref`` is consumed by the index maps only — it routes each packed
-    # lane to its resident slab row; the round body is the shared one
     del seg_ref
-    _mg_wirepath_kernel(ni_ref, crnd_ref, q_ref, alive_ref, lim_ref, *rest)
+    lane = pl.program_id(0)
+
+    def scalars(_k):
+        return (
+            ni_ref[lane], crnd_ref[lane],
+            lambda j: alive_ref[lane, j], lim_ref[lane],
+        )
+
+    _phase2_rows(
+        pl.program_id(1), q_ref[0], scalars,
+        values_ref, st_rnd_ref, st_vrnd_ref, st_val_ref,
+        ldel_ref, linst_ref, lval_ref,
+        o_rnd_ref, o_vrnd_ref, o_val_ref, o_ldel_ref, o_linst_ref, o_lval_ref,
+        fresh_ref, win_ref, value_ref,
+    )
 
 
-@functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
+@functools.partial(
+    jax.jit, static_argnames=("block_b", "window_blocks", "interpret")
+)
 def packed_shard_round(
     segids: jax.Array,      # int32[C]  per-lane local slab row (0..Gl)
-    next_inst: jax.Array,   # int32[C]  per-lane window base (BB-aligned)
+    next_inst: jax.Array,   # int32[C]  per-lane window base
     crnd: jax.Array,        # int32[C]  per-lane coordinator round
     quorum: jax.Array,      # int32[]
     alive: jax.Array,       # int32[C, A] (0/1)
@@ -794,6 +856,7 @@ def packed_shard_round(
     limit: jax.Array | None = None,    # int32[C]; None = no reclamation
     *,
     block_b: int = DEFAULT_BLOCK_B,
+    window_blocks: int | None = None,
     interpret: bool = False,
 ) -> tuple[jax.Array, ...]:
     """One fused Phase-2 round over a shard's *packed* lane table: the grid
@@ -827,81 +890,65 @@ def packed_shard_round(
     """
     gl, a, n = st_rnd.shape
     c, b, v = values.shape
-    bb = min(block_b, b)
-    assert b % bb == 0, (b, bb)
-    assert n % bb == 0, (n, bb)
+    bb = ring_block(n, block_b)
     assert b <= n, "burst may not lap the instance ring"
     assert c <= gl, (
         "packed lane count may not exceed the slab height (pad redirection "
         "needs a free row whenever pads exist)", c, gl,
     )
     nb_ring = n // bb
-    grid = (c, b // bb)
+    nblk = window_blocks or _default_window_blocks(n, bb, b)
+    assert nblk <= nb_ring, (nblk, nb_ring)
+    grid = (c, nblk)
 
     # Each lane's ring offset comes from its OWN watermark; its slab row
     # from its segment id — both per-lane prefetch lookups.
-    def ring2(gi, i, ni_ref, cr_ref, q_ref, al_ref, lim_ref, seg_ref):
-        return (seg_ref[gi], (ni_ref[gi] // bb + i) % nb_ring)
-
-    def ring3(gi, i, ni_ref, cr_ref, q_ref, al_ref, lim_ref, seg_ref):
-        return (seg_ref[gi], (ni_ref[gi] // bb + i) % nb_ring, 0)
+    def ring(gi, i, ni_ref):
+        return (ni_ref[gi] // bb + i) % nb_ring
 
     def stack3(gi, i, ni_ref, cr_ref, q_ref, al_ref, lim_ref, seg_ref):
-        return (seg_ref[gi], 0, (ni_ref[gi] // bb + i) % nb_ring)
+        return (seg_ref[gi], 0, ring(gi, i, ni_ref))
 
     def stack4(gi, i, ni_ref, cr_ref, q_ref, al_ref, lim_ref, seg_ref):
-        return (seg_ref[gi], 0, (ni_ref[gi] // bb + i) % nb_ring, 0)
+        return (seg_ref[gi], 0, 0, ring(gi, i, ni_ref))
 
-    def batch2(gi, i, *_):
-        return (gi, i)
-
-    def batch3(gi, i, *_):
-        return (gi, i, 0)
-
-    def lane1(gi, i, *_):
-        return (gi,)
-
-    def lane2(gi, i, *_):
-        return (gi, 0)
+    def lanes3(gi, i, *_):
+        return (gi, 0, i)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bb, v), batch3),        # values (packed)
+            pl.BlockSpec((1, v, bb), lanes3),        # values (packed)
             pl.BlockSpec((1, a, bb), stack3),        # st_rnd
             pl.BlockSpec((1, a, bb), stack3),        # st_vrnd
-            pl.BlockSpec((1, a, bb, v), stack4),     # st_val
-            pl.BlockSpec((1, bb), ring2),            # ldel
-            pl.BlockSpec((1, bb), ring2),            # linst
-            pl.BlockSpec((1, bb, v), ring3),         # lval
-            pl.BlockSpec((1,), lane1),               # ni (VMEM mirror)
-            pl.BlockSpec((1,), lane1),               # crnd (VMEM mirror)
-            pl.BlockSpec((1, a), lane2),             # alive (VMEM mirror)
-            pl.BlockSpec((1,), lane1),               # limit (VMEM mirror)
+            pl.BlockSpec((1, a, v, bb), stack4),     # st_val
+            pl.BlockSpec((1, 1, bb), stack3),        # ldel
+            pl.BlockSpec((1, 1, bb), stack3),        # linst
+            pl.BlockSpec((1, v, bb), stack3),        # lval
         ],
         out_specs=[
             pl.BlockSpec((1, a, bb), stack3),        # st_rnd'
             pl.BlockSpec((1, a, bb), stack3),        # st_vrnd'
-            pl.BlockSpec((1, a, bb, v), stack4),     # st_val'
-            pl.BlockSpec((1, bb), ring2),            # ldel'
-            pl.BlockSpec((1, bb), ring2),            # linst'
-            pl.BlockSpec((1, bb, v), ring3),         # lval'
-            pl.BlockSpec((1, bb), batch2),           # fresh (packed)
-            pl.BlockSpec((1, bb), batch2),           # win_vrnd (packed)
-            pl.BlockSpec((1, bb, v), batch3),        # value (packed)
+            pl.BlockSpec((1, a, v, bb), stack4),     # st_val'
+            pl.BlockSpec((1, 1, bb), stack3),        # ldel'
+            pl.BlockSpec((1, 1, bb), stack3),        # linst'
+            pl.BlockSpec((1, v, bb), stack3),        # lval'
+            pl.BlockSpec((1, 1, bb), lanes3),        # fresh (packed)
+            pl.BlockSpec((1, 1, bb), lanes3),        # win_vrnd (packed)
+            pl.BlockSpec((1, v, bb), lanes3),        # value (packed)
         ],
     )
     out_shapes = [
         jax.ShapeDtypeStruct((gl, a, n), jnp.int32),
         jax.ShapeDtypeStruct((gl, a, n), jnp.int32),
-        jax.ShapeDtypeStruct((gl, a, n, v), jnp.int32),
-        jax.ShapeDtypeStruct((gl, n), jnp.int32),
-        jax.ShapeDtypeStruct((gl, n), jnp.int32),
-        jax.ShapeDtypeStruct((gl, n, v), jnp.int32),
-        jax.ShapeDtypeStruct((c, b), jnp.int32),
-        jax.ShapeDtypeStruct((c, b), jnp.int32),
-        jax.ShapeDtypeStruct((c, b, v), jnp.int32),
+        jax.ShapeDtypeStruct((gl, a, v, n), jnp.int32),
+        jax.ShapeDtypeStruct((gl, 1, n), jnp.int32),
+        jax.ShapeDtypeStruct((gl, 1, n), jnp.int32),
+        jax.ShapeDtypeStruct((gl, v, n), jnp.int32),
+        jax.ShapeDtypeStruct((c, 1, nblk * bb), jnp.int32),
+        jax.ShapeDtypeStruct((c, 1, nblk * bb), jnp.int32),
+        jax.ShapeDtypeStruct((c, v, nblk * bb), jnp.int32),
     ]
     fn = pl.pallas_call(
         _packed_shard_kernel,
@@ -910,6 +957,7 @@ def packed_shard_round(
         # all five state slabs update in place: inputs 7..12 (after the 6
         # scalar-prefetch args) alias outputs 0..5 — device-resident state
         input_output_aliases={7: 0, 8: 1, 9: 2, 10: 3, 11: 4, 12: 5},
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )
     ni = jnp.asarray(next_inst, jnp.int32).reshape((c,))
@@ -932,19 +980,24 @@ def packed_shard_round(
         ni = jnp.where(en, ni, 0)
     q = jnp.asarray(quorum, jnp.int32).reshape((1,))
     al = jnp.asarray(alive, jnp.int32).reshape((c, a))
-    if limit is None:
-        lim = jnp.full((c,), jnp.iinfo(jnp.int32).max, jnp.int32)
-    else:
-        lim = jnp.asarray(limit, jnp.int32).reshape((c,))
-    return tuple(
-        fn(ni, cr, q, al, lim, seg, values, st_rnd, st_vrnd, st_val, ldel,
-           linst, lval, ni, cr, al, lim)
+    # the window ends the permit: no lane at or past base + B votes
+    lim = ni + b
+    if limit is not None:
+        lim = jnp.minimum(lim, jnp.asarray(limit, jnp.int32).reshape((c,)))
+    off = ni % bb
+    return _window_outputs(
+        fn(ni, cr, q, al, lim, seg, _to_ring_lanes(values, off, nblk * bb),
+           st_rnd, st_vrnd, _v_major(st_val), ldel[:, None], linst[:, None],
+           _v_major(lval)),
+        off, b,
     )
 
 
-@functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
+@functools.partial(
+    jax.jit, static_argnames=("block_b", "window_blocks", "interpret")
+)
 def wirepath_round(
-    next_inst: jax.Array,   # int32[]  absolute window base (BB-aligned)
+    next_inst: jax.Array,   # int32[]  absolute window base
     crnd: jax.Array,        # int32[]
     quorum: jax.Array,      # int32[]
     alive: jax.Array,       # int32[A] (0/1)
@@ -958,6 +1011,7 @@ def wirepath_round(
     limit: jax.Array | None = None,  # int32[]; None = no reclamation
     *,
     block_b: int = DEFAULT_BLOCK_B,
+    window_blocks: int | None = None,
     interpret: bool = False,
 ) -> tuple[jax.Array, ...]:
     """One fused Phase-2 round for a single group: the G=1 slice of
@@ -981,6 +1035,7 @@ def wirepath_round(
         None,
         None if limit is None else jnp.asarray(limit, jnp.int32).reshape((1,)),
         block_b=block_b,
+        window_blocks=window_blocks,
         interpret=interpret,
     )
     return tuple(x[0] for x in outs)
@@ -992,42 +1047,47 @@ def wirepath_round(
 def _vote_all_kernel(
     base_ref,       # int32[1]  window base slot (BB-aligned)
     alive_ref,      # int32[A]
-    msgtype_ref,    # int32[BB]
-    msg_rnd_ref,    # int32[BB]
-    msg_val_ref,    # int32[BB, V]
+    msgtype_ref,    # int32[1, BB]
+    msg_rnd_ref,    # int32[1, BB]
+    msg_val_ref,    # int32[V, BB]
     st_rnd_ref,     # int32[A, BB]  (aliased out)
     st_vrnd_ref,    # int32[A, BB]
-    st_val_ref,     # int32[A, BB, V]
+    st_val_ref,     # int32[A, V, BB]
     o_rnd_ref,      # int32[A, BB]
     o_vrnd_ref,     # int32[A, BB]
-    o_val_ref,      # int32[A, BB, V]
+    o_val_ref,      # int32[A, V, BB]
     vt_ref,         # int32[A, BB]  vote msgtype
     vr_ref,         # int32[A, BB]  vote rnd
     vv_ref,         # int32[A, BB]  vote vrnd
     vs_ref,         # int32[A, BB]  vote swid
-    vval_ref,       # int32[A, BB, V]
+    vval_ref,       # int32[A, V, BB]
 ):
     a, bb = st_rnd_ref.shape
-    msgtype = msgtype_ref[...]
-    mrnd = msg_rnd_ref[...]
+    msgtype = msgtype_ref[...]                                   # (1, BB)
+    mrnd = msg_rnd_ref[...]                                      # (1, BB)
     mval = msg_val_ref[...]
     cur_rnd = st_rnd_ref[...]
     cur_vrnd = st_vrnd_ref[...]
-    cur_val = st_val_ref[...]
 
-    alive = _alive_col(alive_ref, a)                             # (A, 1)
-    is_p2 = (msgtype == MSG_P2A) | (msgtype == MSG_NOP)          # (BB,)
-    accept = alive & is_p2[None, :] & (mrnd[None, :] >= cur_rnd)  # (A, BB)
+    is_p2 = (msgtype == MSG_P2A) | (msgtype == MSG_NOP)          # (1, BB)
+    accept = (
+        _alive_rows(lambda j: alive_ref[j], a, bb)
+        * is_p2.astype(jnp.int32)
+        * (mrnd >= cur_rnd).astype(jnp.int32)
+    )                                                            # (A, BB)
+    acc = accept != 0
 
-    o_rnd_ref[...] = jnp.where(accept, mrnd[None, :], cur_rnd)
-    o_vrnd_ref[...] = jnp.where(accept, mrnd[None, :], cur_vrnd)
-    o_val_ref[...] = jnp.where(accept[:, :, None], mval[None], cur_val)
+    o_rnd_ref[...] = jnp.where(acc, mrnd, cur_rnd)
+    o_vrnd_ref[...] = jnp.where(acc, mrnd, cur_vrnd)
+    for j in range(a):
+        acc_j = accept[j:j + 1] != 0                             # (1, BB)
+        o_val_ref[j] = jnp.where(acc_j, mval, st_val_ref[j])
+        vval_ref[j] = jnp.where(acc_j, mval, 0)
 
-    vt_ref[...] = jnp.where(accept, MSG_P2B, MSG_REJECT).astype(jnp.int32)
-    vr_ref[...] = jnp.where(accept, mrnd[None, :], cur_rnd)
-    vv_ref[...] = jnp.where(accept, mrnd[None, :], cur_vrnd)
+    vt_ref[...] = jnp.where(acc, MSG_P2B, MSG_REJECT).astype(jnp.int32)
+    vr_ref[...] = jnp.where(acc, mrnd, cur_rnd)
+    vv_ref[...] = jnp.where(acc, mrnd, cur_vrnd)
     vs_ref[...] = jax.lax.broadcasted_iota(jnp.int32, (a, bb), 0)
-    vval_ref[...] = jnp.where(accept[:, :, None], mval[None], 0)
 
 
 @functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
@@ -1047,15 +1107,15 @@ def acceptor_vote_all_window(
     """Whole-array Phase-2 vote on a contiguous window, one dispatch.
 
     The staged sibling of ``wirepath_round`` for when votes must surface as
-    messages (per-learner fan-out over SimNet).  Returns
+    messages (per-learner fan-out over SimNet).  The window must be
+    block-aligned: ``ring_block(N, block_b)`` divides ``base`` and B.  Returns
     ``(st_rnd', st_vrnd', st_val', vote_type[A,B], vote_rnd[A,B],
     vote_vrnd[A,B], vote_swid[A,B], vote_val[A,B,V])``.
     """
     a, n = st_rnd.shape
     b, v = msg_val.shape
-    bb = min(block_b, b)
+    bb = ring_block(n, block_b)
     assert b % bb == 0, (b, bb)
-    assert n % bb == 0, (n, bb)
     assert b <= n, "burst may not lap the instance ring"
     nb_ring = n // bb
     grid = (b // bb,)
@@ -1064,51 +1124,45 @@ def acceptor_vote_all_window(
         return (0, (base_ref[0] // bb + i) % nb_ring)
 
     def stack3(i, base_ref, *_):
-        return (0, (base_ref[0] // bb + i) % nb_ring, 0)
+        return (0, 0, (base_ref[0] // bb + i) % nb_ring)
 
     def vote2(i, *_):
         return (0, i)
 
     def vote3(i, *_):
-        return (0, i, 0)
-
-    def batch1(i, *_):
-        return (i,)
-
-    def batch2(i, *_):
-        return (i, 0)
+        return (0, 0, i)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bb,), batch1),         # msgtype
-            pl.BlockSpec((bb,), batch1),         # msg_rnd
-            pl.BlockSpec((bb, v), batch2),       # msg_val
+            pl.BlockSpec((1, bb), vote2),        # msgtype
+            pl.BlockSpec((1, bb), vote2),        # msg_rnd
+            pl.BlockSpec((v, bb), vote2),        # msg_val
             pl.BlockSpec((a, bb), stack2),       # st_rnd
             pl.BlockSpec((a, bb), stack2),       # st_vrnd
-            pl.BlockSpec((a, bb, v), stack3),    # st_val
+            pl.BlockSpec((a, v, bb), stack3),    # st_val
         ],
         out_specs=[
             pl.BlockSpec((a, bb), stack2),       # st_rnd'
             pl.BlockSpec((a, bb), stack2),       # st_vrnd'
-            pl.BlockSpec((a, bb, v), stack3),    # st_val'
+            pl.BlockSpec((a, v, bb), stack3),    # st_val'
             pl.BlockSpec((a, bb), vote2),        # vote_type
             pl.BlockSpec((a, bb), vote2),        # vote_rnd
             pl.BlockSpec((a, bb), vote2),        # vote_vrnd
             pl.BlockSpec((a, bb), vote2),        # vote_swid
-            pl.BlockSpec((a, bb, v), vote3),     # vote_val
+            pl.BlockSpec((a, v, bb), vote3),     # vote_val
         ],
     )
     out_shapes = [
         jax.ShapeDtypeStruct((a, n), jnp.int32),
         jax.ShapeDtypeStruct((a, n), jnp.int32),
-        jax.ShapeDtypeStruct((a, n, v), jnp.int32),
+        jax.ShapeDtypeStruct((a, v, n), jnp.int32),
         jax.ShapeDtypeStruct((a, b), jnp.int32),
         jax.ShapeDtypeStruct((a, b), jnp.int32),
         jax.ShapeDtypeStruct((a, b), jnp.int32),
         jax.ShapeDtypeStruct((a, b), jnp.int32),
-        jax.ShapeDtypeStruct((a, b, v), jnp.int32),
+        jax.ShapeDtypeStruct((a, v, b), jnp.int32),
     ]
     fn = pl.pallas_call(
         _vote_all_kernel,
@@ -1116,8 +1170,18 @@ def acceptor_vote_all_window(
         out_shape=out_shapes,
         # stacked rings in place: inputs 5,6,7 alias outputs 0,1,2
         input_output_aliases={5: 0, 6: 1, 7: 2},
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )
     base = jnp.asarray(base, jnp.int32).reshape((1,))
-    al = jnp.asarray(alive, jnp.int32)
-    return tuple(fn(base, al, msgtype, msg_rnd, msg_val, st_rnd, st_vrnd, st_val))
+    al = jnp.asarray(alive, jnp.int32).reshape((a,))
+    # (B,) header vectors ride as (1, B) — a one-row block of a 2-D array
+    # is legal at any B, a 1-D block is not (XLA tiles s32[B] by 1024) —
+    # and value words V-major, as in the fused kernels
+    outs = fn(
+        base, al, msgtype.reshape(1, b), msg_rnd.reshape(1, b),
+        _v_major(msg_val), st_rnd, st_vrnd, _v_major(st_val),
+    )
+    return (
+        outs[0], outs[1], _v_major(outs[2]), *outs[3:7], _v_major(outs[7]),
+    )

@@ -13,12 +13,19 @@ parallelism with hierarchical gradient reduction.  ``data`` is the FSDP axis
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> jax.sharding.Mesh:
+    # jax.make_mesh defaults to Explicit axes; every program here places
+    # data with NamedSharding and shard_map, which want Auto axes
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(n_devices: int = 0, model_parallel: int = 1) -> jax.sharding.Mesh:
@@ -26,7 +33,7 @@ def make_host_mesh(n_devices: int = 0, model_parallel: int = 1) -> jax.sharding.
     n = n_devices or len(jax.devices())
     mp = model_parallel
     assert n % mp == 0
-    return jax.make_mesh((n // mp, mp), ("data", "model"))
+    return _auto_mesh((n // mp, mp), ("data", "model"))
 
 
 def make_group_mesh(n_devices: int = 0) -> jax.sharding.Mesh:
@@ -47,4 +54,4 @@ def make_group_mesh(n_devices: int = 0) -> jax.sharding.Mesh:
     tenancy, not current tenancy.
     """
     n = n_devices or len(jax.devices())
-    return jax.make_mesh((n,), ("groups",))
+    return _auto_mesh((n,), ("groups",))

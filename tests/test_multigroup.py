@@ -91,11 +91,18 @@ def test_group_failover_does_not_perturb_others(use_kernels):
 
     mg.restore_hardware_coordinator(group=victim)
     singles[victim].restore_hardware_coordinator()
+    planes = [mg.hw] + [ctx.hw for ctx in singles]
+    jnp_before = [hw.jnp_dispatch_count for hw in planes]
 
     _run_schedule(mg, range(G), waves=2, use_groups=True)
     for gid, ctx in enumerate(singles):
         _run_schedule(ctx, [gid], waves=2, use_groups=False)
 
+    # the restored watermark is wherever the software coordinator left it;
+    # hardware-sequenced rounds run it on the kernel path all the same
+    jnp_after = [hw.jnp_dispatch_count for hw in planes]
+    if use_kernels:
+        assert jnp_after == jnp_before
     for gid, ctx in enumerate(singles):
         assert mg.group_log[gid] == ctx.delivered_log, gid
         for a, b in zip(_group_state(mg.hw, gid), _group_state(ctx.hw, gid), strict=True):
@@ -255,8 +262,8 @@ def test_vacant_slot_rides_folded_dispatch_inert(use_kernels):
     assert ctx.create_group() == 0
     assert ctx.hw.next_inst_host == [0, 8, 8, 8]
     # a burst over groups 1..3 (group 0 idle): enabled lockstep folds wide
-    enabled, use_k, gb = ctx.hw._plan_round(8, [False, True, True, True])
-    assert gb == 4 and use_k == use_kernels
+    enabled, nblk, gb = ctx.hw._plan_round(8, [False, True, True, True])
+    assert gb == 4 and (nblk is not None) == use_kernels
     vacant_before = [np.asarray(x) for x in jax.tree_util.tree_leaves(
         jax.tree_util.tree_map(lambda s: s[0], (ctx.hw.stack, ctx.hw.lstate))
     )]

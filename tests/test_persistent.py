@@ -194,7 +194,19 @@ def _mk_plane(use_kernels, sharded, cfg):
 @pytest.mark.parametrize("use_kernels", [False, True])
 @pytest.mark.parametrize("sharded", [False, True])
 def test_pipeline_persistent_equals_k_cohorts(use_kernels, sharded):
-    g, n, be, v, k = 2, 128, 16, 4, 3
+    # a wave that fills the whole ring (K * BE = N) without lapping it
+    _check_persistent_equals_k_cohorts(use_kernels, sharded, n=256, be=128, k=2)
+
+
+@pytest.mark.parametrize("use_kernels", [False, True])
+@pytest.mark.parametrize("sharded", [False, True])
+def test_block_aligned_persistent_equals_k_cohorts(use_kernels, sharded):
+    # block-aligned rounds: the kernel path runs the persistent wave kernel
+    _check_persistent_equals_k_cohorts(use_kernels, sharded, n=512, be=128, k=3)
+
+
+def _check_persistent_equals_k_cohorts(use_kernels, sharded, n, be, k):
+    g, v = 2, 4
     cfg = PaxosConfig(
         n_acceptors=A, n_instances=n, value_words=v, batch=be, n_groups=g
     )
@@ -220,6 +232,7 @@ def test_pipeline_persistent_equals_k_cohorts(use_kernels, sharded):
     # one device launch per wave — except the documented sharded K=1
     # fallback, which dispatches per round
     assert hw_p.dispatch_count == (k if sharded else 1)
+    assert hw_p.persistent_dispatch_count == (0 if sharded else 1)
     assert hw_s.dispatch_count == k
 
 
@@ -235,20 +248,33 @@ def test_pipeline_persistent_rejects_ring_lap():
         hw.pipeline_persistent((0,), vals, act)
 
 
+def test_pipeline_persistent_kernel_refuses_off_block_wave():
+    # rounds of 16 slots share 128-slot ring blocks: the persistent kernel
+    # cannot run them, and the dataplane refuses before any state moves
+    cfg = PaxosConfig(
+        n_acceptors=A, n_instances=128, value_words=4, batch=16, n_groups=2
+    )
+    hw = MultiGroupDataplane(cfg, use_kernels=True)
+    vals, act = _wave_values(np.random.default_rng(5), 3, 2, 16, 4)
+    with pytest.raises(ValueError, match="ring-block"):
+        hw.pipeline_persistent((0, 1), vals, act)
+    assert hw.dispatch_count == 0 and hw.next_inst_host == [0, 0]
+
+
 # ---------------------------------------------------------------------------
 # 4. Pump: persistent waves + async double-buffering vs the serial reference
 # ---------------------------------------------------------------------------
 def _run_ctx(use_kernels, mesh, pr, async_pump, n_extra=0):
     cfg = PaxosConfig(
-        n_acceptors=A, n_instances=1 << 10, value_words=4, batch=32,
+        n_acceptors=A, n_instances=1 << 10, value_words=4, batch=128,
         n_groups=2, persistent_rounds=pr, async_pump=async_pump,
     )
     ctx = PaxosContext(cfg, use_kernels=use_kernels, mesh=mesh)
     # group 0 deep enough for multi-round waves, group 1 a ragged tail —
     # the wave loop mints mixed cohorts and a trailing sub-batch burst
-    for i in range(130):
+    for i in range(520):
         ctx.submit(f"a{i:04d}".encode(), group=0)
-    for i in range(45):
+    for i in range(180):
         ctx.submit(f"b{i:04d}".encode(), group=1)
     ctx.run_until_quiescent()
     for i in range(n_extra):
@@ -270,11 +296,12 @@ def test_pump_persistent_waves_bit_identical_four_backends(use_kernels, sharded)
 
 
 def test_pump_dispatch_count_one_launch_per_wave():
-    # 130 submits / batch 32 -> one K=4 persistent wave (128) + one
-    # 2-row tail burst = 2 launches; the K=1 pump needs 5
+    # 520 submits / batch 128 -> one K=4 persistent wave (512) + one
+    # 8-row tail burst = 2 launches; the K=1 pump needs 5
     ctx = _run_ctx(True, None, pr=4, async_pump=True)
     assert ctx.hw.dispatch_count == 2 + 2  # group-1 traffic adds 2 bursts
     assert ctx.planner.stats["persistent_waves"] == 1
+    assert ctx.hw.persistent_dispatch_count == 1
     ref = _run_ctx(True, None, pr=1, async_pump=False)
     assert ref.planner.stats["persistent_waves"] == 0
     assert ctx.hw.dispatch_count < ref.hw.dispatch_count
@@ -294,7 +321,7 @@ def test_async_pump_overlap_with_midstream_submissions():
     logs = {}
     for ap in (True, False):
         cfg = PaxosConfig(
-            n_acceptors=A, n_instances=1 << 10, value_words=4, batch=32,
+            n_acceptors=A, n_instances=1 << 10, value_words=4, batch=128,
             n_groups=2, persistent_rounds=4, async_pump=ap,
         )
         fired = []
@@ -302,15 +329,15 @@ def test_async_pump_overlap_with_midstream_submissions():
         def follow_up(payload, size, inst):
             if payload == b"a0000" and not fired:
                 fired.append(inst)
-                for j in range(40):
+                for j in range(160):
                     ctx.submit(f"f{j:04d}".encode(), group=1)
 
         ctx = PaxosContext(cfg, deliver=follow_up)
-        for i in range(96):
+        for i in range(384):
             ctx.submit(f"a{i:04d}".encode(), group=0)
         ctx.run_until_quiescent()
         assert ctx.quiescent()
         assert fired, "overlap callback never fired"
         logs[ap] = ctx.group_log
     assert logs[True] == logs[False]
-    assert len(logs[True][1]) == 40  # the mid-drain follow-ups all landed
+    assert len(logs[True][1]) == 160  # the mid-drain follow-ups all landed

@@ -63,6 +63,38 @@ def test_fold_width_full_generalizes_the_binary_cliff():
     assert fold_width_full([], [0, 1, 2, 3], 4) == 4
 
 
+@pytest.mark.parametrize(
+    "n,bases,b,want",
+    [
+        (512, [0], 128, 1),          # aligned: exactly the window's block
+        (512, [8], 128, 2),          # off the boundary: one block more
+        (512, [120, 0], 8, 1),       # a short burst inside one block
+        (512, [124], 8, 2),          # ...or straddling two
+        (65536, [3], 8192, 65),
+        (64, [56], 8, 1),            # a short ring is one block
+        (64, [60], 8, None),         # wraps onto its own block: jnp
+    ],
+)
+def test_window_blocks_cover_any_window(n, bases, b, want):
+    assert plan_mod.window_blocks(n, bases, b) == want
+    assert plan_mod.blocks_aligned(n, bases, b) == (
+        want == 1 and b % plan_mod.ring_block(n) == 0
+        and all(x % plan_mod.ring_block(n) == 0 for x in bases)
+    )
+
+
+def test_fold_cap_bounds_a_grid_step():
+    # a grid step holds at most MAX_FOLD_LANES group-slots
+    assert plan_mod.fold_cap(256, 65536, 3, 16) == plan_mod.MAX_FOLD_LANES // 128
+    assert plan_mod.fold_cap(256, 65536, 5, 16) == plan_mod.MAX_FOLD_LANES // 128
+    assert plan_mod.fold_cap(8, 65536, 3, 16) == 8
+    assert plan_mod.fold_cap(96, 65536, 3, 16) == 48      # a divisor of G
+    assert plan_mod.fold_cap(4, 64, 3, 16) == 4
+    # more acceptors or value words than the compiled shape: fewer groups
+    assert plan_mod.fold_cap(64, 65536, 7, 16) == 32
+    assert plan_mod.fold_cap(64, 65536, 3, 64) == 16
+
+
 def test_cohort_blocks_compacts_the_group_axis():
     marks = [0] * 8
     # a single hot group: one width-1 block, not a full-width sweep
@@ -331,15 +363,23 @@ def test_report_snapshots_service_loads_not_aliases():
 
 def test_wave_depth_policy_full_batch_and_covered_queues_only():
     """The planner mints K > 1 only for full-batch cohorts whose every
-    member has K full chunks queued, clamped by the policy knob and the
-    ring (DESIGN.md §11)."""
-    p = DispatchPlanner(batch=32, n_instances=128, persistent_rounds=8)
+    member has K full chunks queued at a ring-block-aligned watermark,
+    clamped by the policy knob and the ring (DESIGN.md §11)."""
+    p = DispatchPlanner(batch=128, n_instances=512, persistent_rounds=8)
     rp = p.plan_round(
-        loads=[32, 32], marks=[0, 0], live=[True] * 2, crnd=[0, 0],
-        pending=[160, 96],
+        loads=[128, 128], marks=[0, 0], live=[True] * 2, crnd=[0, 0],
+        pending=[640, 384],
     )
-    # min(160, 96) // 32 = 3 full chunks each; ring cap 128 // 32 = 4
-    assert rp.cohorts == (plan_mod.Cohort(gids=(0, 1), burst=32, rounds=3),)
+    # min(640, 384) // 128 = 3 full chunks each; ring cap 512 // 128 = 4
+    assert rp.cohorts == (plan_mod.Cohort(gids=(0, 1), burst=128, rounds=3),)
+    assert p.stats["persistent_waves"] == 1
+    # a watermark off the 128-slot ring block never goes persistent (the
+    # kernel's rounds must share no block), on every engine alike
+    rp = p.plan_round(
+        loads=[128, 128], marks=[128, 136], live=[True] * 2, crnd=[0, 0],
+        pending=[640, 384],
+    )
+    assert all(c.rounds == 1 for c in rp.cohorts)
     assert p.stats["persistent_waves"] == 1
     # a sub-batch burst never goes persistent (numbering would fork)
     rp = p.plan_round(
@@ -348,12 +388,14 @@ def test_wave_depth_policy_full_batch_and_covered_queues_only():
     )
     assert all(c.rounds == 1 for c in rp.cohorts)
     # no pending telemetry -> classic single-round planning
-    rp = p.plan_round(loads=[32, 32], marks=[0, 0], live=[True] * 2, crnd=[0, 0])
+    rp = p.plan_round(
+        loads=[128, 128], marks=[0, 0], live=[True] * 2, crnd=[0, 0]
+    )
     assert all(c.rounds == 1 for c in rp.cohorts)
     # the knob off switches the feature off wholesale
-    p1 = DispatchPlanner(batch=32, n_instances=128, persistent_rounds=1)
+    p1 = DispatchPlanner(batch=128, n_instances=512, persistent_rounds=1)
     rp = p1.plan_round(
-        loads=[32], marks=[0], live=[True], crnd=[0], pending=[320],
+        loads=[128], marks=[0], live=[True], crnd=[0], pending=[1280],
     )
     assert rp.cohorts[0].rounds == 1
     assert p1.stats["persistent_waves"] == 0
@@ -422,12 +464,12 @@ def test_sharded_planner_clamps_wave_depth_to_one():
     K dispatches anyway, and ``persistent_waves`` must count only waves
     that actually ran device-persistent (DESIGN.md §11)."""
     p = DispatchPlanner(
-        batch=32, n_instances=128, persistent_rounds=8, sharded=True
+        batch=128, n_instances=512, persistent_rounds=8, sharded=True
     )
     rp = p.plan_round(
-        loads=[32, 32], marks=[0, 0], live=[True] * 2, crnd=[0, 0],
-        pending=[160, 96],
+        loads=[128, 128], marks=[0, 0], live=[True] * 2, crnd=[0, 0],
+        pending=[640, 384],
     )
     # the identical inputs mint rounds=3 on the unsharded planner (above)
-    assert rp.cohorts == (plan_mod.Cohort(gids=(0, 1), burst=32, rounds=1),)
+    assert rp.cohorts == (plan_mod.Cohort(gids=(0, 1), burst=128, rounds=1),)
     assert p.stats["persistent_waves"] == 0
