@@ -19,6 +19,7 @@ from __future__ import annotations
 import bisect
 import dataclasses
 import functools
+import time
 from typing import Any
 from collections.abc import Callable, Sequence
 
@@ -26,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..analysis.contracts import mirror_guard
 from . import batched
 from . import plan as plan_mod
@@ -82,6 +84,8 @@ class _Pending:
     payload: bytes
     age: int = 0
     group: int = 0
+    # host clock at submit: a wire burst's queue wait is measured from here
+    t_submit: float = dataclasses.field(default_factory=time.perf_counter)
 
 
 class _DeferredRound:
@@ -199,33 +203,37 @@ class HardwareDataplane(RingReclamationMixin, _DispatchCounter):
         the host boundary (DESIGN.md §3).  Returns host ``(fresh, inst,
         value)`` where ``fresh`` masks non-duplicate deliveries.
         """
-        b = values.shape[0]
-        self._guard_capacity(self._next_inst_host, b)
-        nblk = _kernel_blocks(self, [self._next_inst_host], b)
-        fn = (
-            self._fused
-            if nblk is None
-            else functools.partial(self._fused_k, window_blocks=nblk)
-        )
-        args = [
-            self.cstate,
-            self.stack,
-            self.lstate,
-            jnp.asarray(values),
-            jnp.asarray(active),
-            self.alive_mask,
-            self.cfg.quorum,
-        ]
-        if self.reclaimed_host is not None:
-            args.append(
-                jnp.int32(self.reclaimed_host + self.cfg.n_instances)
+        with obs.span("repro.hw.launch") as sp:
+            b = values.shape[0]
+            self._guard_capacity(self._next_inst_host, b)
+            nblk = _kernel_blocks(self, [self._next_inst_host], b)
+            if obs.enabled():
+                sp.set_metadata(blocks=nblk or 0)
+            fn = (
+                self._fused
+                if nblk is None
+                else functools.partial(self._fused_k, window_blocks=nblk)
             )
-        self._count(nblk is not None)
-        self.cstate, self.stack, self.lstate, fresh, inst, _win, value = fn(
-            *args
-        )
-        self._next_inst_host += b
-        return np.asarray(fresh), np.asarray(inst), np.asarray(value)
+            args = [
+                self.cstate,
+                self.stack,
+                self.lstate,
+                jnp.asarray(values),
+                jnp.asarray(active),
+                self.alive_mask,
+                self.cfg.quorum,
+            ]
+            if self.reclaimed_host is not None:
+                args.append(
+                    jnp.int32(self.reclaimed_host + self.cfg.n_instances)
+                )
+            self._count(nblk is not None)
+            self.cstate, self.stack, self.lstate, fresh, inst, _win, value = (
+                fn(*args)
+            )
+            self._next_inst_host += b
+        with obs.span("repro.hw.readback"):
+            return np.asarray(fresh), np.asarray(inst), np.asarray(value)
 
     def kill_acceptor(self, aid: int) -> None:
         self.alive[aid] = False
@@ -1586,7 +1594,7 @@ class PaxosContext:
                 )
             self.snapshots = SnapshotStore()
             self.hw.enable_reclamation()
-        self.stats = {"submitted": 0, "delivered": 0, "retransmits": 0}
+        self.stats = {"delivered": 0, "retransmits": 0}
 
     # -- paper API -----------------------------------------------------------
     def _check_group(self, group: int) -> None:
@@ -1622,7 +1630,6 @@ class PaxosContext:
             self._next_client_seq += 1
             self._pending[seq] = _Pending(payload)
         self.net.send("coordinator", ("submit", seq, payload, group))
-        self.stats["submitted"] += 1
         return seq
 
     def recover(self, inst: int, nop: bytes = b"\x00", group: int = 0) -> None:
@@ -1635,9 +1642,12 @@ class PaxosContext:
         """Drive the fabric: drain submits through the hardware dataplane,
         route votes to learners, fire deliver callbacks, retransmit losses."""
         for _ in range(rounds):
-            self._pump_coordinator()
-            self._pump_learners()
-            self._retransmit()
+            with obs.span("repro.ctx.pump") as sp:
+                submits = self._pump_coordinator()
+                self._pump_learners()
+                self._retransmit()
+                if obs.enabled():
+                    sp.set_metadata(submits=submits, pending=len(self._pending))
 
     def quiescent(self) -> bool:
         """True when nothing is in flight: no pending client sequences and
@@ -1651,7 +1661,8 @@ class PaxosContext:
             self.pump()
 
     # -- internals -----------------------------------------------------------
-    def _pump_coordinator(self) -> None:
+    def _pump_coordinator(self) -> int:
+        """Run the coordinator over its inbox; returns the submits taken."""
         inbox = self.net.recv_all("coordinator")
         submits = [
             (m[1], m[2], m[3] if len(m) > 3 else 0)
@@ -1665,7 +1676,7 @@ class PaxosContext:
         ]
         if self.grouped:
             self._pump_coordinator_groups(submits, recovers)
-            return
+            return len(submits)
 
         for inst, nop, _gid in recovers:
             self._run_recover(inst, nop)
@@ -1674,34 +1685,62 @@ class PaxosContext:
         b = self.cfg.batch
         for i in range(0, len(submits), b):
             chunk = submits[i : i + b]
-            # the fused path right-sizes the burst on BOTH engines
-            # (engine-agnostic quantization, core.plan); the staged path
-            # keeps the full batch.
-            be = self._burst_size(len(chunk)) if self.fused else b
-            vals, active = self._pack_chunk(chunk, be)
-            if self.fused and self._softco is None:
-                # the CAANS wire path: the whole Phase-2 round below the host
-                # boundary, one dispatch — votes never surface as messages
-                fresh, inst, value = self.hw.pipeline(vals, active)
-                for j in range(len(fresh)):
-                    if not fresh[j]:
-                        continue
-                    raw = value[j].tobytes()
-                    for lid in range(self.n_learners):
-                        if int(inst[j]) not in self.learned[lid]:
-                            self.learned[lid][int(inst[j])] = raw
-                    self._deliver(int(inst[j]), raw)
-                continue
-            if self._softco is not None:
-                p2a = self._soft_sequence(vals, active)
-            else:
-                p2a = self.hw.sequence(vals, active)
-            votes = self.hw.vote(p2a)
-            for aid, v in enumerate(votes):
-                if v is None:
+            with obs.span("repro.ctx.chunk") as sp:
+                traced = obs.enabled()
+                if traced:
+                    t_chunk = time.perf_counter()
+                with obs.span("repro.ctx.pack"):
+                    # the fused path right-sizes the burst on BOTH engines
+                    # (engine-agnostic quantization, core.plan); the staged
+                    # path keeps the full batch.
+                    be = self._burst_size(len(chunk)) if self.fused else b
+                    vals, active = self._pack_chunk(chunk, be)
+                if traced:
+                    sp.set_metadata(
+                        ops=len(chunk), burst=be,
+                        wait_us=self._queue_wait_us(chunk, t_chunk),
+                    )
+                if self.fused and self._softco is None:
+                    # the CAANS wire path: the whole Phase-2 round below the
+                    # host boundary, one dispatch — votes never surface as
+                    # messages
+                    fresh, inst, value = self.hw.pipeline(vals, active)
+                    self._deliver_burst(fresh, inst, value)
                     continue
+                if self._softco is not None:
+                    p2a = self._soft_sequence(vals, active)
+                else:
+                    p2a = self.hw.sequence(vals, active)
+                votes = self.hw.vote(p2a)
+                for aid, v in enumerate(votes):
+                    if v is None:
+                        continue
+                    for lid in range(self.n_learners):
+                        self.net.send(("learner", lid), ("votes", aid, _to_host(v)))
+        return len(submits)
+
+    def _queue_wait_us(self, chunk: list[tuple[int, bytes]], t: float) -> float:
+        """Microseconds the chunk's ops waited from submit to ``t``, summed
+        (a resent op whose first copy was delivered waits no more)."""
+        pending = self._pending
+        return 1e6 * sum(
+            t - pending[seq].t_submit for seq, _ in chunk if seq in pending
+        )
+
+    def _deliver_burst(self, fresh, inst, value) -> None:
+        """Deliver a fused dispatch's fresh lanes, in lane order."""
+        with obs.span("repro.ctx.deliver") as sp:
+            n0 = self.stats["delivered"]
+            for j in range(len(fresh)):
+                if not fresh[j]:
+                    continue
+                raw = value[j].tobytes()
                 for lid in range(self.n_learners):
-                    self.net.send(("learner", lid), ("votes", aid, _to_host(v)))
+                    if int(inst[j]) not in self.learned[lid]:
+                        self.learned[lid][int(inst[j])] = raw
+                self._deliver(int(inst[j]), raw)
+            if obs.enabled():
+                sp.set_metadata(delivered=self.stats["delivered"] - n0)
 
     def _pump_learners(self) -> None:
         for lid in range(self.n_learners):
@@ -1984,13 +2023,16 @@ class PaxosContext:
             self.deliver_cb(payload, len(payload), inst)
 
     def _retransmit(self) -> None:
-        for key, p in list(self._pending.items()):
-            p.age += 1
-            if p.age >= self.retransmit_after:
-                p.age = 0
-                self.stats["retransmits"] += 1
-                seq = key[1] if isinstance(key, tuple) else key
-                self.net.send("coordinator", ("submit", seq, p.payload, p.group))
+        with obs.span("repro.ctx.retransmit") as sp:
+            if obs.enabled():
+                sp.set_metadata(pending=len(self._pending))
+            for key, p in list(self._pending.items()):
+                p.age += 1
+                if p.age >= self.retransmit_after:
+                    p.age = 0
+                    self.stats["retransmits"] += 1
+                    seq = key[1] if isinstance(key, tuple) else key
+                    self.net.send("coordinator", ("submit", seq, p.payload, p.group))
 
     def _encode(self, seq: int, payload: bytes) -> np.ndarray:
         nbytes = self.cfg.value_words * 4
@@ -2028,46 +2070,49 @@ class PaxosContext:
         advance the reclamation watermark so the drained ring slots may be
         re-sequenced.  Returns the group's sealed ``GroupSnapshot``.
         """
-        store = self._require_snapshots()
-        self._check_group(gid)
-        hw = self.hw
-        if self.grouped:
-            row = hw._slab_row(gid)
-            seq_mark = hw.next_inst_host[gid]
-            ld = np.asarray(hw.lstate.delivered[row])
-            li = np.asarray(hw.lstate.inst[row])
-            lv = np.asarray(hw.lstate.value[row])
-        else:
-            seq_mark = hw._next_inst_host
-            ld = np.asarray(hw.lstate.delivered)
-            li = np.asarray(hw.lstate.inst)
-            lv = np.asarray(hw.lstate.value)
-        upto = seq_mark if upto is None else upto
-        wm = store.watermark(gid)
-        if not wm <= upto <= seq_mark:
-            raise ValueError(
-                f"snapshot upto={upto} outside [{wm}, {seq_mark}] "
-                f"(group {gid})"
-            )
-        # decided entries in [wm, upto), ascending by instance — the raw
-        # ring words (NOP fillers included: the seal covers device history)
-        slots = np.nonzero((ld != 0) & (li >= wm) & (li < upto))[0]
-        order = slots[np.argsort(li[slots], kind="stable")]
-        store.absorb(gid, li[order], lv[order], upto)
-        # compaction: move the host log's leading run below the watermark
-        # into the store (list order preserved exactly — stitched reads are
-        # bit-identical to the unsplit log)
-        log = self.group_log[gid]
-        cut = 0
-        while cut < len(log) and log[cut][0] < upto:
-            cut += 1
-        store.absorb_log(gid, log[:cut])
-        self.group_log[gid] = log[cut:]
-        if self.grouped:
-            hw.set_reclaimed(gid, upto)
-        else:
-            hw.set_reclaimed(upto)
-        return store.snapshot(gid)
+        with obs.span("repro.snapshot.drain") as sp:
+            store = self._require_snapshots()
+            self._check_group(gid)
+            hw = self.hw
+            if self.grouped:
+                row = hw._slab_row(gid)
+                seq_mark = hw.next_inst_host[gid]
+                ld = np.asarray(hw.lstate.delivered[row])
+                li = np.asarray(hw.lstate.inst[row])
+                lv = np.asarray(hw.lstate.value[row])
+            else:
+                seq_mark = hw._next_inst_host
+                ld = np.asarray(hw.lstate.delivered)
+                li = np.asarray(hw.lstate.inst)
+                lv = np.asarray(hw.lstate.value)
+            upto = seq_mark if upto is None else upto
+            wm = store.watermark(gid)
+            if not wm <= upto <= seq_mark:
+                raise ValueError(
+                    f"snapshot upto={upto} outside [{wm}, {seq_mark}] "
+                    f"(group {gid})"
+                )
+            # decided entries in [wm, upto), ascending by instance — the raw
+            # ring words (NOP fillers included: the seal covers device history)
+            slots = np.nonzero((ld != 0) & (li >= wm) & (li < upto))[0]
+            order = slots[np.argsort(li[slots], kind="stable")]
+            if obs.enabled():
+                sp.set_metadata(entries=len(order))
+            store.absorb(gid, li[order], lv[order], upto)
+            # compaction: move the host log's leading run below the watermark
+            # into the store (list order preserved exactly — stitched reads are
+            # bit-identical to the unsplit log)
+            log = self.group_log[gid]
+            cut = 0
+            while cut < len(log) and log[cut][0] < upto:
+                cut += 1
+            store.absorb_log(gid, log[:cut])
+            self.group_log[gid] = log[cut:]
+            if self.grouped:
+                hw.set_reclaimed(gid, upto)
+            else:
+                hw.set_reclaimed(upto)
+            return store.snapshot(gid)
 
     def crash_acceptor(self, aid: int, group: int = 0) -> None:
         """Crash one group member WITH state loss: liveness drops AND its

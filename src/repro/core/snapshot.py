@@ -148,11 +148,14 @@ class GroupSnapshot:
 
 def _seal(insts: np.ndarray, values: np.ndarray) -> int:
     # lazy import: kernels.ops pulls in jax; keep the store importable cheap
+    from repro import obs
     from repro.kernels import ops as kops
 
     if insts.size == 0:
         return 0
-    return int(kops.tree_digest((insts, values)))
+    # the digest is a program per prefix length: a compile shows here
+    with obs.span("repro.snapshot.seal", prefix=insts.size):
+        return int(kops.tree_digest((insts, values)))
 
 
 class SnapshotStore:

@@ -162,7 +162,6 @@ class Session:
             )
         gid = svc.group_of(self.id)
         seq = svc.ctx.submit(payload, group=gid)
-        svc.stats["submitted"] += 1
         svc.submits_per_group[gid] += 1
         return Ticket(gid, seq)
 
@@ -201,7 +200,6 @@ class ConsensusService:
     def __init__(self, ctx):
         self.ctx = ctx
         self.n_groups = ctx.cfg.n_groups
-        self.stats = {"submitted": 0}
         # bounded introspection state: G counters, not a per-session map —
         # the hash is pure and cheap, and a session universe of millions
         # must not accrete host memory in the routing tier

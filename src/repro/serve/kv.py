@@ -37,6 +37,7 @@ import dataclasses
 import struct
 from typing import Any
 
+from .. import obs
 from .engine import ConsensusService, Ticket, session_hash
 
 # ---------------------------------------------------------------------------
@@ -266,29 +267,36 @@ class ReplicatedKV:
         Snapshot and adopted prefixes are *applied* here exactly like live
         entries (they arrive through the same stitched ``full_group_log``
         read), never replayed through the dataplane."""
-        svc = self.service
-        ctx = svc.ctx
-        if svc.routing_epoch != self._epoch_seen:
-            for key, log in svc.archived_segments().items():
-                rep = self.replica(*key)
-                if not rep.final:
-                    rep.apply_log(log)
-                    rep.final = True
-            self._live_reps = [
-                (gid, self.replica(gid)) for gid in ctx.live_groups()
-            ]
-            self._epoch_seen = svc.routing_epoch
-        snaps = self._snaps
-        for gid, rep in self._live_reps:
-            # cheap steady-state exit: the stitched log is append-only
-            # stable, so an unchanged length means no new suffix — skip
-            # materializing the prefix+live concatenation (this is what
-            # keeps a leased get O(1) in the history length)
-            total = len(ctx.group_log[gid])
-            if snaps is not None:
-                total += len(snaps.log_prefix(gid))
-            if total != rep.applied_len:
-                rep.apply_log(ctx.full_group_log(gid))
+        with obs.span("repro.kv.refresh") as sp:
+            svc = self.service
+            ctx = svc.ctx
+            applied = copied = 0
+            if svc.routing_epoch != self._epoch_seen:
+                for key, log in svc.archived_segments().items():
+                    rep = self.replica(*key)
+                    if not rep.final:
+                        applied += rep.apply_log(log)
+                        rep.final = True
+                self._live_reps = [
+                    (gid, self.replica(gid)) for gid in ctx.live_groups()
+                ]
+                self._epoch_seen = svc.routing_epoch
+            snaps = self._snaps
+            for gid, rep in self._live_reps:
+                # cheap steady-state exit: the stitched log is append-only
+                # stable, so an unchanged length means no new suffix — skip
+                # materializing the prefix+live concatenation (this is what
+                # keeps a leased get O(1) in the history length)
+                total = len(ctx.group_log[gid])
+                if snaps is not None:
+                    total += len(snaps.log_prefix(gid))
+                if total != rep.applied_len:
+                    log = ctx.full_group_log(gid)
+                    applied += rep.apply_log(log)
+                    if snaps is not None:     # the stitched log is a new list
+                        copied += len(log)
+            if obs.enabled():
+                sp.set_metadata(copied=copied, applied=applied)
 
     def read_watermark(self, gid: int) -> int:
         """Applied-entry count of the group's current-generation segment —
@@ -436,20 +444,23 @@ class KVSession:
         ticket = self._submit(KvOp(OP_GET, b"", b"", None, self.tag))
         target = self._counter
         seg = (ticket.group, svc.group_generation(ticket.group))
-        for _ in range(self.kv.max_read_rounds):
-            self.kv.refresh()
-            rep = self.kv._replicas.get(seg)
-            if (
-                rep is not None
-                and rep.applied_counter.get(self.tag, 0) >= target
-            ):
-                break
-            svc.pump()
-        else:
-            raise RuntimeError(
-                f"read-index op for session {self.id!r} did not apply "
-                f"within {self.kv.max_read_rounds} pump rounds"
-            )
+        with obs.span("repro.kv.read_index") as sp:
+            for pumps in range(self.kv.max_read_rounds):
+                self.kv.refresh()
+                rep = self.kv._replicas.get(seg)
+                if (
+                    rep is not None
+                    and rep.applied_counter.get(self.tag, 0) >= target
+                ):
+                    break
+                svc.pump()
+            else:
+                raise RuntimeError(
+                    f"read-index op for session {self.id!r} did not apply "
+                    f"within {self.kv.max_read_rounds} pump rounds"
+                )
+            if obs.enabled():
+                sp.set_metadata(pumps=pumps)
         # every op this session issued before the read either applied (it
         # sequences ahead of the read in the same group) or died with a
         # retired generation — nothing is still outstanding
