@@ -259,6 +259,7 @@ MIRROR_ATTRS = frozenset(
         "crnd_host",
         "reclaimed_host",
         "_reclaim_marks",
+        "_reclaim_limit_dev",
     }
 )
 

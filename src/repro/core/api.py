@@ -119,6 +119,54 @@ class _DeferredRound:
         return fresh, self._inst, value
 
 
+def _fused_round_packed(
+    cstate: CoordinatorState,
+    stack: AcceptorState,
+    lstate: batched.LearnerState,
+    burst: jax.Array,
+    alive: jax.Array,
+    reclaim_limit: jax.Array | None = None,
+    *,
+    quorum: int,
+    window_blocks: int | None = None,
+) -> tuple[CoordinatorState, AcceptorState, batched.LearnerState, jax.Array]:
+    """The single-group fused round with one array in and one array out, so
+    that a dispatch makes one host->device and one device->host transfer.
+
+    ``window_blocks`` picks the engine as ``_kernel_blocks`` does: the Pallas
+    megakernel over that many ring blocks, or the jnp oracle when ``None``.
+    The kernel never reads the active mask, so its ``burst`` is the int32[B, V]
+    values alone; the oracle's sequencer does, so its burst carries the mask
+    as a last column, int32[B, V+1].  ``quorum`` is a constant of the
+    deployment, compiled in.  Returns the new state and int32[B, V+2]: each
+    lane's fresh flag, instance and V value words.
+    """
+    if window_blocks is None:
+        values, active = burst[:, :-1], burst[:, -1] != 0
+        out = batched.fused_round(
+            cstate, stack, lstate, values, active, alive, quorum, reclaim_limit
+        )
+    else:
+        from repro.kernels import ops as kops
+
+        out = kops.fused_round(
+            cstate,
+            stack,
+            lstate,
+            burst,
+            None,
+            alive,
+            quorum,
+            reclaim_limit,
+            window_blocks=window_blocks,
+        )
+    cstate, stack, lstate, fresh, inst, _win, value = out
+    packed = jnp.concatenate(
+        [fresh.astype(jnp.int32)[:, None], inst[:, None], value], axis=1
+    )
+    return cstate, stack, lstate, packed
+
+
 class HardwareDataplane(RingReclamationMixin, _DispatchCounter):
     """The coordinator + acceptor array + learner dedup memory, executing as
     single-dispatch device programs.
@@ -157,21 +205,23 @@ class HardwareDataplane(RingReclamationMixin, _DispatchCounter):
         # window without a device sync
         self._next_inst_host = 0
         self._seq_base: int | None = None        # provenance hint for vote()
+        # the reclamation limit (watermark + N) on the device, replaced only
+        # when the watermark moves; None while reclamation is disabled
+        self._reclaim_limit_dev: jax.Array | None = None
         if use_kernels:
             from repro.kernels import ops as kops
 
             self._seq = kops.coordinator_sequence
-            self._fused_k = jax.jit(
-                kops.fused_round,
-                donate_argnums=(1, 2),
-                static_argnames=("window_blocks",),
-            )
             self._vote_all_k = jax.jit(
                 kops.acceptor_phase2_all, donate_argnums=(0,)
             )
         else:
             self._seq = jax.jit(batched.coordinator_sequence)
-        self._fused = jax.jit(batched.fused_round, donate_argnums=(1, 2))
+        self._fused = jax.jit(
+            _fused_round_packed,
+            donate_argnums=(1, 2),
+            static_argnames=("quorum", "window_blocks"),
+        )
         self._vote_all = jax.jit(batched.acceptor_phase2_all, donate_argnums=(0,))
         self._prep_all = jax.jit(batched.acceptor_phase1_all, donate_argnums=(0,))
 
@@ -186,10 +236,23 @@ class HardwareDataplane(RingReclamationMixin, _DispatchCounter):
         marks = self._reclaim_marks
         return None if marks is None else marks[0]
 
+    @mirror_guard
+    def enable_reclamation(self) -> None:
+        super().enable_reclamation()
+        self._hold_reclaim_limit()
+
+    @mirror_guard
     def set_reclaimed(self, upto: int) -> None:
         """Advance the reclamation watermark: instances below ``upto`` have
         been drained to a snapshot and their ring slots may be re-used."""
         self._reclaim_set(0, upto)
+        self._hold_reclaim_limit()
+
+    @mirror_guard
+    def _hold_reclaim_limit(self) -> None:
+        """Upload the reclamation limit once per watermark move, so the
+        dispatches between two snapshots upload nothing for it."""
+        self._reclaim_limit_dev = jax.device_put(self._reclaim_limits_np()[0])
 
     def _guard_capacity(self, base: int, b: int) -> None:
         self._reclaim_guard(0, base, b)
@@ -201,7 +264,9 @@ class HardwareDataplane(RingReclamationMixin, _DispatchCounter):
 
         This is the CAANS wire path — consensus logic fused end-to-end below
         the host boundary (DESIGN.md §3).  Returns host ``(fresh, inst,
-        value)`` where ``fresh`` masks non-duplicate deliveries.
+        value)`` where ``fresh`` masks non-duplicate deliveries.  The burst
+        is the one upload and the packed result the one read-back; every
+        other operand already lives on the device.
         """
         with obs.span("repro.hw.launch") as sp:
             b = values.shape[0]
@@ -209,31 +274,27 @@ class HardwareDataplane(RingReclamationMixin, _DispatchCounter):
             nblk = _kernel_blocks(self, [self._next_inst_host], b)
             if obs.enabled():
                 sp.set_metadata(blocks=nblk or 0)
-            fn = (
-                self._fused
-                if nblk is None
-                else functools.partial(self._fused_k, window_blocks=nblk)
-            )
-            args = [
+            burst = np.asarray(values, np.int32)
+            if nblk is None:
+                # the jnp engine's sequencer reads the active mask
+                burst = np.concatenate(
+                    [burst, np.asarray(active, np.int32)[:, None]], axis=1
+                )
+            self._count(nblk is not None)
+            self.cstate, self.stack, self.lstate, out = self._fused(
                 self.cstate,
                 self.stack,
                 self.lstate,
-                jnp.asarray(values),
-                jnp.asarray(active),
+                jax.device_put(burst),
                 self.alive_mask,
-                self.cfg.quorum,
-            ]
-            if self.reclaimed_host is not None:
-                args.append(
-                    jnp.int32(self.reclaimed_host + self.cfg.n_instances)
-                )
-            self._count(nblk is not None)
-            self.cstate, self.stack, self.lstate, fresh, inst, _win, value = (
-                fn(*args)
+                self._reclaim_limit_dev,
+                quorum=self.cfg.quorum,
+                window_blocks=nblk,
             )
             self._next_inst_host += b
         with obs.span("repro.hw.readback"):
-            return np.asarray(fresh), np.asarray(inst), np.asarray(value)
+            out = jax.device_get(out)
+            return out[:, 0] != 0, out[:, 1], out[:, 2:]
 
     def kill_acceptor(self, aid: int) -> None:
         self.alive[aid] = False
