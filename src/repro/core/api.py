@@ -444,13 +444,15 @@ class MultiGroupDataplane(RingReclamationMixin, _DispatchCounter):
     rings; neither touches any other group's slab state.
     """
 
-    def __init__(self, cfg: PaxosConfig, use_kernels: bool = False):
+    def __init__(
+        self, cfg: PaxosConfig, use_kernels: bool = False, slab_sharding=None
+    ):
         if cfg.n_groups < 1:
             raise ValueError(f"n_groups must be >= 1, got {cfg.n_groups}")
         self.cfg = cfg
         g, a = cfg.n_groups, cfg.n_acceptors
         self.cstate, self.stack, self.lstate = batched.init_multigroup_state(
-            g, a, cfg.n_instances, cfg.value_words
+            g, a, cfg.n_instances, cfg.value_words, sharding=slab_sharding
         )
         self.alive = [[True] * a for _ in range(g)]   # host mirror
         self.alive_mask = jnp.ones((g, a), jnp.bool_)
@@ -974,6 +976,16 @@ class MultiGroupDataplane(RingReclamationMixin, _DispatchCounter):
         the group's current placement (DESIGN.md §13)."""
         return gid
 
+    def ring_rows(self, gid: int) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """Group ``gid``'s learner ring on the host: the delivered flags,
+        the instance decided into each slot and its value words."""
+        row = self._slab_row(gid)
+        return (
+            np.asarray(self.lstate.delivered[row]),
+            np.asarray(self.lstate.inst[row]),
+            np.asarray(self.lstate.value[row]),
+        )
+
     def _reset_group_slab(self, gid: int) -> None:
         """Zero ONE group's acceptor and learner rings — a fresh tenant's
         slot.  Touches only the group's slab row (the sharded subclass
@@ -1048,10 +1060,7 @@ class MultiGroupDataplane(RingReclamationMixin, _DispatchCounter):
         pairs in instance order — the decided values still resident in the
         retiring group's dedup ring."""
         self._check_live(gid)
-        row = self._slab_row(gid)
-        ld = np.asarray(self.lstate.delivered[row])
-        li = np.asarray(self.lstate.inst[row])
-        lv = np.asarray(self.lstate.value[row])
+        ld, li, lv = self.ring_rows(gid)
         slots = np.nonzero(ld != 0)[0]
         order = slots[np.argsort(li[slots], kind="stable")]
         drained = [(int(li[s]), lv[s].tobytes()) for s in order]
@@ -1098,7 +1107,15 @@ class ShardedMultiGroupDataplane(MultiGroupDataplane):
                 f"n_groups={cfg.n_groups} must be divisible by the {axis!r} "
                 f"mesh axis size {n_sh}"
             )
-        super().__init__(cfg, use_kernels=use_kernels)
+        from jax.sharding import NamedSharding
+        from jax.sharding import PartitionSpec as P
+
+        # big slabs: device-resident, leading group axis sharded over the
+        # mesh, each shard built on its own device
+        self._slab_sharding = NamedSharding(mesh, P(axis))
+        super().__init__(
+            cfg, use_kernels=use_kernels, slab_sharding=self._slab_sharding
+        )
         self.mesh = mesh
         self.axis = axis
         self.n_shards = n_sh
@@ -1110,13 +1127,6 @@ class ShardedMultiGroupDataplane(MultiGroupDataplane):
             next_inst=np.zeros((g,), np.int32), crnd=np.zeros((g,), np.int32)
         )
         self.alive_mask = np.ones((g, a), np.int32)
-        # big slabs: device-resident, leading group axis sharded over the mesh
-        from jax.sharding import NamedSharding
-        from jax.sharding import PartitionSpec as P
-
-        self._slab_sharding = NamedSharding(mesh, P(axis))
-        self.stack = jax.device_put(self.stack, self._slab_sharding)
-        self.lstate = jax.device_put(self.lstate, self._slab_sharding)
         self._dispatches: dict[tuple[int | None, int], Any] = {}
         self._packed_dispatches: dict[int | None, Any] = {}
         # group -> physical slot permutation (DESIGN.md §13); identity at
@@ -1126,6 +1136,9 @@ class ShardedMultiGroupDataplane(MultiGroupDataplane):
         self._placement = plan_mod.PlacementMap.identity(
             cfg.n_groups, self.groups_per_shard
         )
+        # every program a dispatch may run (``core.plan.sharded_programs``),
+        # all compiled by ``precompile``
+        self.programs = plan_mod.sharded_programs(cfg, n_sh, use_kernels)
 
     def _fold_width(self) -> int:
         # lockstep folds within one shard's slab (a block has a single ring
@@ -1207,6 +1220,108 @@ class ShardedMultiGroupDataplane(MultiGroupDataplane):
         self.stack = jax.device_put(self.stack, self._slab_sharding)
         self.lstate = jax.device_put(self.lstate, self._slab_sharding)
 
+    def _window(self, nblk: int | None, b: int) -> int | None:
+        """The vocabulary's ring blocks for a dispatch whose windows need
+        ``nblk`` (``None``: the jnp engine)."""
+        if nblk is None:
+            return None
+        return plan_mod.sharded_window_blocks(self.cfg.n_instances, b)
+
+    def _full_program(
+        self, nblk: int | None, slots, marks_slot, b: int
+    ) -> plan_mod.ShardedProgram:
+        """The full-width program for enabled ``slots`` at slot-ordered
+        ``marks_slot``: folded at the cap or not at all on the kernels."""
+        gb = 1
+        if nblk is not None:
+            gb = plan_mod.full_fold(slots, marks_slot, self._fold_width())
+        return plan_mod.ShardedProgram("full", self._window(nblk, b), gb, b)
+
+    def _launch_full(self, prog, en_slot, vals_slot, act_slot):
+        """Run full-width program ``prog`` over every slab row in slot
+        order, rows with ``en_slot`` 0 inert; returns the device
+        ``(fresh, inst, value)`` rows in slot order."""
+        perm = list(self._placement.group_of)     # slot -> gid
+        crnd = np.asarray(self.crnd_host, np.int32)[perm]
+        lim = self._reclaim_limits_np()
+        if lim is None:
+            # full permit: int32.max is unreachable, every lane passes the
+            # reclamation gate (legacy overwrite-on-wrap mode)
+            lim = np.full((self.cfg.n_groups,), np.iinfo(np.int32).max, np.int32)
+        self._ensure_placement()
+        fn = self._dispatch(prog.window_blocks, prog.width)
+        self.stack, self.lstate, fresh, inst, _win, value = fn(
+            np.asarray(self.next_inst_host, np.int32)[perm],
+            np.where(en_slot != 0, crnd, NO_ROUND).astype(np.int32),
+            en_slot,
+            self.alive_mask[perm],
+            self.stack,
+            self.lstate,
+            jnp.asarray(vals_slot),
+            jnp.asarray(act_slot),
+            reclaim_limit=lim[perm],
+        )
+        self.last_gb = prog.width
+        return fresh, inst, value
+
+    def _launch_packed(self, prog, seg, nip, crp, enp, alp, limp, valsp):
+        """Run packed program ``prog`` on per-lane ``(n_shards, C, ...)``
+        tables; returns the device ``(fresh, value)`` rows, lane-major."""
+        self._ensure_placement()
+        fn = self._packed_dispatch(prog.window_blocks)
+        self.stack, self.lstate, fresh, _inst, _win, value = fn(
+            seg,
+            nip,
+            crp,
+            enp,
+            alp,
+            self.stack,
+            self.lstate,
+            jnp.asarray(valsp),
+            reclaim_limit=limp,
+        )
+        self.last_gb = 1               # each lane is its own grid step
+        return fresh, value
+
+    def _lane_tables(self, c: int, b: int):
+        """Per-lane tables of ``c`` inert pad lanes per shard: no slab row
+        of their own, round ``NO_ROUND``, NOP-sentinel values."""
+        n_sh, a, v = self.n_shards, self.cfg.n_acceptors, self.cfg.value_words
+        valsp = np.zeros((n_sh, c, b, v), np.int32)
+        valsp[:, :, :, 0] = NOP_SENTINEL
+        return (
+            np.zeros((n_sh, c), np.int32),                     # segids
+            np.zeros((n_sh, c), np.int32),                     # next_inst
+            np.full((n_sh, c), NO_ROUND, np.int32),            # crnd
+            np.zeros((n_sh, c), np.int32),                     # enabled
+            np.ones((n_sh, c, a), np.int32),                   # alive
+            np.full((n_sh, c), np.iinfo(np.int32).max, np.int32),  # limit
+            valsp,
+        )
+
+    def precompile(self) -> None:
+        """Compile every program of ``programs`` before the first dispatch:
+        each runs once on the live slabs with every lane inert (round
+        ``NO_ROUND``), which leaves the slabs bit-identical and fills the
+        jitted functions' own caches, and the learner ring read of a
+        snapshot drain runs once."""
+        g, v = self.cfg.n_groups, self.cfg.value_words
+        with obs.span("repro.hw.precompile", programs=len(self.programs)):
+            for prog in self.programs:
+                b = prog.burst
+                if prog.kind == "packed":
+                    out = self._launch_packed(prog, *self._lane_tables(prog.width, b))
+                else:
+                    vals = np.zeros((g, b, v), np.int32)
+                    vals[:, :, 0] = NOP_SENTINEL
+                    out = self._launch_full(
+                        prog, np.zeros((g,), np.int32), vals,
+                        np.zeros((g, b), bool),
+                    )
+                jax.block_until_ready(out)
+            self.ring_rows(0)
+        self.last_gb = None
+
     # -- fused fast path: all shards advance their slabs in ONE dispatch ----
     @mirror_guard
     def pipeline(
@@ -1222,50 +1337,36 @@ class ShardedMultiGroupDataplane(MultiGroupDataplane):
         enabled, nblk, _ = self._plan_round(b, enabled)
         if not any(enabled):
             return self._empty_round(g, b)
-        self._guard_capacity(
-            [gid for gid in range(g) if enabled[gid]], b
-        )
-        pm = self._placement
-        # the fold's lockstep blocks are SLOT blocks (the kernel walks
-        # physical slab rows), so the width derives from slot-ordered marks
-        perm = list(pm.group_of)       # slot -> gid
-        marks_slot = [self.next_inst_host[gid] for gid in perm]
-        slots = [pm.slot_of[gid] for gid in range(g) if enabled[gid]]
-        gb = plan_mod.fold_width_full(slots, marks_slot, self._fold_width())
-        plan_gb = gb               # reported engine-agnostically (last_gb)
-        if nblk is None:
-            gb = 1
-        self._ensure_placement()
-        ni = np.asarray(self.next_inst_host, np.int32)[perm]
-        en = np.asarray(enabled, np.int32)[perm]
-        eff_crnd = np.where(
-            en != 0, np.asarray(self.crnd_host, np.int32)[perm], NO_ROUND
-        ).astype(np.int32)
-        lim = self._reclaim_limits_np()
-        fn = self._dispatch(nblk, gb)
-        self._count(nblk is not None)
-        self.stack, self.lstate, fresh, inst, _win, value = fn(
-            ni,
-            eff_crnd,
-            en,
-            self.alive_mask[perm],
-            self.stack,
-            self.lstate,
-            jnp.asarray(np.asarray(values)[perm]),
-            jnp.asarray(np.asarray(active)[perm]),
-            reclaim_limit=None if lim is None else lim[perm],
-        )
+        with obs.span("repro.hw.launch") as sp:
+            self._guard_capacity(
+                [gid for gid in range(g) if enabled[gid]], b
+            )
+            pm = self._placement
+            # the fold's lockstep blocks are SLOT blocks (the kernel walks
+            # physical slab rows), so the width derives from slot-ordered marks
+            perm = list(pm.group_of)       # slot -> gid
+            marks_slot = [self.next_inst_host[gid] for gid in perm]
+            slots = [pm.slot_of[gid] for gid in range(g) if enabled[gid]]
+            prog = self._full_program(nblk, slots, marks_slot, b)
+            self._count(nblk is not None)
+            fresh, inst, value = self._launch_full(
+                prog,
+                np.asarray(enabled, np.int32)[perm],
+                np.asarray(values)[perm],
+                np.asarray(active)[perm],
+            )
+            if obs.enabled():
+                sp.set_metadata(members=len(slots), lanes=g, burst=b, full=1)
+        with obs.span("repro.hw.readback"):
+            rows = list(pm.slot_of)        # gid -> slot: back to gid order
+            fresh = np.asarray(fresh)[rows]
+            inst = np.asarray(inst)[rows]
+            value = np.asarray(value)[rows]
         for gid in range(g):
             if enabled[gid]:
                 self.next_inst_host[gid] += b
         self._sync_cstate()
-        self.last_gb = plan_gb
-        inv = list(pm.slot_of)         # gid -> slot: gather back to gid order
-        return (
-            np.asarray(fresh)[inv],
-            np.asarray(inst)[inv],
-            np.asarray(value)[inv],
-        )
+        return fresh, inst, value
 
     # -- cohort dispatch (DESIGN.md §8), sharded execution -------------------
     @mirror_guard
@@ -1277,138 +1378,78 @@ class ShardedMultiGroupDataplane(MultiGroupDataplane):
         ``pipeline_cohort``, executed as one *packed* ``shard_map`` program
         (DESIGN.md §13).
 
-        Historically this path ran every shard's full ``Gl``-row slab with
-        non-members held inert, so a cold one-group cohort paid full-width
-        slab cost on every shard.  Packed dispatch restores proportional
-        cost under shard_map's shape uniformity via input packing: each
-        shard advances ``C`` lanes (the cohort's max per-shard residency,
-        pow2-quantized), each lane routed to its physical slab row by a
-        ``segids`` table riding scalar prefetch; shards with fewer resident
-        members ride inert pad lanes.  The burst still right-sizes per
-        tier, so cohort cost is ``O(C x BE)`` instead of ``O(Gl x BE)``."""
-        gids, member, nblk, inst = self._cohort_prologue(gids, values)
+        A full-width dispatch walks every shard's ``Gl``-row slab with
+        non-members held inert, so a cold one-group cohort would pay
+        full-width slab cost on every shard.  Packed dispatch restores
+        proportional cost under shard_map's shape uniformity via input
+        packing: each shard advances ``C`` lanes (``core.plan.packed_lanes``
+        of the cohort's max per-shard residency), each lane routed to its
+        physical slab row by a ``segids`` table riding scalar prefetch;
+        shards with fewer resident members ride inert pad lanes.  A cohort
+        whose ``C`` would reach the slab's height runs the full-width fold
+        instead: its packed table would visit as many slab rows and pay one
+        grid step per lane.  The read-back is eager; with ``defer=True``
+        the result comes wrapped as an already-resolved round."""
         be = values.shape[1]
-        self._guard_capacity(gids, be)
-        marks = self.next_inst_host
-        pm = self._placement
-        n_sh, gl = self.n_shards, self.groups_per_shard
-        # pack the cohort into per-shard lane tables: C = max residency,
-        # pow2-quantized (bounded retrace vocabulary), capped by the slab
-        lanes: list[list[int]] = [[] for _ in range(n_sh)]
-        for row, gid in enumerate(gids):
-            lanes[pm.shard_of(gid)].append(row)
-        cmax = max(len(ls) for ls in lanes)
-        c = min(1 << max(0, cmax - 1).bit_length(), gl)
-        if c >= gl:
-            # crossover: a saturated cohort's packed table visits as many
-            # slab rows as the full-width fold but pays one grid step per
-            # lane, so the fat folded dispatch is strictly cheaper
-            return self._cohort_full_width(
-                gids, member, nblk, inst, values, active, defer
+        with obs.span("repro.hw.launch") as sp:
+            gids, member, nblk, inst = self._cohort_prologue(gids, values)
+            self._guard_capacity(gids, be)
+            marks = self.next_inst_host
+            pm = self._placement
+            n_sh = self.n_shards
+            lanes: list[list[int]] = [[] for _ in range(n_sh)]
+            for row, gid in enumerate(gids):
+                lanes[pm.shard_of(gid)].append(row)
+            c = plan_mod.packed_lanes(
+                max(len(ls) for ls in lanes), self.groups_per_shard
             )
-        # the full-width fold over slot-ordered marks remains the reported
-        # plan (engine-agnostic, comparable across rounds); packed
-        # execution itself needs no fold — lanes carry their own offsets
-        marks_slot = [marks[gid] for gid in pm.group_of]
-        plan_gb = plan_mod.fold_width_full(
-            [pm.slot_of[gid] for gid in gids], marks_slot, self._fold_width()
-        )
-        a, v = self.cfg.n_acceptors, self.cfg.value_words
-        seg = np.zeros((n_sh, c), np.int32)
-        enp = np.zeros((n_sh, c), np.int32)
-        nip = np.zeros((n_sh, c), np.int32)
-        crp = np.full((n_sh, c), NO_ROUND, np.int32)
-        alp = np.ones((n_sh, c, a), np.int32)
-        limnp = self._reclaim_limits_np()
-        limp = np.full((n_sh, c), np.iinfo(np.int32).max, np.int32)
-        valsp = np.zeros((n_sh, c, be, v), np.int32)
-        valsp[:, :, :, 0] = NOP_SENTINEL
-        lane_of: dict[int, tuple[int, int]] = {}
-        for s in range(n_sh):
-            for j, row in enumerate(lanes[s]):
-                gid = gids[row]
-                seg[s, j] = pm.row_of(gid)
-                enp[s, j] = 1
-                nip[s, j] = marks[gid]
-                crp[s, j] = self.crnd_host[gid]
-                alp[s, j] = self.alive_mask[gid]
-                if limnp is not None:
-                    limp[s, j] = limnp[gid]
-                valsp[s, j] = values[row]
-                lane_of[gid] = (s, j)
-        self._ensure_placement()
-        fn = self._packed_dispatch(nblk)
-        self._count(nblk is not None)
-        self.stack, self.lstate, fresh, _inst_d, _win, value = fn(
-            seg,
-            nip,
-            crp,
-            enp,
-            alp,
-            self.stack,
-            self.lstate,
-            jnp.asarray(valsp),
-            reclaim_limit=limp,
-        )
-        fresh = np.asarray(fresh).reshape(n_sh, c, be)
-        value = np.asarray(value).reshape(n_sh, c, be, v)
-        fresh = np.stack([fresh[lane_of[gid]] for gid in gids])
-        value = np.stack([value[lane_of[gid]] for gid in gids])
+            self._count(nblk is not None)
+            if c is None:
+                marks_slot = [marks[gid] for gid in pm.group_of]
+                rows = slots = [pm.slot_of[gid] for gid in gids]
+                vals_f, act_f = plan_mod.scatter_rows(
+                    gids, values, active, self.cfg.n_groups,
+                    self.cfg.value_words,
+                )
+                perm = list(pm.group_of)
+                fresh, _inst, value = self._launch_full(
+                    self._full_program(nblk, slots, marks_slot, be),
+                    np.asarray(member, np.int32)[perm],
+                    vals_f[perm],
+                    act_f[perm],
+                )
+            else:
+                seg, nip, crp, enp, alp, limp, valsp = self._lane_tables(c, be)
+                limnp = self._reclaim_limits_np()
+                rows = [0] * len(gids)
+                for s in range(n_sh):
+                    for j, row in enumerate(lanes[s]):
+                        gid = gids[row]
+                        seg[s, j] = pm.row_of(gid)
+                        enp[s, j] = 1
+                        nip[s, j] = marks[gid]
+                        crp[s, j] = self.crnd_host[gid]
+                        alp[s, j] = self.alive_mask[gid]
+                        if limnp is not None:
+                            limp[s, j] = limnp[gid]
+                        valsp[s, j] = values[row]
+                        rows[row] = s * c + j
+                prog = plan_mod.ShardedProgram("packed", self._window(nblk, be), c, be)
+                fresh, value = self._launch_packed(
+                    prog, seg, nip, crp, enp, alp, limp, valsp
+                )
+            if obs.enabled():
+                sp.set_metadata(
+                    members=len(gids),
+                    lanes=self.cfg.n_groups if c is None else n_sh * c,
+                    burst=be, full=int(c is None),
+                )
+        with obs.span("repro.hw.readback"):
+            fresh = np.asarray(fresh)[rows]
+            value = np.asarray(value)[rows]
         for gid in gids:
             self.next_inst_host[gid] += be
         self._sync_cstate()
-        self.last_gb = plan_gb
-        if defer:
-            return _DeferredRound.resolved(fresh, value, inst)
-        return fresh, inst, value
-
-    @mirror_guard
-    def _cohort_full_width(
-        self, gids, member, nblk, inst, values, active, defer: bool,
-    ):
-        """Full-width folded execution for saturated cohorts: non-members
-        ride the dispatch inert (NOP sentinel rows, membership-masked
-        crnd), exactly the unsharded cohort oracle's packing convention
-        (``plan.scatter_rows``), permuted into slot order for the slabs."""
-        g = self.cfg.n_groups
-        be = values.shape[1]
-        pm = self._placement
-        marks = self.next_inst_host
-        perm = list(pm.group_of)       # slot -> gid
-        marks_slot = [marks[gid] for gid in perm]
-        plan_gb = plan_mod.fold_width_full(
-            [pm.slot_of[gid] for gid in gids], marks_slot, self._fold_width()
-        )
-        gb = plan_gb if nblk is not None else 1
-        vals_f, act_f = plan_mod.scatter_rows(
-            gids, values, active, g, self.cfg.value_words
-        )
-        memp = np.asarray(member, np.int32)[perm]
-        eff_crnd = np.where(
-            memp != 0, np.asarray(self.crnd_host, np.int32)[perm], NO_ROUND
-        ).astype(np.int32)
-        lim = self._reclaim_limits_np()
-        self._ensure_placement()
-        fn = self._dispatch(nblk, gb)
-        self._count(nblk is not None)
-        self.stack, self.lstate, fresh, _inst_d, _win, value = fn(
-            np.asarray(marks, np.int32)[perm],
-            eff_crnd,
-            memp,
-            self.alive_mask[perm],
-            self.stack,
-            self.lstate,
-            jnp.asarray(vals_f[perm]),
-            jnp.asarray(act_f[perm]),
-            reclaim_limit=None if lim is None else lim[perm],
-        )
-        inv = list(pm.slot_of)         # gid -> slot: gather back to gid order
-        fresh = np.asarray(fresh)[inv][gids]
-        value = np.asarray(value)[inv][gids]
-        for gid in gids:
-            self.next_inst_host[gid] += be
-        self._sync_cstate()
-        self.last_gb = plan_gb
         if defer:
             return _DeferredRound.resolved(fresh, value, inst)
         return fresh, inst, value
@@ -1656,6 +1697,10 @@ class PaxosContext:
             self.snapshots = SnapshotStore()
             self.hw.enable_reclamation()
         self.stats = {"delivered": 0, "retransmits": 0}
+        if mesh is not None and use_kernels:
+            # the sharded dataplane's programs are a closed list: compile
+            # them all before the first submit, none while serving
+            self.hw.precompile()
 
     # -- paper API -----------------------------------------------------------
     def _check_group(self, group: int) -> None:
@@ -1916,13 +1961,19 @@ class PaxosContext:
             pending = [len(q) for q in queues]
             chunks = [q[:b] for q in queues]
             queues = [q[b:] for q in queues]
-            rp = self.planner.plan_round(
-                [len(c) for c in chunks],
-                hw.next_inst_host,
-                hw.live_host,
-                hw.crnd_host,
-                pending=pending,
-            )
+            with obs.span("repro.plan.round") as sp:
+                rp = self.planner.plan_round(
+                    [len(c) for c in chunks],
+                    hw.next_inst_host,
+                    hw.live_host,
+                    hw.crnd_host,
+                    pending=pending,
+                )
+                if obs.enabled():
+                    sp.set_metadata(
+                        cohorts=len(rp.cohorts),
+                        members=sum(len(c.gids) for c in rp.cohorts),
+                    )
             for gid, target in rp.realign:
                 hw.burn_forward(gid, target)
             wave: list[tuple[tuple[int, ...], Any]] = []
@@ -2136,11 +2187,8 @@ class PaxosContext:
             self._check_group(gid)
             hw = self.hw
             if self.grouped:
-                row = hw._slab_row(gid)
                 seq_mark = hw.next_inst_host[gid]
-                ld = np.asarray(hw.lstate.delivered[row])
-                li = np.asarray(hw.lstate.inst[row])
-                lv = np.asarray(hw.lstate.value[row])
+                ld, li, lv = hw.ring_rows(gid)
             else:
                 seq_mark = hw._next_inst_host
                 ld = np.asarray(hw.lstate.delivered)

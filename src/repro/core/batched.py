@@ -560,20 +560,33 @@ def packed_multigroup_round(
 
 
 def init_multigroup_state(
-    n_groups: int, n_acceptors: int, n_instances: int, value_words: int
+    n_groups: int, n_acceptors: int, n_instances: int, value_words: int,
+    sharding: jax.sharding.Sharding | None = None,
 ) -> tuple[CoordinatorState, AcceptorState, LearnerState]:
-    """Freshly initialized (G,)-stacked coordinator/acceptor/learner state."""
+    """Freshly initialized (G,)-stacked coordinator/acceptor/learner state.
+
+    With ``sharding`` the acceptor and learner slabs are built in place,
+    each device writing only its own part: slabs that fit the mesh need not
+    fit one device."""
     cstate = CoordinatorState(
         next_inst=jnp.zeros((n_groups,), jnp.int32),
         crnd=jnp.zeros((n_groups,), jnp.int32),
     )
-    one = AcceptorState.init(n_instances, value_words)
-    stack = jax.tree_util.tree_map(
-        lambda x: jnp.broadcast_to(x, (n_groups, n_acceptors) + x.shape).copy(),
-        one,
-    )
-    lstate = jax.tree_util.tree_map(
-        lambda x: jnp.broadcast_to(x, (n_groups,) + x.shape).copy(),
-        LearnerState.init(n_instances, value_words),
-    )
+
+    def slabs():
+        one = AcceptorState.init(n_instances, value_words)
+        stack = jax.tree_util.tree_map(
+            lambda x: jnp.broadcast_to(x, (n_groups, n_acceptors) + x.shape).copy(),
+            one,
+        )
+        lstate = jax.tree_util.tree_map(
+            lambda x: jnp.broadcast_to(x, (n_groups,) + x.shape).copy(),
+            LearnerState.init(n_instances, value_words),
+        )
+        return stack, lstate
+
+    if sharding is None:
+        stack, lstate = slabs()
+    else:
+        stack, lstate = jax.jit(slabs, out_shardings=sharding)()
     return cstate, stack, lstate

@@ -192,18 +192,19 @@ def make_sharded_multigroup_round(
     along inert — see the enabled-mask path in ``kernels.wirepath``
     (DESIGN.md §7): membership events therefore never move slab state.
 
-    Returns ``step(next_inst[G], crnd[G], enabled[G], alive[G, A], stack,
-    lstate, values[G, B, V], active[G, B]) -> (stack', lstate',
-    fresh[G, B], inst[G, B], win[G, B], value[G, B, V])`` with the state
-    arguments donated (device-resident in place across rounds).
+    Returns ``sharded_multigroup_round(next_inst[G], crnd[G], enabled[G],
+    alive[G, A], stack, lstate, values[G, B, V], active[G, B]) -> (stack',
+    lstate', fresh[G, B], inst[G, B], win[G, B], value[G, B, V])`` with the
+    state arguments donated (device-resident in place across rounds).
 
     Under the cohort dispatch planner (DESIGN.md §8) the same step serves
     every tier of a round plan: ``B`` is the tier's right-sized burst (the
-    step retraces per distinct pow2 burst — a bounded vocabulary), the
-    ``enabled`` mask is the tier's membership, and ``group_block`` is the
-    per-cohort fold width (``core.plan.fold_width_full`` against the
-    per-shard slab), and ``window_blocks`` the kernel's ring blocks per
-    group (``core.plan.window_blocks``; ``None`` covers any offset).  The
+    step retraces per distinct pow2 burst), the ``enabled`` mask is the
+    tier's membership, ``group_block`` the fold width
+    (``core.plan.full_fold`` against the per-shard slab), and
+    ``window_blocks`` the kernel's ring blocks per group
+    (``core.plan.sharded_window_blocks``; ``None`` covers any offset); the
+    programs a dataplane may build are ``core.plan.sharded_programs``.  The
     group axis is *not* compacted here — shard_map
     needs uniform per-shard shapes, and a cohort may concentrate on one
     shard — so non-member slabs ride each tier inert; the unsharded
@@ -312,7 +313,8 @@ def make_sharded_multigroup_round(
             ),
         )
 
-    def step(
+    # the name of the XLA module on the device's trace
+    def sharded_multigroup_round(
         next_inst: Any,
         crnd: Any,
         enabled: Any,
@@ -342,7 +344,7 @@ def make_sharded_multigroup_round(
             active,
         )
 
-    return jax.jit(step, donate_argnums=(4, 5))
+    return jax.jit(sharded_multigroup_round, donate_argnums=(4, 5))
 
 
 def make_packed_sharded_round(
@@ -367,17 +369,17 @@ def make_packed_sharded_round(
     riding scalar prefetch, with pad lanes (``enabled == 0``) inert.  All
     control tables are per-LANE, packed by the caller in lane order:
 
-        step(segids[S, C], next_inst[S, C], crnd[S, C], enabled[S, C],
-             alive[S, C, A], stack, lstate, values[S, C, B, V],
-             reclaim_limit[S, C] | None)
+        packed_sharded_round(segids[S, C], next_inst[S, C], crnd[S, C],
+             enabled[S, C], alive[S, C, A], stack, lstate,
+             values[S, C, B, V], reclaim_limit[S, C] | None)
           -> (stack', lstate', fresh[S*C, B], inst[S*C, B], win[S*C, B],
               value[S*C, B, V])
 
     with shard ``s``'s lane ``j`` at packed row ``s*C + j`` of the outputs,
     state donated in place, and the slab state updated bit-identically to
     the full-width dispatch (pads and absent rows untouched).  ``C`` is a
-    trace-time shape: the step retraces per distinct (C, B) — both pow2-
-    quantized vocabularies bounded by the planner.  ``window_blocks`` is
+    trace-time shape: the step retraces per distinct (C, B), both drawn
+    from ``core.plan.sharded_programs``.  ``window_blocks`` is
     the kernel's ring blocks per lane (``None`` covers any offset).
     """
     if axis not in mesh.shape:
@@ -463,7 +465,8 @@ def make_packed_sharded_round(
             ),
         )
 
-    def packed_step(
+    # the name of the XLA module on the device's trace
+    def packed_sharded_round(
         segids: Any,
         next_inst: Any,
         crnd: Any,
@@ -491,7 +494,7 @@ def make_packed_sharded_round(
             values,
         )
 
-    return jax.jit(packed_step, donate_argnums=(5, 6))
+    return jax.jit(packed_sharded_round, donate_argnums=(5, 6))
 
 
 # ---------------------------------------------------------------------------

@@ -38,6 +38,14 @@ never re-engaged.  ``plan.py`` owns all of those decisions in one place:
   (``PaxosConfig.realign_after = None``) because burning forward changes
   instance numbering relative to an independent deployment — services opt
   in when they prefer amortization over twin-exact numbering.
+
+* **The sharded program vocabulary** — ``sharded_programs`` lists, from the
+  configuration and the shard count alone, every program the groups-sharded
+  dataplane may run, so that a context can compile all of them before it
+  serves.  Packed dispatches run lanes per shard in powers of
+  ``LANE_STEP`` (``packed_lanes``), full-width dispatches fold at the whole
+  cap or not at all (``full_fold``), and every kernel dispatch visits the
+  ring blocks a window at any offset needs (``sharded_window_blocks``).
 """
 from __future__ import annotations
 
@@ -56,6 +64,10 @@ MIN_BURST = 8               # smallest wire burst (pow2 quantization floor)
 # scale it by the words a group-slot holds in VMEM (``fold_cap``).
 MAX_FOLD_LANES = 8192
 _FOLD_SHAPE = (5, 16)       # the (A, V) at which MAX_FOLD_LANES compiled
+# Packed sharded dispatches run 1, 8, 64, ... lanes per shard: three lane
+# counts below a 256-group slab instead of eight, at the price of up to
+# LANE_STEP - 1 inert pad lanes per member on the fullest shard.
+LANE_STEP = 8
 
 
 def wire_block(b: int) -> int:
@@ -126,6 +138,83 @@ def quantize_burst(n: int, cap: int) -> int:
     while be < n:
         be *= 2
     return min(be, cap)
+
+
+def burst_sizes(batch: int) -> list[int]:
+    """Every burst ``quantize_burst`` can mint under ``batch``."""
+    out, n = set(), 1
+    while True:
+        out.add(quantize_burst(n, batch))
+        if n >= batch:
+            return sorted(out)
+        n *= 2
+
+
+def packed_lanes(residency: int, groups_per_shard: int) -> int | None:
+    """Lanes per shard of a packed sharded dispatch whose fullest shard
+    holds ``residency`` members: the least power of ``LANE_STEP`` that
+    holds them, or ``None`` -- the full-width fold -- where that would
+    reach the slab's height."""
+    c = 1
+    while c < residency:
+        c *= LANE_STEP
+    return c if c < groups_per_shard else None
+
+
+def full_fold(gids: Sequence[int], marks: Sequence[int], cap: int) -> int:
+    """Fold width a full-width sharded kernel dispatch runs: the whole
+    ``cap`` where every ``cap``-aligned block's members share a watermark,
+    else 1.  Two widths per burst instead of one per divisor of ``cap``; a
+    cohort that could fold at an intermediate width runs unfolded."""
+    return cap if _block_lockstep(gids, marks, cap) else 1
+
+
+def sharded_window_blocks(n_instances: int, b: int) -> int:
+    """Ring blocks every sharded kernel dispatch of ``b``-slot windows
+    visits: as many as a window at any offset within its first block needs
+    (the kernels' own default), so that the count follows from the burst
+    alone.  A window that fits in fewer blocks visits one more, loaded and
+    stored back unchanged."""
+    bb = ring_block(n_instances)
+    return min(n_instances // bb, -(-b // bb) + 1)
+
+
+@dataclasses.dataclass(frozen=True)
+class ShardedProgram:
+    """One program of the groups-sharded dataplane: a ``packed`` dispatch
+    of ``width`` lanes per shard, or a ``full``-width one folding ``width``
+    groups per grid step; ``window_blocks`` is ``None`` on the jnp
+    engine."""
+
+    kind: str
+    window_blocks: int | None
+    width: int
+    burst: int
+
+
+def sharded_programs(cfg: Any, n_shards: int, kernels: bool) -> list[ShardedProgram]:
+    """Every program the groups-sharded dataplane may run for ``cfg``
+    (a ``PaxosConfig``) over ``n_shards`` shards, on the kernel engine or
+    the jnp one.  Packing and the packed/full-width crossover choose only
+    from this list.  A kernel dataplane falls back to the jnp engine for a
+    window that would wrap onto its own first ring block, which only a ring
+    shorter than a burst plus one block allows."""
+    n, gl = cfg.n_instances, cfg.n_groups // n_shards
+    bb = ring_block(n)
+    lanes = [c for c in (LANE_STEP**k for k in range(gl.bit_length())) if c < gl]
+    out = []
+    for b in burst_sizes(cfg.batch):
+        engines: list[int | None] = [None]
+        if kernels:
+            engines = [sharded_window_blocks(n, b)]
+            if bb - 1 + b > n:
+                engines.append(None)
+        for nblk in engines:
+            cap = fold_cap(gl, n, cfg.n_acceptors, cfg.value_words)
+            folds = sorted({1, cap}) if nblk is not None else [1]
+            out += [ShardedProgram("packed", nblk, c, b) for c in lanes]
+            out += [ShardedProgram("full", nblk, gb, b) for gb in folds]
+    return out
 
 
 def _divisors(cap: int) -> list[int]:

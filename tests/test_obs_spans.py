@@ -155,8 +155,9 @@ def test_off_state_changes_no_result(traced):
 
 
 def test_grouped_pump_span_counts_its_submits(tmp_path):
-    """The multi-group pump carries the pump span alone: its ``submits``
-    add up to the ops submitted, and nothing inside it is spanned."""
+    """The multi-group pump carries the pump span and the planner's round:
+    its ``submits`` add up to the ops submitted, each planned cohort is one
+    dispatch, and the unsharded dispatches are not spanned."""
     ctx = PaxosContext(PaxosConfig(n_acceptors=3, n_instances=64, batch=8, n_groups=4))
     svc = ConsensusService(ctx)
     submitted = 0
@@ -171,8 +172,47 @@ def test_grouped_pump_span_counts_its_submits(tmp_path):
         jax.profiler.stop_trace()
     events = _program_events(str(tmp_path))
     assert ctx.hw.dispatch_count > 0
-    assert {n for _s, _e, n, _m in events} == {"repro.ctx.pump", "repro.ctx.retransmit"}
+    assert {n for _s, _e, n, _m in events} == {
+        "repro.ctx.pump", "repro.ctx.retransmit", "repro.plan.round"}
     pumps = [m for _s, _e, n, m in events if n == "repro.ctx.pump"]
     assert len(pumps) == 6
     assert sum(m["submits"] for m in pumps) == submitted
     assert pumps[-1]["pending"] == len(ctx._pending)
+    plans = [m for _s, _e, n, m in events if n == "repro.plan.round"]
+    assert sum(m["cohorts"] for m in plans) == ctx.hw.dispatch_count
+    assert all(m["members"] >= m["cohorts"] for m in plans)
+
+
+def test_sharded_pump_spans_each_dispatch(tmp_path):
+    """A groups-sharded context spans each dispatch: one ``repro.hw.launch``
+    and one ``repro.hw.readback`` per ``dispatch_count`` step, the launches'
+    ``members`` add up to the planned cohorts' members, and a context built
+    with the kernels spans the precompile of its programs."""
+    from repro.launch.mesh import make_group_mesh
+
+    cfg = PaxosConfig(n_acceptors=3, n_instances=64, batch=8, n_groups=4)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        ctx = PaxosContext(cfg, use_kernels=True, mesh=make_group_mesh())
+        svc = ConsensusService(ctx)
+        for step in range(5):
+            for i in range(3 + 3 * step):
+                svc.session(f"tenant{i % 7}").submit(b"op%d.%d" % (step, i))
+            svc.pump()
+    finally:
+        jax.profiler.stop_trace()
+    events = _program_events(str(tmp_path))
+
+    def of(name):
+        return [m for _s, _e, n, m in events if n == name]
+
+    [pre] = of("repro.hw.precompile")
+    assert pre["programs"] == len(ctx.hw.programs) > 0
+    launches, plans = of("repro.hw.launch"), of("repro.plan.round")
+    assert len(launches) == len(of("repro.hw.readback")) == ctx.hw.dispatch_count > 0
+    assert sum(m["cohorts"] for m in plans) == ctx.hw.dispatch_count
+    assert sum(m["members"] for m in launches) == sum(m["members"] for m in plans)
+    for m in launches:
+        assert {"members", "lanes", "burst", "full"} <= set(m)
+        assert m["lanes"] >= m["members"] and m["full"] in (0, 1)
+    assert sum(len(log) for log in ctx.group_log) == sum(3 + 3 * s for s in range(5))

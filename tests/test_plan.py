@@ -473,3 +473,53 @@ def test_sharded_planner_clamps_wave_depth_to_one():
     # the identical inputs mint rounds=3 on the unsharded planner (above)
     assert rp.cohorts == (plan_mod.Cohort(gids=(0, 1), burst=128, rounds=1),)
     assert p.stats["persistent_waves"] == 0
+
+
+def test_sharded_program_vocabulary_is_closed_and_small():
+    """The four-chip deployment's 1,024 groups of the paper's shape: every
+    program its sharded dispatch may run is one of 25, listed from the
+    configuration and the shard count alone."""
+    cfg = PaxosConfig(n_acceptors=3, n_instances=65536, value_words=16,
+                      batch=128, n_groups=1024)
+    progs = plan_mod.sharded_programs(cfg, 4, kernels=True)
+    assert len(progs) == len(set(progs)) == 25
+    assert plan_mod.burst_sizes(128) == [8, 16, 32, 64, 128]
+    assert {p.width for p in progs if p.kind == "packed"} == {1, 8, 64}
+    assert {p.width for p in progs if p.kind == "full"} == {1, 64}
+    assert {p.window_blocks for p in progs} == {2}
+    # the jnp engine: one window-less program per lane count and burst
+    jnp_progs = plan_mod.sharded_programs(cfg, 4, kernels=False)
+    assert {p.window_blocks for p in jnp_progs} == {None}
+    assert {p.width for p in jnp_progs if p.kind == "full"} == {1}
+    # a ring a window can wrap lists the jnp fallback beside the kernels
+    small = PaxosConfig(n_acceptors=3, n_instances=64, batch=8, n_groups=2)
+    assert {p.window_blocks for p in plan_mod.sharded_programs(small, 1, True)} == {1, None}
+
+
+@pytest.mark.parametrize("residency,gl,want", [
+    (1, 256, 1), (2, 256, 8), (8, 256, 8), (9, 256, 64), (64, 256, 64),
+    (65, 256, None), (1, 1, None), (3, 16, 8), (9, 16, None),
+])
+def test_packed_lanes_step_by_lane_step(residency, gl, want):
+    assert plan_mod.packed_lanes(residency, gl) == want
+
+
+def test_full_fold_is_the_cap_or_one():
+    marks = [0] * 8 + [16] * 8
+    assert plan_mod.full_fold(range(16), marks, 8) == 8
+    assert plan_mod.full_fold(range(16), marks, 16) == 1
+    # fold_width_full would find 4 here; the vocabulary runs it unfolded
+    marks = [0] * 4 + [16] * 4
+    assert fold_width_full(range(8), marks, 8) == 4
+    assert plan_mod.full_fold(range(8), marks, 8) == 1
+
+
+@pytest.mark.parametrize("n,b,want", [(65536, 8, 2), (65536, 128, 2), (128, 16, 1),
+                                      (64, 8, 1), (1024, 256, 3)])
+def test_sharded_window_blocks_follow_the_burst(n, b, want):
+    assert plan_mod.sharded_window_blocks(n, b) == want
+    bb = plan_mod.ring_block(n)
+    # enough for every window that fits the ring, from any offset
+    for base in range(0, bb, 8):
+        got = plan_mod.window_blocks(n, [base], b)
+        assert got is None or got <= want

@@ -280,3 +280,135 @@ def test_sharded_multidevice():
         """
     )
     assert "SHARDED_OK" in out
+
+
+_VOCABULARY_RUN = """
+    import json, sys
+    import numpy as np, jax
+    sys.path.insert(0, "tests")
+    from test_sharded_multigroup import _ScalarGroup
+    from repro.core import (MultiGroupDataplane, PaxosConfig, PaxosContext,
+                            ShardedMultiGroupDataplane)
+    from repro.launch.mesh import make_group_mesh
+    from repro.serve import ConsensusService
+
+    assert len(jax.devices()) == 4
+    # 16 groups per shard: packed at 1 and 8 lanes, full width past 8
+    cfg = PaxosConfig(n_acceptors=3, n_instances=1024, batch=16, n_groups=64)
+    out = {}
+
+    # a seeded Zipf schedule through the served path, on a kernel context
+    # (which compiles its vocabulary as it is built) and an unsharded jnp one
+    ctx = PaxosContext(cfg, use_kernels=True, mesh=make_group_mesh())
+    ref = PaxosContext(cfg, use_kernels=False)
+    compiled, ran, pumping = [], [], [False]
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda ev, _s, **kw: compiled.append(kw.get("fun_name"))
+        if pumping[0] and ev == "/jax/core/compile/backend_compile_duration"
+        else None)
+
+    def record(dp, ran):
+        # note the program of every dispatch ``dp`` runs from now on
+        for name in ("_launch_packed", "_launch_full"):
+            launch = getattr(dp, name)
+
+            def recorded(prog, *a, _launch=launch):
+                ran.append(prog)
+                return _launch(prog, *a)
+
+            setattr(dp, name, recorded)
+
+    record(ctx.hw, ran)
+    svc, rsvc = ConsensusService(ctx), ConsensusService(ref)
+    rng = np.random.default_rng(2**31 + 2024)
+    w = 1.0 / np.arange(1, 257) ** 0.99
+    for wave, k in enumerate([1, 2, 3, 5, 9, 20, 60, 150, 400, 4, 33]):
+        for i, r in enumerate(rng.choice(256, size=k, p=w / w.sum())):
+            for s in (svc, rsvc):
+                s.session(f"tenant{r}").submit(b"w%d.%d" % (wave, i))
+        pumping[0] = True
+        svc.pump()
+        pumping[0] = False
+        rsvc.pump()
+    out["compiled"] = compiled
+    out["n_programs"] = len(ctx.hw.programs)
+    out["ran"] = sorted({(p.kind, p.width, p.burst) for p in ran})
+    out["outside"] = [str(p) for p in ran if p not in ctx.hw.programs]
+    out["logs_equal"] = ctx.group_log == ref.group_log
+    out["delivered"] = sum(len(log) for log in ctx.group_log)
+    out["state_equal"] = all(
+        np.array_equal(np.asarray(x), np.asarray(y)) for x, y in zip(
+            jax.tree_util.tree_leaves((ctx.hw.stack, ctx.hw.lstate)),
+            jax.tree_util.tree_leaves((ref.hw.stack, ref.hw.lstate))))
+
+    # cohorts whose fullest shard holds 2 to 5 members run 8 packed lanes,
+    # between the quantization steps 1 and 8: equal to the unsharded
+    # dataplane and to the scalar roles, through a dead acceptor
+    sh = ShardedMultiGroupDataplane(cfg, mesh=make_group_mesh(), use_kernels=True)
+    mg = MultiGroupDataplane(cfg, use_kernels=False)
+    oracles = [_ScalarGroup(3, cfg.n_instances) for _ in range(64)]
+    alive = np.ones((64, 3), bool)
+    for dp in (sh, mg):
+        dp.kill_acceptor(17, 2)
+    alive[17, 2] = False
+    cohorts = [[0, 5, 9, 17], [2, 3, 4, 17, 40], [1, 6, 7, 11, 12, 20, 33],
+               [9, 17, 18, 19, 50, 51], [0, 5, 9, 17]]
+    widths, equal, sh_ran = [], True, []
+    record(sh, sh_ran)
+    for r, gids in enumerate(cohorts):
+        be = (16, 8)[r % 2]
+        vals = rng.integers(-99, 99, (len(gids), be, 16)).astype(np.int32)
+        act = np.ones((len(gids), be), bool)
+        got = sh.pipeline_cohort(gids, vals, act)
+        want = mg.pipeline_cohort(gids, vals, act)
+        widths.append(sh_ran[-1].width)
+        equal &= all(np.array_equal(x, y) for x, y in zip(got, want))
+        fresh, inst, value = got
+        for row, gid in enumerate(gids):
+            for j, d in enumerate(oracles[gid].round(vals[row], alive[gid])):
+                equal &= (d is not None) == bool(fresh[row, j])
+                if d is not None:
+                    equal &= d.inst == inst[row, j]
+                    equal &= np.array_equal(d.value, value[row, j])
+    equal &= all(np.array_equal(np.asarray(x), np.asarray(y)) for x, y in zip(
+        jax.tree_util.tree_leaves((sh.stack, sh.lstate)),
+        jax.tree_util.tree_leaves((mg.stack, mg.lstate))))
+    out["parity_widths"] = widths
+    out["parity_equal"] = bool(equal)
+    print("VOCAB " + json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def vocabulary_run():
+    out = _run(_VOCABULARY_RUN, devices=4)
+    line = next(ln for ln in out.splitlines() if ln.startswith("VOCAB "))
+    import json
+
+    return json.loads(line[len("VOCAB "):])
+
+
+def test_zipf_schedule_runs_only_listed_programs(vocabulary_run):
+    """Every program a seeded Zipf schedule makes the sharded dispatch run
+    is in ``programs``, the list ``core.plan.sharded_programs`` gives."""
+    r = vocabulary_run
+    assert r["outside"] == []
+    assert r["n_programs"] == 8            # 2 bursts x (2 packed + 2 full)
+    kinds = {(kind, width) for kind, width, _b in r["ran"]}
+    assert {("packed", 1), ("packed", 8), ("full", 1)} <= kinds
+
+
+def test_sharded_context_compiles_nothing_after_construction(vocabulary_run):
+    """A sharded kernel context compiles its programs as it is built:
+    pumping the schedule then compiles nothing, and the inert warm-up runs
+    left the slabs as the unsharded context's."""
+    r = vocabulary_run
+    assert r["compiled"] == []
+    assert r["logs_equal"] and r["delivered"] == 687
+    assert r["state_equal"]
+
+
+def test_packed_lanes_between_steps_match_unsharded_and_scalar_oracle(vocabulary_run):
+    r = vocabulary_run
+    assert r["parity_widths"] == [8, 8, 8, 8, 8]
+    assert r["parity_equal"]

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import batched, plan
+from repro.core import batched, fabric, plan
 from repro.core.types import AcceptorState, CoordinatorState
 from repro.kernels import coordinator, digest, wirepath
 
@@ -151,3 +151,53 @@ def test_jnp_engine_fits_one_chip(shape, b):
         shape(g, a).update(dtype=jnp.bool_), 2, reclaim_limit=shape(g),
     ).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**30
+
+
+@pytest.mark.parametrize("kind,width,b", [("packed", 64, 128), ("full", 64, 128),
+                                          ("full", 1, 8)])
+def test_sharded_program_compiles_for_four_chips(topo, monkeypatch, kind, width, b):
+    """The four-chip tenants deployment's programs at their real size:
+    1,024 groups over a 2x2 mesh, 256 per chip.  Each compiles, updates
+    the donated slabs in place, and needs little beside them."""
+    import numpy as np
+    from jax.sharding import AxisType, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.kernels import ops as kops
+
+    monkeypatch.setattr(kops, "INTERPRET", False)
+    mesh = jax.sharding.Mesh(
+        np.array(topo.devices), ("groups",), axis_types=(AxisType.Auto,)
+    )
+    g, a, s = 1024, 3, 4
+    slab, rep = NamedSharding(mesh, P("groups")), NamedSharding(mesh, P())
+
+    def arg(*dims, sharding=rep, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
+
+    stack = AcceptorState(arg(g, a, N, sharding=slab), arg(g, a, N, sharding=slab),
+                          arg(g, a, N, V, sharding=slab))
+    lstate = batched.LearnerState(arg(g, N, sharding=slab), arg(g, N, sharding=slab),
+                                  arg(g, N, V, sharding=slab))
+    nblk = plan.sharded_window_blocks(N, b)
+    if kind == "packed":
+        fn = fabric.make_packed_sharded_round(
+            mesh, quorum=2, use_kernels=True, window_blocks=nblk)
+        c = width
+        lowered = fn.lower(arg(s, c), arg(s, c), arg(s, c), arg(s, c), arg(s, c, a),
+                           stack, lstate, arg(s, c, b, V, sharding=slab),
+                           reclaim_limit=arg(s, c))
+    else:
+        fn = fabric.make_sharded_multigroup_round(
+            mesh, n_groups=g, quorum=2, use_kernels=True, group_block=width,
+            window_blocks=nblk)
+        lowered = fn.lower(arg(g), arg(g), arg(g), arg(g, a), stack, lstate,
+                           arg(g, b, V, sharding=slab),
+                           arg(g, b, sharding=slab, dtype=jnp.bool_),
+                           reclaim_limit=arg(g))
+    # the benchmark finds the program on the device trace by this name
+    assert "sharded" in lowered.as_text().split("\n", 1)[0]
+    mem = lowered.compile().memory_analysis()
+    per_chip = g // s * (a * (2 + V) + 2 + V) * N * 4
+    assert mem.alias_size_in_bytes == per_chip
+    assert mem.temp_size_in_bytes < 2**30
