@@ -2169,9 +2169,23 @@ class PaxosContext:
         """The group's complete delivery history: compacted snapshot prefix
         (if any) stitched before the live ``group_log`` — the ONE read that
         is uniform in steady state, at retirement, and after restore."""
+        return self.group_log_since(gid, 0)
+
+    def group_log_since(
+        self, gid: int = 0, start: int = 0
+    ) -> list[tuple[int, bytes]]:
+        """``full_group_log(gid)[start:]`` as a new list, without building
+        the whole stitched history: O(entries past ``start``).  Indices are
+        stable under compaction — ``snapshot_group`` moves the live log's
+        head into the prefix without reordering — so an apply cursor can
+        read from its watermark across any number of compactions."""
+        live = self.group_log[gid]
         if self.snapshots is None:
-            return self.group_log[gid]
-        return self.snapshots.log_prefix(gid) + self.group_log[gid]
+            return live[start:]
+        prefix = self.snapshots.log_prefix(gid)
+        if start >= len(prefix):
+            return live[start - len(prefix):]
+        return prefix[start:] + live
 
     def snapshot_group(
         self, gid: int = 0, upto: int | None = None

@@ -24,12 +24,14 @@ Three layers:
   the wire path.  A stale lease escalates to ONE serialized read-index op,
   which orders behind every surviving earlier op of the session.
 
-Snapshot integration: a replica's apply cursor runs over
-``full_group_log`` — snapshot-store prefix + live log, whose concatenation
-is append-only stable under compaction — and ``ConsensusService.
-adopt_group`` seeds transferred prefixes into that read.  State transfer
-is therefore *applied* host-side, never replayed through the dataplane
-(the dispatch-count tests pin this).
+Snapshot integration: a replica's apply cursor runs over the stitched log
+— snapshot-store prefix + live log, whose concatenation is append-only
+stable under compaction — and reads only its suffix past the cursor
+(``PaxosContext.group_log_since``), so a refresh costs the entries it
+applies, not the history.  ``ConsensusService.adopt_group`` seeds
+transferred prefixes into that read.  State transfer is therefore
+*applied* host-side, never replayed through the dataplane (the
+dispatch-count tests pin this).
 """
 from __future__ import annotations
 
@@ -172,21 +174,29 @@ class GroupReplica:
         self.final = False           # archived segment, fully applied
 
     def apply_log(self, log: list[tuple[int, bytes]]) -> int:
-        """Apply the suffix past the watermark; returns ops consumed.
+        """Apply a whole view of the segment past the watermark; returns ops
+        consumed.
 
-        Safe against any later view of the same segment: ``full_group_log``
+        Safe against any later view of the same segment: the stitched log
         is append-only stable (compaction migrates entries into the
         snapshot prefix without reordering), so the cursor never re-applies
         an entry."""
-        if len(log) < self.applied_len:
+        return self.apply_suffix(log[self.applied_len :], len(log))
+
+    def apply_suffix(
+        self, suffix: list[tuple[int, bytes]], total: int
+    ) -> int:
+        """Apply ``suffix``, the segment's entries from ``applied_len`` on,
+        of a view ``total`` entries long; returns ops consumed.  A view
+        shorter than the watermark raises: the segment log shrank."""
+        if total < self.applied_len:
             raise ValueError(
-                f"segment log shrank: {len(log)} < applied {self.applied_len}"
+                f"segment log shrank: {total} < applied {self.applied_len}"
             )
-        new = log[self.applied_len :]
-        for _inst, payload in new:
+        for _inst, payload in suffix:
             self._apply_one(decode_op(payload))
-        self.applied_len = len(log)
-        return len(new)
+        self.applied_len += len(suffix)
+        return len(suffix)
 
     def _apply_one(self, op: KvOp) -> None:
         prev = self.applied_counter.get(op.sid_tag, 0)
@@ -264,9 +274,13 @@ class ReplicatedKV:
     def refresh(self) -> None:
         """Apply everything already delivered — host-side only.
 
-        Snapshot and adopted prefixes are *applied* here exactly like live
-        entries (they arrive through the same stitched ``full_group_log``
-        read), never replayed through the dataplane."""
+        A live segment reads only its stitched log's suffix past the
+        replica's watermark (``PaxosContext.group_log_since``): the cost is
+        the entries applied, not the history.  Snapshot and adopted
+        prefixes are *applied* here exactly like live entries (they arrive
+        through the same stitched read), never replayed through the
+        dataplane.  The span's ``copied`` counts the entries materialised
+        for the apply — in steady state exactly ``applied``."""
         with obs.span("repro.kv.refresh") as sp:
             svc = self.service
             ctx = svc.ctx
@@ -275,7 +289,9 @@ class ReplicatedKV:
                 for key, log in svc.archived_segments().items():
                     rep = self.replica(*key)
                     if not rep.final:
-                        applied += rep.apply_log(log)
+                        n = rep.apply_log(log)
+                        applied += n
+                        copied += n
                         rep.final = True
                 self._live_reps = [
                     (gid, self.replica(gid)) for gid in ctx.live_groups()
@@ -284,17 +300,16 @@ class ReplicatedKV:
             snaps = self._snaps
             for gid, rep in self._live_reps:
                 # cheap steady-state exit: the stitched log is append-only
-                # stable, so an unchanged length means no new suffix — skip
-                # materializing the prefix+live concatenation (this is what
-                # keeps a leased get O(1) in the history length)
+                # stable, so an unchanged length means no new suffix, and a
+                # changed one means the suffix past the watermark is all
+                # there is to read (this keeps a refresh O(new entries))
                 total = len(ctx.group_log[gid])
                 if snaps is not None:
                     total += len(snaps.log_prefix(gid))
                 if total != rep.applied_len:
-                    log = ctx.full_group_log(gid)
-                    applied += rep.apply_log(log)
-                    if snaps is not None:     # the stitched log is a new list
-                        copied += len(log)
+                    new = ctx.group_log_since(gid, rep.applied_len)
+                    applied += rep.apply_suffix(new, total)
+                    copied += len(new)
             if obs.enabled():
                 sp.set_metadata(copied=copied, applied=applied)
 

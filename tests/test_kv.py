@@ -4,15 +4,22 @@ Covers the op codec (round-trip + malformed-frame rejection), the
 deterministic apply loop's cas/tombstone semantics, lease validity and
 read-watermark monotonicity, the consensus-free read path's dispatch-count
 pin, the payload-width door guards, the typed Session surface, and
-snapshot state transfer applying (never replaying) through the dataplane.
+snapshot state transfer applying (never replaying) through the dataplane,
+and the apply cursor's suffix read: a refresh copies the entries it
+applies, never the history, and lands on the state a fresh replay of the
+whole stitched log gives.
 """
+import contextlib
+import random
 import sys
+import types
 
 import pytest
 
 sys.path.insert(0, "src")
 
 from repro.core.api import PaxosContext  # noqa: E402
+from repro.serve import kv as kv_module  # noqa: E402
 from repro.core.snapshot import RingOverflowError  # noqa: E402
 from repro.core.types import PaxosConfig  # noqa: E402
 from repro.serve.engine import ConsensusService, Session, Ticket  # noqa: E402
@@ -316,3 +323,187 @@ def test_adopted_snapshot_is_applied_not_replayed():
     assert kv2.replica(1).signature() == sig
     # ...without a single wire-path launch: applied, not replayed
     assert ctx2.hw.dispatch_count == 0
+
+
+# ---------------------------------------------------------------------------
+# The apply cursor reads the stitched log's suffix, never the whole history
+# ---------------------------------------------------------------------------
+class _SpanLog:
+    """Stands in for ``repro.obs`` inside ``serve/kv.py``: spans are on and
+    each span's metadata is kept, so a test reads ``copied``/``applied``
+    without a profiler session."""
+
+    def __init__(self):
+        self.spans: list[dict] = []
+
+    def enabled(self) -> bool:
+        return True
+
+    @contextlib.contextmanager
+    def span(self, name, **meta):
+        rec = dict(meta, name=name)
+        self.spans.append(rec)
+        yield types.SimpleNamespace(set_metadata=rec.update)
+
+    def refreshes(self) -> list[dict]:
+        return [m for m in self.spans if m["name"] == "repro.kv.refresh"]
+
+
+def test_refresh_copies_only_the_new_suffix(monkeypatch):
+    """A refresh after k new deliveries copies k entries, whether the
+    history behind the cursor is about 1,000 entries or 16 times that,
+    compacted into the snapshot prefix many times over."""
+    spans = _SpanLog()
+    monkeypatch.setattr(kv_module, "obs", spans)
+    cfg = PaxosConfig(n_acceptors=A, n_instances=4096, batch=128)
+    copied = []
+    for history in (1_000, 16_000):
+        ctx = PaxosContext(cfg, fused=True, snapshots=True)
+        svc = ConsensusService(ctx)
+        kv = ReplicatedKV(svc)
+        s = kv.session("hist")
+        compactions = 0
+        while len(ctx.full_group_log(0)) < history:
+            for i in range(history // 4):
+                s.put(b"k%d" % (i % 97), b"v%d" % i)
+            svc.run_until_quiescent()
+            kv.refresh()
+            ctx.snapshot_group(0)
+            compactions += 1
+        assert compactions >= 2
+        assert len(ctx.snapshots.log_prefix(0)) >= history
+        for i in range(40):                       # live entries past the cut
+            s.put(b"live%d" % i, b"v")
+        svc.run_until_quiescent()
+        kv.refresh()
+        stitched = len(ctx.full_group_log(0))
+        for i in range(5):                        # k = 5 new deliveries
+            s.put(b"new%d" % i, b"v")
+        svc.run_until_quiescent()
+        k = len(ctx.full_group_log(0)) - stitched
+        kv.refresh()
+        last = spans.refreshes()[-1]
+        assert last["applied"] == k == 5
+        copied.append(last["copied"])
+        assert kv.read_watermark(0) == stitched + k
+    assert copied == [5, 5]
+
+
+def _replayed(ctx, gid):
+    """A fresh replica that applied the whole stitched log at once."""
+    rep = GroupReplica()
+    rep.apply_log(ctx.full_group_log(gid))
+    return rep.signature()
+
+
+def _check_replicas(ctx, kvs):
+    """Each of ``kvs`` (just refreshed) against a replay, and the suffix
+    read against a slice of the whole stitched log."""
+    for gid in ctx.live_groups():
+        full = ctx.full_group_log(gid)
+        for start in range(len(full) + 2):
+            assert ctx.group_log_since(gid, start) == full[start:], (gid, start)
+        for kv in kvs:
+            assert kv.replica(gid).signature() == _replayed(ctx, gid), gid
+
+
+def _random_ops(rng, svc, kvs, sessions, steps, snapshots):
+    """Puts, deletes, cas, gets (leased or read-index), pumps, refreshes and
+    snapshots at seeded random points; every refresh is checked against a
+    replay.  Returns the compactions made."""
+    ctx = svc.ctx
+    keys = [b"a", b"b", b"c", b"d"]
+    compactions = 0
+    for _ in range(steps):
+        s = rng.choice(sessions)
+        key = rng.choice(keys)
+        r = rng.random()
+        if r < 0.25:
+            s.put(key, b"p%d" % rng.randrange(1000))
+        elif r < 0.35:
+            s.delete(key)
+        elif r < 0.45:
+            s.cas(key, rng.choice([None, b"", b"p1", s.kv.lookup(s.id, key)]),
+                  b"c%d" % rng.randrange(1000))
+        elif r < 0.6:
+            s.get(key)                   # refreshes the session's own tier
+            _check_replicas(ctx, [s.kv])
+        elif r < 0.75:
+            svc.pump()
+        elif r < 0.88:
+            kv = rng.choice(kvs)
+            kv.refresh()
+            _check_replicas(ctx, [kv])
+        elif snapshots:
+            svc.run_until_quiescent()
+            ctx.snapshot_group(rng.choice(ctx.live_groups()))
+            compactions += 1
+    svc.run_until_quiescent()
+    for kv in kvs:
+        kv.refresh()
+    _check_replicas(ctx, kvs)
+    return compactions
+
+
+@pytest.mark.parametrize(
+    "case", ["attached_after_compaction", "adopted_snapshot", "no_snapshots"]
+)
+def test_suffix_apply_matches_a_whole_log_replay(case):
+    """After every refresh each replica equals a fresh replay of
+    ``full_group_log``, and ``group_log_since(gid, s)`` equals
+    ``full_group_log(gid)[s:]`` for every ``s``: through compactions that
+    move entries the cursor has not reached into the prefix, for a replica
+    first attached after a compaction, over an adopted snapshot, and
+    without snapshots."""
+    rng = random.Random(f"suffix-{case}")
+    cfg = PaxosConfig(n_acceptors=A, n_instances=256, batch=8, n_groups=2)
+    ctx = PaxosContext(cfg, snapshots=case != "no_snapshots")
+    svc = ConsensusService(ctx)
+    reads = []                           # (start, prefix length) per read
+
+    def record_reads(ctx):
+        since = ctx.group_log_since
+
+        def recorded(gid=0, start=0):
+            prefix = ctx.snapshots.log_prefix(gid) if ctx.snapshots else []
+            reads.append((start, len(prefix)))
+            return since(gid, start)
+
+        ctx.group_log_since = recorded
+
+    record_reads(ctx)
+    kv = ReplicatedKV(svc)
+    sessions = [kv.session(f"s{i}") for i in range(6)]
+    assert {svc.group_of(s.id) for s in sessions} == {0, 1}
+    made = _random_ops(rng, svc, [kv], sessions, 120, case != "no_snapshots")
+    kvs = [kv]
+    if case == "attached_after_compaction":
+        assert made >= 2 and ctx.snapshots.log_prefix(0)
+        late = ReplicatedKV(svc)
+        kvs.append(late)
+        late.refresh()                   # its first read starts in the prefix
+        assert reads[-1][0] < reads[-1][1] or reads[-2][0] < reads[-2][1]
+        _check_replicas(ctx, kvs)
+        sessions += [late.session(f"late{i}") for i in range(3)]
+        made += _random_ops(rng, svc, kvs, sessions, 120, True)
+    elif case == "adopted_snapshot":
+        assert made >= 2
+        snap = ctx.snapshot_group(1)
+        prefix = list(ctx.snapshots.log_prefix(1))
+        ctx2 = PaxosContext(cfg, snapshots=True)
+        svc2 = ConsensusService(ctx2)
+        kv2 = ReplicatedKV(svc2)
+        svc2.retire_group(1)
+        assert svc2.adopt_group(snap, log_prefix=prefix) == 1
+        kv2.refresh()
+        assert kv2.replica(1).signature() == _replayed(ctx, 1)
+        assert ctx2.hw.dispatch_count == 0
+        record_reads(ctx2)
+        sessions2 = [kv2.session(f"t{i}") for i in range(6)]
+        made += _random_ops(rng, svc2, [kv2], sessions2, 120, True)
+        ctx = ctx2
+    # some refresh read from inside the prefix, past a cursor that a
+    # compaction overtook (0 < start < prefix length)
+    if case != "no_snapshots":
+        assert any(0 < start < p for start, p in reads), reads
+    assert kv.stats["read_index_gets"] and kv.stats["leased_gets"]

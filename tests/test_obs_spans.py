@@ -134,13 +134,14 @@ def test_span_counts_agree_with_the_program(traced):
     assert len(drains) == 3 and all(m["entries"] > 0 for m in drains)
     prefixes = [m["prefix"] for m in of("repro.snapshot.seal")]
     assert prefixes == sorted(prefixes) and len(prefixes) == 3
-    # each refresh that applied something copied the whole stitched log,
-    # and one that applied nothing copied nothing
+    # each refresh copied exactly the suffix it applied — never the whole
+    # stitched log — and the applied entries add up to that log
     refreshes = of("repro.kv.refresh")
     assert len(refreshes) == len(stitched)
-    assert [m["copied"] for m in refreshes] == [
-        n if m["applied"] else 0 for m, n in zip(refreshes, stitched)]
+    assert [m["copied"] for m in refreshes] == [m["applied"] for m in refreshes]
     assert any(m["copied"] for m in refreshes)
+    assert sum(m["applied"] for m in refreshes) == stitched[-1]
+    assert any(m["copied"] < n for m, n in zip(refreshes, stitched) if m["copied"])
 
 
 def test_off_state_changes_no_result(traced):
