@@ -1,96 +1,13 @@
-"""Emit the EXPERIMENTS.md §Dry-run and §Roofline tables from artifacts.
+"""Render BENCH_wirepath.json as markdown tables.
 
     PYTHONPATH=src python -m benchmarks.make_report > /tmp/tables.md
 """
 from __future__ import annotations
 
-import glob
 import json
 import os
 
-from repro.analysis.roofline import from_record
-
-ART = os.path.join(os.path.dirname(__file__), "artifacts", "dryrun")
 WIREPATH_JSON = os.path.join(os.path.dirname(__file__), "..", "BENCH_wirepath.json")
-
-
-def fmt_bytes(b: float) -> str:
-    if b >= 2**30:
-        return f"{b/2**30:.1f} GiB"
-    if b >= 2**20:
-        return f"{b/2**20:.1f} MiB"
-    return f"{b/2**10:.0f} KiB"
-
-
-def load(pattern: str):
-    out = []
-    for p in sorted(glob.glob(os.path.join(ART, pattern))):
-        with open(p) as f:
-            out.append(json.load(f))
-    return out
-
-
-def dryrun_table() -> None:
-    print("### Dry-run results (per cell x mesh; baseline rules, fixed-digest)\n")
-    print("| arch | shape | mesh | status | compile s | args/dev | temps/dev | coll bytes/dev | coll ops |")
-    print("|---|---|---|---|---|---|---|---|---|")
-    for rec in load("*__base.json"):
-        tag = (rec["arch"], rec["shape"], rec["mesh"])
-        if rec.get("skipped"):
-            print(f"| {tag[0]} | {tag[1]} | {tag[2]} | SKIP (sub-quadratic rule) | — | — | — | — | — |")
-            continue
-        ops = ", ".join(
-            f"{k}x{v}" for k, v in sorted(rec.get("collective_counts", {}).items())
-        )
-        print(
-            f"| {tag[0]} | {tag[1]} | {tag[2]} | ok | {rec.get('compile_s', 0):.1f} "
-            f"| {fmt_bytes(rec.get('arg_bytes_per_dev_est', 0))} "
-            f"| {fmt_bytes(rec.get('temp_bytes', 0))} "
-            f"| {fmt_bytes(rec.get('collective_bytes', 0))} | {ops} |"
-        )
-    print()
-
-
-def roofline_table() -> None:
-    print("### Roofline terms (single-pod 16x16, baseline rules, fixed-digest)\n")
-    print("| arch | shape | t_compute | t_memory | t_collective | dominant | MODEL_FLOPS | useful | roofline frac |")
-    print("|---|---|---|---|---|---|---|---|---|")
-    rows = []
-    for rec in load("*single__base.json"):
-        if not rec.get("ok"):
-            continue
-        rl = from_record(rec)
-        rows.append(rl)
-    rows.sort(key=lambda r: (r.arch, r.shape))
-    for rl in rows:
-        print(
-            f"| {rl.arch} | {rl.shape} | {rl.t_compute*1e3:.2f} ms | "
-            f"{rl.t_memory*1e3:.2f} ms | {rl.t_collective*1e3:.2f} ms | "
-            f"**{rl.dominant}** | {rl.model_flops:.3g} | {rl.useful_ratio:.2f} | "
-            f"{rl.roofline_fraction:.4f} |"
-        )
-    print()
-
-
-def variants_table() -> None:
-    print("### §Perf variant measurements (hillclimbed cells)\n")
-    print("| cell | rules | variant | t_compute | t_memory | t_collective | dominant | frac |")
-    print("|---|---|---|---|---|---|---|---|")
-    for p in sorted(glob.glob(os.path.join(ART, "*.json"))):
-        with open(p) as f:
-            rec = json.load(f)
-        if rec.get("skipped") or not rec.get("ok"):
-            continue
-        if rec.get("variant", "base") == "base" and rec.get("rules") == "base":
-            continue
-        rl = from_record(rec)
-        print(
-            f"| {rl.arch} x {rl.shape} ({rl.mesh}) | {rec.get('rules')} | "
-            f"{rec.get('variant')} | {rl.t_compute*1e3:.2f} ms | "
-            f"{rl.t_memory*1e3:.2f} ms | {rl.t_collective*1e3:.3f} ms | "
-            f"{rl.dominant} | {rl.roofline_fraction:.4f} |"
-        )
-    print()
 
 
 def wirepath_table() -> None:
@@ -160,7 +77,4 @@ def wirepath_table() -> None:
 
 
 if __name__ == "__main__":
-    dryrun_table()
-    roofline_table()
-    variants_table()
     wirepath_table()

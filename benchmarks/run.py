@@ -17,7 +17,6 @@ from . import (
     fig7c_bottleneck_shift,
     fig7d_replicated_kv,
     fig8_failure,
-    roofline_report,
     table1_component_latency,
     table2_throughput,
 )
@@ -31,7 +30,6 @@ SUITES = [
     ("fig7d", fig7d_replicated_kv),
     ("fig8", fig8_failure),
     ("wirepath", bench_wirepath),
-    ("roofline", roofline_report),
 ]
 
 

@@ -4,18 +4,15 @@
   2. acceptor failure (f of 2f+1): throughput holds,
   3. hardware-coordinator failure -> safe software takeover with Phase-1
      re-scan (re-proposing voted instances),
-  4. learner gap + recover(),
-  5. elastic membership view change decided through the log.
+  4. learner gap + recover().
 
     PYTHONPATH=src python examples/failover_demo.py
 """
-import dataclasses
 import sys
 
 sys.path.insert(0, "src")
 
 from repro.core import PaxosConfig, PaxosContext
-from repro.train import elastic
 
 
 def main() -> None:
@@ -53,15 +50,6 @@ def main() -> None:
     ctx.run_until_quiescent()
     assert ctx.learned[0][inst] == lost
     print(f"   instance {inst} recovered: {lost!r}")
-
-    print("5) membership view change decided through the consensus log")
-    view_ctx = PaxosContext(dataclasses.replace(cfg, value_words=64))
-    v0 = elastic.MembershipView(0, ("h0", "h1", "h2", "h3"), (4, 1),
-                                ("data", "model"))
-    vm = elastic.ViewManager(view_ctx, v0)
-    new = vm.propose_view(["h0", "h1", "h3"], model_parallel=1)
-    print(f"   epoch {new.epoch}: hosts={new.hosts} mesh={new.mesh_shape}")
-    assert new.epoch == 1
 
     print("\nall failure paths exercised; no value lost, no double delivery")
 

@@ -264,7 +264,7 @@ MIRROR_ATTRS = frozenset(
 )
 
 # Files whose jax.jit sites are kernel-wrapper dispatches (donation audit
-# scope); training/launch jits donate model state and are out of scope.
+# scope).
 DONATION_FILES = ("core/api.py", "core/fabric.py")
 
 

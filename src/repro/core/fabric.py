@@ -495,33 +495,3 @@ def make_packed_sharded_round(
         )
 
     return jax.jit(packed_sharded_round, donate_argnums=(5, 6))
-
-
-# ---------------------------------------------------------------------------
-# Quorum step-commit for distributed training (straggler mitigation)
-# ---------------------------------------------------------------------------
-def quorum_commit_digest(
-    digest: jax.Array,       # int32[] or int32[k]  this replica-group's digest
-    healthy: jax.Array,      # bool []              this group voted in time
-    *,
-    axis: str,
-    quorum: int,
-) -> tuple[jax.Array, jax.Array]:
-    """Decide a training step commit by digest agreement (inside shard_map).
-
-    Each data-parallel replica group votes with the digest of its gradient
-    contribution; the step commits iff >= quorum healthy groups hold the
-    identical digest.  A straggling / dead group (healthy=False) cannot block
-    the step — the paper's f-of-2f+1 resilience doubles as straggler
-    mitigation.
-
-    Returns (commit: bool[], winning_count: int32[]).
-    """
-    d = jnp.atleast_1d(digest)
-    all_d = jax.lax.all_gather(d, axis)                      # [G, k]
-    all_h = jax.lax.all_gather(healthy, axis)                # [G]
-    eq = jnp.all(all_d[:, None, :] == all_d[None, :, :], -1)  # [G, G]
-    eq = eq & all_h[None, :] & all_h[:, None]
-    counts = jnp.sum(eq.astype(jnp.int32), axis=1)           # votes per digest
-    win = jnp.max(counts)
-    return win >= quorum, win

@@ -1,15 +1,16 @@
-"""Pallas TPU kernel: gradient digest for quorum step-commit.
+"""Pallas TPU kernel: the digest that seals a snapshot (DESIGN.md §9).
 
-Beyond-paper integration (DESIGN.md §3): each data-parallel replica group
-votes for a training step with the *digest* of its gradient contribution; the
-step commits when f+1 of 2f+1 groups agree.  The digest must be (a) cheap —
-it runs every step over every gradient byte — and (b) order-deterministic.
+``core.snapshot.SnapshotStore`` seals each group's drained ring prefix with
+``tree_digest`` so that replicas at equal watermarks can compare snapshots by
+one integer, and a transferred snapshot is checked before it is trusted.  The
+digest must be (a) cheap — it folds every drained slot — and (b)
+order-deterministic.
 
 We use a weighted modular fold over the int32 bit pattern:
 
     digest = sum_i  bits(x_i) * (2*i + 1)   (mod 2^32)
 
-(odd weights make the fold position-sensitive: permuted or shifted gradients
+(odd weights make the fold position-sensitive: permuted or shifted entries
 collide with probability ~2^-32, unlike a plain sum).  The kernel is a
 bandwidth-bound grid reduction: HBM-stream blocks into VMEM, fold in VREGs,
 accumulate into a single scalar tile across grid steps.
@@ -77,7 +78,7 @@ def digest(
 
 
 def tree_digest(tree, *, interpret: bool = False) -> jax.Array:
-    """Digest a whole gradient pytree (combines leaf digests order-sensitively)."""
+    """Digest a whole pytree (combines leaf digests order-sensitively)."""
     leaves = jax.tree_util.tree_leaves(tree)
     acc = jnp.int32(0)
     for leaf in leaves:

@@ -136,37 +136,3 @@ def digest(x: jax.Array) -> jax.Array:
     bits = flat.view(jnp.int32) if flat.dtype != jnp.int32 else flat
     idx = jnp.arange(bits.shape[0], dtype=jnp.int32)
     return jnp.sum(bits * (idx * 2 + 1))
-
-
-def flash_attention(
-    q: jax.Array,            # (B, H, Sq, D)
-    k: jax.Array,            # (B, KVH, Sk, D)
-    v: jax.Array,
-    *,
-    window: int = 0,
-    causal: bool = True,
-    softmax_scale=None,
-) -> jax.Array:
-    """Oracle for kernels.flash_attention (direct softmax, no tiling)."""
-    b, h, sq, d = q.shape
-    kvh, sk = k.shape[1], k.shape[2]
-    g = h // kvh
-    scale = softmax_scale if softmax_scale is not None else d ** -0.5
-    qg = q.reshape(b, kvh, g, sq, d)
-    s = jnp.einsum(
-        "bkgqd,bksd->bkgqs", qg, k, preferred_element_type=jnp.float32
-    ) * scale
-    qpos = jnp.arange(sq)[:, None]
-    kpos = jnp.arange(sk)[None, :]
-    mask = jnp.ones((sq, sk), bool)
-    if causal:
-        mask &= kpos <= qpos
-    if window:
-        mask &= kpos > qpos - window
-    s = jnp.where(mask[None, None, None], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum(
-        "bkgqs,bksd->bkgqd", p.astype(v.dtype), v,
-        preferred_element_type=jnp.float32,
-    )
-    return out.reshape(b, h, sq, d).astype(q.dtype)

@@ -1,14 +1,7 @@
-"""Production mesh construction.
+"""The device mesh of the groups-sharded consensus dataplane.
 
 A function (not a module-level constant) so importing this module never
-touches jax device state.  Shapes:
-
-  * single-pod: (16, 16)    axes (data, model)   — 256 chips (one v5e pod)
-  * multi-pod:  (2, 16, 16) axes (pod, data, model) — 512 chips / 2 pods
-
-The ``pod`` axis maps onto DCN-connected pod boundaries: pure data
-parallelism with hierarchical gradient reduction.  ``data`` is the FSDP axis
-(intra-pod ICI), ``model`` the tensor/expert/sequence-parallel axis.
+touches jax device state.
 """
 from __future__ import annotations
 
@@ -20,20 +13,6 @@ def _auto_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> jax.sharding.Me
     # jax.make_mesh defaults to Explicit axes; every program here places
     # data with NamedSharding and shard_map, which want Auto axes
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-
-
-def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _auto_mesh(shape, axes)
-
-
-def make_host_mesh(n_devices: int = 0, model_parallel: int = 1) -> jax.sharding.Mesh:
-    """Small mesh over the locally available devices (tests / examples)."""
-    n = n_devices or len(jax.devices())
-    mp = model_parallel
-    assert n % mp == 0
-    return _auto_mesh((n // mp, mp), ("data", "model"))
 
 
 def make_group_mesh(n_devices: int = 0) -> jax.sharding.Mesh:

@@ -1,11 +1,7 @@
 from .engine import (  # noqa: F401
     ConsensusService,
-    Request,
-    ServeLoop,
     Session,
     Ticket,
-    make_prefill_step,
-    make_serve_step,
     session_hash,
 )
 from .kv import (  # noqa: F401

@@ -1,58 +1,16 @@
-"""Serving engine: batched prefill + decode over any registry architecture,
-plus the consensus-as-a-service front door.
-
-``prefill_step`` and ``serve_step`` are the two lowered entry points of the
-inference shapes (``prefill_32k`` lowers prefill; ``decode_32k`` /
-``long_500k`` lower one ``serve_step`` against a seq_len-deep cache).  The
-host-side ``ServeLoop`` runs continuous batching over them for the examples
-and benchmarks.
+"""Consensus as a service: the front door of the multi-group dataplane.
 
 ``ConsensusService`` is the serving tier of the multi-group dataplane
 (DESIGN.md §5): client *sessions* hash-route onto the G device-resident
 Paxos groups of a multi-group ``PaxosContext``, so millions of independent
 session streams share one fused dispatch while each session keeps a total
-order within its group.
+order within its group.  ``Session`` is one client's handle on its group,
+and ``Ticket`` names a submission by group and client sequence.
 """
 from __future__ import annotations
 
-import dataclasses
 import warnings
 from typing import NamedTuple
-from collections.abc import Callable
-
-import jax
-import jax.numpy as jnp
-import numpy as np
-
-from repro.models import registry
-
-
-def make_prefill_step(cfg) -> Callable:
-    mod = registry.family_module(cfg)
-
-    def prefill_step(params, batch: dict[str, jax.Array]):
-        logits, cache = mod.prefill(cfg, params, batch)
-        return logits[:, -1], cache
-
-    return prefill_step
-
-
-def make_serve_step(cfg) -> Callable:
-    mod = registry.family_module(cfg)
-
-    def serve_step(params, tokens, cache, pos):
-        logits, cache = mod.decode_step(cfg, params, tokens, cache, pos)
-        return logits.reshape(tokens.shape[0], -1), cache
-
-    return serve_step
-
-
-@dataclasses.dataclass
-class Request:
-    rid: int
-    prompt: np.ndarray         # (S,) int32
-    max_new: int = 16
-    generated: list[int] | None = None
 
 
 # ---------------------------------------------------------------------------
@@ -423,62 +381,3 @@ class ConsensusService:
     def group_loads(self) -> list[int]:
         """Values submitted per group (load-balance introspection)."""
         return list(self.submits_per_group)
-
-
-class ServeLoop:
-    """Greedy continuous-batching loop (host side, CPU-scale)."""
-
-    def __init__(self, cfg, params, batch_size: int, max_len: int):
-        self.cfg = cfg
-        self.params = params
-        self.batch = batch_size
-        self.max_len = max_len
-        self.mod = registry.family_module(cfg)
-        self._decode = jax.jit(make_serve_step(cfg))
-        self.steps = 0
-
-    def run(self, requests: list[Request]) -> dict[int, list[int]]:
-        """Teacher-forced prefill via decode steps, then greedy generation.
-
-        Mixed prompt lengths never see padding: every row feeds a *real*
-        token at every step — its prompt while the shared position counter
-        is inside the prompt, its own greedy continuation afterwards.  Each
-        row therefore crosses from teacher-forcing to generation at its own
-        boundary, and since row ``i`` has consumed exactly ``t`` of its own
-        tokens by step ``t``, the shared position counter is per-row exact.
-        Generations match per-request decode bit-for-bit (cache rows only
-        ever hold the row's own tokens); rows that finish early idle on
-        their last token, which touches no other row.
-        """
-        out: dict[int, list[int]] = {}
-        for chunk_start in range(0, len(requests), self.batch):
-            chunk = requests[chunk_start : chunk_start + self.batch]
-            b = len(chunk)
-            # an empty prompt seeds token 0 as an implicit BOS (the row must
-            # feed something at step 0) and generates from it
-            lens = [max(1, len(r.prompt)) for r in chunk]
-            cache = self.mod.init_cache(
-                self.cfg, self.batch, self.max_len, jnp.dtype(self.cfg.dtype)
-            )
-            gen: list[list[int]] = [[] for _ in range(b)]
-            cur = np.zeros((self.batch, 1), np.int32)
-            for i, r in enumerate(chunk):
-                if len(r.prompt):
-                    cur[i, 0] = r.prompt[0]
-            total = max(ln + r.max_new for ln, r in zip(lens, chunk, strict=True))
-            for t in range(total - 1):
-                last, cache = self._decode(
-                    self.params, jnp.asarray(cur), cache, jnp.int32(t)
-                )
-                self.steps += 1
-                nxt = np.asarray(jnp.argmax(last, axis=-1), np.int32)
-                for i, r in enumerate(chunk):
-                    k = t + 1 - lens[i]         # generation index this step
-                    if k < 0:
-                        cur[i, 0] = r.prompt[t + 1]   # still teacher-forcing
-                    elif k < r.max_new:
-                        gen[i].append(int(nxt[i]))
-                        cur[i, 0] = nxt[i]
-            for i, r in enumerate(chunk):
-                out[r.rid] = gen[i]
-        return out

@@ -79,7 +79,7 @@ def test_all_kernel_pallas_sites_are_audited():
     files = {s.file.rsplit("/", 1)[-1] for s in sites}
     assert {
         "acceptor.py", "coordinator.py", "learner.py", "digest.py",
-        "wirepath.py", "flash_attention.py",
+        "wirepath.py",
     } <= files
 
 

@@ -1,85 +1,13 @@
-"""Fault-tolerance paths: consensus-committed checkpoints, restart,
-coordinator failover (hardware -> software takeover), recover() gap fill,
-replicated log trim, elastic membership views."""
+"""Fault-tolerance paths of the consensus core: coordinator failover
+(hardware -> software takeover), recover() gap fill, replicated log trim."""
 from __future__ import annotations
 
-import dataclasses
-import os
-
-import jax
-import jax.numpy as jnp
 import numpy as np
-import pytest
 
-from repro.configs import get_config
 from repro.core import FaultSpec, PaxosConfig, PaxosContext, ReplicatedLog, SimNet
 from repro.core.failover import allocate_round, takeover
-from repro.train import checkpoint as ckpt_mod
-from repro.train import elastic, train_loop
-from repro.train.data import DataConfig, SyntheticStream
 
 CFG = PaxosConfig(n_acceptors=3, n_instances=512, batch=16)
-
-
-# ---------------------------------------------------------------------------
-# checkpoints
-# ---------------------------------------------------------------------------
-def test_checkpoint_roundtrip_committed(tmp_path):
-    cfg = get_config("qwen3-4b").reduced()
-    state = train_loop.init_state(cfg, jax.random.PRNGKey(0))
-    ctx = PaxosContext(CFG)
-    mgr = ckpt_mod.CheckpointManager(str(tmp_path), paxos_ctx=ctx)
-    path = mgr.save(state, step=3)
-    assert os.path.exists(os.path.join(path, "COMMITTED"))
-    # the commit record went through consensus
-    assert any(p.startswith(b"ckpt:3:") for _, p in ctx.delivered_log)
-
-    restored, step = mgr.restore(state)
-    assert step == 3
-    for a, b in zip(
-        jax.tree_util.tree_leaves(state), jax.tree_util.tree_leaves(restored)
-    , strict=True):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-def test_uncommitted_checkpoint_invisible(tmp_path):
-    """If the consensus layer cannot decide (no quorum), the checkpoint must
-    not become eligible for restart."""
-    cfg = get_config("whisper-base").reduced()
-    state = train_loop.init_state(cfg, jax.random.PRNGKey(0))
-    ctx = PaxosContext(CFG)
-    ctx.hw.kill_acceptor(0)
-    ctx.hw.kill_acceptor(1)  # no quorum
-    mgr = ckpt_mod.CheckpointManager(str(tmp_path), paxos_ctx=ctx)
-    mgr.save(state, step=1)
-    assert mgr.latest_committed() is None
-    with pytest.raises(FileNotFoundError):
-        mgr.restore(state)
-
-
-def test_restart_resumes_training(tmp_path):
-    """Crash/restart: restore from latest committed step and keep training
-    deterministically (counter-based data stream is restart-safe)."""
-    cfg = get_config("qwen3-4b").reduced()
-    ocfg_steps = 4
-    state = train_loop.init_state(cfg, jax.random.PRNGKey(0))
-    step = jax.jit(train_loop.make_train_step(cfg))
-    stream = SyntheticStream(
-        DataConfig(vocab=cfg.vocab, global_batch=2, seq_len=16, seed=1)
-    )
-    mgr = ckpt_mod.CheckpointManager(str(tmp_path))
-    for i in range(ocfg_steps):
-        state, _ = step(state, {k: jnp.asarray(v) for k, v in stream.batch_at(i).items()})
-    mgr.save(state, step=ocfg_steps)
-
-    # "crash"; restore and continue
-    state2, at = mgr.restore(train_loop.init_state(cfg, jax.random.PRNGKey(9)))
-    assert at == ocfg_steps
-    s_a, _ = step(state, {k: jnp.asarray(v) for k, v in stream.batch_at(at).items()})
-    s_b, _ = step(state2, {k: jnp.asarray(v) for k, v in stream.batch_at(at).items()})
-    for a, b in zip(jax.tree_util.tree_leaves(s_a.params),
-                    jax.tree_util.tree_leaves(s_b.params), strict=True):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 # ---------------------------------------------------------------------------
@@ -207,43 +135,3 @@ def test_replicated_log_order_gaps_trim():
     assert log.trim_watermark == 2
     log.offer(1, b"zz")  # below watermark: ignored
     assert 1 not in log.entries
-
-
-# ---------------------------------------------------------------------------
-# elastic membership
-# ---------------------------------------------------------------------------
-def test_membership_view_change_through_consensus():
-    # membership views are bigger than 64B: use a wide-value config
-    ctx = PaxosContext(dataclasses.replace(CFG, value_words=64))
-    v0 = elastic.MembershipView(0, ("h0", "h1", "h2", "h3"), (2, 2), ("data", "model"))
-    vm = elastic.ViewManager(ctx, v0)
-    view = vm.propose_view(["h0", "h1", "h3"], model_parallel=1)
-    assert view.epoch == 1
-    assert view.hosts == ("h0", "h1", "h3")
-    assert view.mesh_shape == (3, 1)
-
-
-def test_elastic_reshard_restore(tmp_path):
-    """Checkpoint written on one 'mesh', restored against new shardings."""
-    cfg = get_config("yi-9b").reduced()
-    state = train_loop.init_state(cfg, jax.random.PRNGKey(0))
-    mgr = ckpt_mod.CheckpointManager(str(tmp_path))
-    mgr.save(state, step=1)
-    # restore with explicit (single-device) shardings for the new mesh
-    mesh = jax.make_mesh((1,), ("data",))
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    shardings = jax.tree_util.tree_map(
-        lambda _: NamedSharding(mesh, P()), state
-    )
-    restored, step = mgr.restore(state, shardings=shardings)
-    assert step == 1
-    for a, b in zip(jax.tree_util.tree_leaves(state),
-                    jax.tree_util.tree_leaves(restored), strict=True):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-def test_replan_mesh():
-    assert elastic.replan_mesh(512)[0] == (32, 16)
-    assert elastic.replan_mesh(496)[0] == (31, 16)   # lost a host: shrink data
-    assert elastic.replan_mesh(8, model_parallel=16)[0] == (1, 8)

@@ -17,6 +17,11 @@ groups-sharded):
   decodes the session's stitched segment chain.  Every *leased* get is
   pinned consensus-free by the dataplane's dispatch counter; the schedule
   must exercise both the leased path and the read-index fallback.
+
+* **A dict state machine** — ``KVSession`` clients over a one-group KV
+  deployment (the shape of the benchmark's ``kv_a3``) under YCSB-shaped
+  mixes: every ``get`` and the final state equal a plain ``dict`` applying
+  the acknowledged ops in log order.
 """
 from __future__ import annotations
 
@@ -37,7 +42,8 @@ from repro.serve.kv import (
     encode_op,
 )
 
-pytestmark = pytest.mark.slow    # chaos suite: skipped in the fast CI lane
+# the chaos suites are marked slow: skipped in the fast CI lane
+slow = pytest.mark.slow
 
 A = 3
 KEYS = [f"key{i}".encode() for i in range(4)]
@@ -160,12 +166,14 @@ def run_kv_twins(
     assert not ctx._pending
 
 
+@slow
 @pytest.mark.parametrize("use_kernels", [False, True])
 @pytest.mark.parametrize("seed", [0, 1])
 def test_kv_twins_unsharded(seed, use_kernels):
     run_kv_twins(seed, g=3, use_kernels=use_kernels, sharded=False)
 
 
+@slow
 @pytest.mark.parametrize("use_kernels", [False, True])
 @pytest.mark.parametrize("seed", [2, 3])
 def test_kv_twins_sharded(seed, use_kernels):
@@ -239,13 +247,114 @@ def run_kv_sessions(
     assert kv.stats["read_index_gets"] > 0
 
 
+@slow
 @pytest.mark.parametrize("use_kernels", [False, True])
 @pytest.mark.parametrize("seed", [0, 1])
 def test_kv_sessions_unsharded(seed, use_kernels):
     run_kv_sessions(seed, g=3, use_kernels=use_kernels, sharded=False)
 
 
+@slow
 @pytest.mark.parametrize("use_kernels", [False, True])
 @pytest.mark.parametrize("seed", [2, 3])
 def test_kv_sessions_sharded(seed, use_kernels):
     run_kv_sessions(seed, g=2, use_kernels=use_kernels, sharded=True)
+
+
+# ---------------------------------------------------------------------------
+# Part C: the KV tier against a dict state machine
+# ---------------------------------------------------------------------------
+# (get, put, cas, delete) shares of each traffic mix
+MIXES = {
+    "ycsb_a": (0.5, 0.5, 0.0, 0.0),
+    "ycsb_b": (0.95, 0.05, 0.0, 0.0),
+    "cas_delete": (0.1, 0.3, 0.35, 0.25),
+    "put_only": (0.0, 1.0, 0.0, 0.0),
+}
+
+
+def _dict_apply(state: dict, op: tuple) -> None:
+    """The plain reference: one acknowledged op on a ``dict``."""
+    kind, key, value, expect = op
+    if kind == "put":
+        state[key] = value
+    elif kind == "delete":
+        state.pop(key, None)
+    elif state.get(key) == expect:      # cas; expect None = key absent
+        state[key] = value
+
+
+@pytest.mark.parametrize("seed", range(4))
+@pytest.mark.parametrize("mix", sorted(MIXES))
+def test_kv_matches_dict_state_machine(mix, seed):
+    """Eight sessions drive 240 ops of ``mix`` over 64 Zipf(0.99) keys
+    through ``KVSession`` on a one-group fused context with snapshots,
+    pumping at random points and compacting once mid-run.  One group
+    decides in submission order, and a pump delivers everything submitted
+    before it, so the acknowledged prefix of the issued writes is known at
+    every get: each get must read the dict over that prefix, the final
+    replica state the dict over every write, and the log must hold each
+    write and each read-index marker once."""
+    ctx = PaxosContext(
+        PaxosConfig(n_acceptors=A, n_instances=4096, value_words=16, batch=128),
+        use_kernels=False, fused=True, snapshots=True,
+    )
+    svc = ConsensusService(ctx)
+    kv = ReplicatedKV(svc)
+    rng = np.random.default_rng(seed)
+    keys = [f"k{i}".encode() for i in range(64)]
+    zipf = 1.0 / np.arange(1, 65) ** 0.99
+    zipf = zipf[rng.permutation(64)] / zipf.sum()
+    sessions = [kv.session(f"c{i}") for i in range(8)]
+    issued: list[tuple] = []          # every write, in submission order
+    latest: dict = {}                 # the dict over every issued write
+    acked: dict = {}                  # the dict over the acknowledged ones
+    n_acked = n_applied = 0
+
+    def catch_up() -> None:
+        nonlocal n_applied
+        for op in issued[n_applied:n_acked]:
+            _dict_apply(acked, op)
+        n_applied = n_acked
+
+    for step in range(240):
+        s = sessions[int(rng.integers(len(sessions)))]
+        key = keys[int(rng.choice(64, p=zipf))]
+        kind = rng.choice(["get", "put", "cas", "delete"], p=MIXES[mix])
+        value = f"{s.id}.{step}".encode()
+        if kind == "get":
+            before = kv.stats["read_index_gets"]
+            got = s.get(key)
+            if kv.stats["read_index_gets"] > before:
+                n_acked = len(issued)   # the read-index op pumped them all
+            catch_up()
+            assert got == acked.get(key), (step, key)
+            continue
+        if kind == "put":
+            s.put(key, value)
+            issued.append(("put", key, value, None))
+        elif kind == "delete":
+            s.delete(key)
+            issued.append(("delete", key, b"", None))
+        else:
+            r = rng.random()
+            expect = latest.get(key) if r < 0.5 else None if r < 0.75 else b"stale"
+            s.cas(key, expect, value)
+            issued.append(("cas", key, value, expect))
+        _dict_apply(latest, issued[-1])
+        if rng.random() < 0.25:
+            svc.pump()
+            assert ctx.quiescent()
+            n_acked = len(issued)
+        if step == 120:
+            ctx.snapshot_group(0)
+    svc.run_until_quiescent()
+    assert ctx.quiescent()
+    kv.refresh()
+    n_acked = len(issued)
+    catch_up()
+    state = {k: v for k, (v, _ver) in kv.replica(0).state.items() if v is not None}
+    assert state == acked
+    assert len(ctx.full_group_log(0)) == len(issued) + kv.stats["read_index_gets"]
+    if MIXES[mix][0]:
+        assert kv.stats["leased_gets"] and kv.stats["read_index_gets"]

@@ -523,3 +523,82 @@ def test_sharded_window_blocks_follow_the_burst(n, b, want):
     for base in range(0, bb, 8):
         got = plan_mod.window_blocks(n, [base], b)
         assert got is None or got <= want
+
+
+# ---------------------------------------------------------------------------
+# Partition of skewed pending work (host only)
+# ---------------------------------------------------------------------------
+def _zipf_pending(seed: int, g: int, total: int) -> np.ndarray:
+    """``total`` submissions spread over ``g`` groups by a Zipf(0.99) law
+    on a seeded rank order, then about a quarter of the groups left idle."""
+    rng = np.random.default_rng(seed)
+    weights = 1.0 / np.arange(1, g + 1) ** 0.99
+    ranks = rng.permutation(g)
+    pending = rng.multinomial(total, weights[ranks] / weights.sum())
+    pending[rng.random(g) < 0.25] = 0
+    return pending
+
+
+@pytest.mark.parametrize("shards", [1, 4])
+@pytest.mark.parametrize("seed", range(13))
+def test_planner_partitions_pending_work(seed, shards):
+    """One chunk wave of Zipf-skewed pending work over 64 groups of the
+    paper's shape: every group with work that is live and not frozen rides
+    exactly one cohort, and no other group any; each cohort's burst is the
+    least burst size holding each member's chunk; no fold exceeds the fold
+    cap; and over four shards every cohort runs one of the programs the
+    sharded dataplane compiles before it serves."""
+    g, n, batch = 64, 65536, 128
+    cfg = PaxosConfig(n_acceptors=3, n_instances=n, value_words=16,
+                      batch=batch, n_groups=g)
+    rng = np.random.default_rng(1000 + seed)
+    pending = _zipf_pending(seed, g, total=int(rng.integers(200, 4000)))
+    loads = np.minimum(pending, batch)
+    live = rng.random(g) > 0.05
+    crnd = np.where(rng.random(g) < 0.05, NO_ROUND, 0)
+    # watermarks in a few lockstep classes, some off the block boundary
+    marks = rng.choice([0, 128, 256, 1024, 1032, 4096], size=g)
+    planner = DispatchPlanner(batch=batch, n_instances=n, persistent_rounds=4,
+                              sharded=shards > 1)
+    plan = planner.plan_round(
+        loads=loads.tolist(), marks=marks.tolist(), live=live.tolist(),
+        crnd=crnd.tolist(), pending=pending.tolist(),
+    )
+
+    want = {i for i in range(g) if pending[i] and live[i] and crnd[i] != NO_ROUND}
+    members = [i for c in plan.cohorts for i in c.gids]
+    assert sorted(members) == sorted(want)          # each exactly once
+    assert plan.enabled == tuple(i in want for i in range(g))
+
+    sizes = plan_mod.burst_sizes(batch)
+    bursts = [c.burst for c in plan.cohorts]
+    assert bursts == sorted(set(bursts), reverse=True)   # one per size, hot first
+    gl = g // shards
+    cap = plan_mod.fold_cap(gl, n, cfg.n_acceptors, cfg.value_words)
+    programs = set(plan_mod.sharded_programs(cfg, shards, kernels=True))
+    for c in plan.cohorts:
+        assert c.burst in sizes
+        for i in c.gids:
+            assert c.burst == min(s for s in sizes if s >= loads[i])
+        if shards == 1:
+            assert 1 <= c.rounds and c.rounds * c.burst <= n
+            if c.rounds > 1:
+                assert c.burst == batch
+                assert all(pending[i] >= c.rounds * batch for i in c.gids)
+            width = fold_width_full(c.gids, marks, cap)
+            gb, blocks = cohort_blocks(c.gids, marks, cap)
+            assert cap % width == 0 and cap % gb == 0
+            assert blocks == sorted({i // gb for i in c.gids})
+        else:
+            assert c.rounds == 1
+            per_shard = np.bincount([i // gl for i in c.gids], minlength=shards)
+            lanes = plan_mod.packed_lanes(int(per_shard.max()), gl)
+            nblk = plan_mod.sharded_window_blocks(n, c.burst)
+            if lanes is None:
+                prog = plan_mod.ShardedProgram(
+                    "full", nblk, plan_mod.full_fold(c.gids, marks, cap), c.burst
+                )
+            else:
+                prog = plan_mod.ShardedProgram("packed", nblk, lanes, c.burst)
+            assert prog in programs, prog
+            assert prog.width <= (gl if prog.kind == "packed" else cap)
