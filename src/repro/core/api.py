@@ -1306,7 +1306,10 @@ class ShardedMultiGroupDataplane(MultiGroupDataplane):
         jitted functions' own caches, and the learner ring read of a
         snapshot drain runs once."""
         g, v = self.cfg.n_groups, self.cfg.value_words
-        with obs.span("repro.hw.precompile", programs=len(self.programs)):
+        # folded full-width programs run their group rows as a loop
+        rolled = sum(p.kind == "full" and p.width > 1 for p in self.programs)
+        with obs.span("repro.hw.precompile", programs=len(self.programs),
+                      rolled=rolled):
             for prog in self.programs:
                 b = prog.burst
                 if prog.kind == "packed":

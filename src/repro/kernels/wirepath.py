@@ -188,17 +188,22 @@ def _phase2_rows(
 ):
     """One Phase-2 round over one ring block of ``GB`` groups: sequence ->
     all-acceptor vote -> learner quorum -> ring dedup, one group row at a
-    time (GB and A are static, so the loops unroll).  Shared by every
-    wire-path kernel body, which differ only in where a row's scalars come
-    from — identical arithmetic is what makes the K-round entry bit-exact
-    against K single rounds by construction.
+    time.  Shared by every wire-path kernel body, which differ only in where
+    a row's scalars come from — identical arithmetic is what makes the
+    K-round entry bit-exact against K single rounds by construction.
+
+    A block of one row runs the body once; a folded block runs it as a
+    loop over the rows, indexing the blocks' leading (untiled) group
+    dimension dynamically, so the traced kernel does not grow with GB.
+    The acceptor loop (A is small and static) unrolls.
 
     Every per-slot quantity is a ``(1, BB)`` lane row, which broadcasts down
     the V sublanes of the value blocks; masks are int32 until consumed.
     """
     gb, a, bb = st_rnd_ref.shape
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, bb), 1)
-    for k in range(gb):
+
+    def row(k):
         base, crnd, alive_of, limit = scalars(k)
         # instance numbers of this ring block; the window's first block is
         # the one holding slot ``base``
@@ -247,6 +252,11 @@ def _phase2_rows(
         fresh_ref[k] = fresh
         win_ref[k] = win
         value_ref[k] = value
+
+    if gb == 1:
+        row(0)
+    else:
+        jax.lax.fori_loop(0, gb, lambda k, _: row(k), None)
 
 
 def _cohort_wirepath_kernel(

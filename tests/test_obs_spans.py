@@ -209,6 +209,9 @@ def test_sharded_pump_spans_each_dispatch(tmp_path):
 
     [pre] = of("repro.hw.precompile")
     assert pre["programs"] == len(ctx.hw.programs) > 0
+    # the folded full-width programs, whose kernel runs its rows as a loop
+    assert pre["rolled"] == sum(
+        p.kind == "full" and p.width > 1 for p in ctx.hw.programs) > 0
     launches, plans = of("repro.hw.launch"), of("repro.plan.round")
     assert len(launches) == len(of("repro.hw.readback")) == ctx.hw.dispatch_count > 0
     assert sum(m["cohorts"] for m in plans) == ctx.hw.dispatch_count

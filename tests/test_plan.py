@@ -486,6 +486,8 @@ def test_sharded_program_vocabulary_is_closed_and_small():
     assert plan_mod.burst_sizes(128) == [8, 16, 32, 64, 128]
     assert {p.width for p in progs if p.kind == "packed"} == {1, 8, 64}
     assert {p.width for p in progs if p.kind == "full"} == {1, 64}
+    # one full-width program per burst folds at the cap (the rolled kernel)
+    assert sum(p.kind == "full" and p.width > 1 for p in progs) == 5
     assert {p.window_blocks for p in progs} == {2}
     # the jnp engine: one window-less program per lane count and burst
     jnp_progs = plan_mod.sharded_programs(cfg, 4, kernels=False)

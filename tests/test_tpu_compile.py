@@ -154,11 +154,14 @@ def test_jnp_engine_fits_one_chip(shape, b):
 
 
 @pytest.mark.parametrize("kind,width,b", [("packed", 64, 128), ("full", 64, 128),
-                                          ("full", 1, 8)])
+                                          ("full", 64, 8), ("full", 1, 8)])
 def test_sharded_program_compiles_for_four_chips(topo, monkeypatch, kind, width, b):
     """The four-chip tenants deployment's programs at their real size:
     1,024 groups over a 2x2 mesh, 256 per chip.  Each compiles, updates
-    the donated slabs in place, and needs little beside them."""
+    the donated slabs in place, and needs little beside them.  The
+    full-width programs folded at the cap (64) run their group rows as a
+    loop that indexes the blocks' group dimension dynamically; Mosaic
+    takes it at the largest and the smallest burst."""
     import numpy as np
     from jax.sharding import AxisType, NamedSharding
     from jax.sharding import PartitionSpec as P

@@ -301,10 +301,13 @@ def test_multigroup_fused_matches_independent_runs(g):
             )
         )
         # Pallas megakernel, one group per grid step (general mapping) and —
-        # while the watermarks are in lockstep — all groups folded per step.
-        # EVERY mapping must match the jnp oracle bit for bit (both calls see
-        # the same pre-round state; no donation at this call level).
-        group_blocks = (1, g) if lockstep else (1,)
+        # while the watermarks are in lockstep — 2, 4 or all groups folded
+        # per step, their rows run as a loop.  EVERY mapping must match the
+        # jnp oracle bit for bit (both calls see the same pre-round state;
+        # no donation at this call level).
+        group_blocks = (1,)
+        if lockstep:
+            group_blocks += tuple(gb for gb in (2, 4, 8) if g % gb == 0)
         for gb in group_blocks:
             outs = wirepath.multigroup_wirepath_round(
                 cstate_k.next_inst, cstate_k.crnd, jnp.int32(quorum),
@@ -537,36 +540,55 @@ def test_cohort_round_matches_full_width_oracle():
             np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
-def test_cohort_round_per_block_bases():
-    """Two divergent lockstep halves fold at width G/2, each block deriving
-    its ring offset from its own base — bit-identical to the oracle."""
-    g, a, n, v, b = 8, 3, 128, 4, 16
+@pytest.mark.parametrize("gb", [2, 4, 8])
+def test_cohort_round_per_block_bases(gb):
+    """Folded blocks of ``gb`` groups, each at a lockstep base of its own,
+    run their group rows as a loop and stay bit-identical to the oracle —
+    on random register files and learner rings, so a row that read or
+    wrote another row's block would show.  The last member of every block
+    is disabled with a watermark that diverged from the block's, so the
+    block-base substitution runs through the loop too."""
+    g, a, n, v, b = 16, 3, 256, 4, 16
     quorum = a // 2 + 1
-    rng = np.random.default_rng(5)
-    _cs, stack, ls = _mk_mg_state(g, a, n, v)
-    _cso, stack_o, ls_o = _mk_mg_state(g, a, n, v)
-    alive = jnp.ones((g, a), jnp.int32)
-    marks = np.asarray([32, 32, 32, 32, 96, 96, 96, 96], np.int32)
+    rng = np.random.default_rng(5 + gb)
+
+    def rand(lo, hi, shape):
+        return jnp.asarray(rng.integers(lo, hi, shape).astype(np.int32))
+
+    stack = AcceptorState(rand(0, 3, (g, a, n)), rand(-1, 3, (g, a, n)),
+                          rand(-99, 99, (g, a, n, v)))
+    ls = batched.LearnerState(rand(0, 2, (g, n)), rand(0, 2 * n, (g, n)),
+                              rand(-99, 99, (g, n, v)))
+    alive = jnp.asarray(rng.random((g, a)) > 0.2, jnp.int32)
+    crnd = rand(0, 4, (g,))
+    # a window at any offset of its first ring block: base % 128 + b <= 256
+    bases = rng.integers(0, 4 * n, g // gb).astype(np.int32)
+    marks = np.repeat(bases, gb)
+    enabled = np.ones((g,), np.int32)
+    enabled[gb - 1::gb] = 0
+    marks[gb - 1::gb] += rng.integers(1, n, g // gb).astype(np.int32)
     vals = rng.integers(-99, 99, (g, b, v)).astype(np.int32)
     outs = wirepath.cohort_wirepath_round(
-        jnp.asarray([0, 1], jnp.int32),
-        jnp.asarray(marks), jnp.zeros((g,), jnp.int32),
-        jnp.int32(quorum), alive,
+        jnp.arange(g // gb, dtype=jnp.int32),
+        jnp.asarray(marks), crnd, jnp.int32(quorum), alive,
         stack.rnd, stack.vrnd, stack.value,
         ls.delivered, ls.inst, ls.value,
-        jnp.asarray(vals), jnp.ones((g,), jnp.int32),
-        group_block=4, interpret=True,
+        jnp.asarray(vals), jnp.asarray(enabled),
+        group_block=gb, interpret=True,
     )
     cs_o = CoordinatorState(
-        next_inst=jnp.asarray(marks), crnd=jnp.zeros((g,), jnp.int32)
+        next_inst=jnp.asarray(marks),
+        crnd=jnp.where(jnp.asarray(enabled) != 0, crnd, NO_ROUND),
     )
-    _c, stack_o, ls_o, fresh_o, _i, _w, val_o = (
+    _c, stack_o, ls_o, fresh_o, _i, win_o, val_o = (
         batched.multigroup_fused_round(
-            cs_o, stack_o, ls_o, jnp.asarray(vals),
+            cs_o, stack, ls, jnp.asarray(vals),
             jnp.ones((g, b), bool), alive != 0, quorum,
         )
     )
+    assert np.asarray(fresh_o).any()
     np.testing.assert_array_equal(np.asarray(outs[6] != 0), np.asarray(fresh_o))
+    np.testing.assert_array_equal(np.asarray(outs[7]), np.asarray(win_o))
     np.testing.assert_array_equal(np.asarray(outs[8]), np.asarray(val_o))
     for x, y in zip(
         jax.tree_util.tree_leaves((AcceptorState(*outs[:3]),
@@ -574,3 +596,45 @@ def test_cohort_round_per_block_bases():
         jax.tree_util.tree_leaves((stack_o, ls_o)), strict=True,
     ):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+def _kernel_eqn_count(group_block: int) -> int:
+    """Equations, nested ones included, of the kernel body inside the
+    ``pallas_call`` of ``cohort_wirepath_round`` folded at ``group_block``,
+    traced from abstract shapes at the tenants deployment's widths."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
+    g, a, n, v, b = 64, 3, 65536, 16, 128
+
+    def sds(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.int32)
+
+    traced = wirepath.cohort_wirepath_round.trace(
+        sds(g // group_block), sds(g), sds(g), sds(), sds(g, a),
+        sds(g, a, n), sds(g, a, n), sds(g, a, n, v), sds(g, n), sds(g, n),
+        sds(g, n, v), sds(g, b, v), sds(g), sds(g),
+        group_block=group_block, window_blocks=2,
+    )
+
+    def subjaxprs(eqn):
+        for p in eqn.params.values():
+            for x in p if isinstance(p, (tuple, list)) else (p,):
+                if isinstance(x, ClosedJaxpr):
+                    yield x.jaxpr
+                elif isinstance(x, Jaxpr):
+                    yield x
+
+    def count(jaxpr) -> int:
+        return sum(1 + sum(count(j) for j in subjaxprs(e)) for e in jaxpr.eqns)
+
+    [kernel] = [e.params["jaxpr"] for e in traced.jaxpr.eqns
+                if e.primitive.name == "pallas_call"]
+    return count(kernel)
+
+
+def test_folded_kernel_trace_does_not_grow_with_the_fold():
+    """The group rows of a folded block are one loop, not GB copies: the
+    kernel traced at the widest fold (64) is no larger than at a fold of 2,
+    give or take a few equations.  Unrolled rows made the deployment's
+    five fold-64 programs most of its set-up."""
+    assert _kernel_eqn_count(64) <= _kernel_eqn_count(2) + 8
